@@ -57,9 +57,12 @@ for sched in ("xla", "summa", "cannon"):
     }}
 print(json.dumps(res))
 """
+    # The child rehearses on virtual host devices: pinned to the CPU, so it
+    # never contends for an accelerator the parent process may hold.
     proc = subprocess.run(
         [sys.executable, "-c", code], capture_output=True, text=True,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"}, timeout=3000,
+        env={**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": "src"},
+        timeout=3000,
     )
     if proc.returncode != 0:
         out(f"bench_chain_dryrun,error,{proc.stderr[-300:]}")

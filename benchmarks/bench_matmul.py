@@ -25,6 +25,7 @@ import numpy as np
 def run(n: int = 1024, out=print):
     # collective-bytes comparison needs many fake devices -> subprocess
     import json
+    import os
     import subprocess
     import sys
 
@@ -50,9 +51,11 @@ for sched in ("xla", "summa", "cannon"):
                    "dot_flops": a["dot_flops"]}}
 print(json.dumps(res))
 """
+    # The child rehearses on virtual host devices: pinned to the CPU, so it
+    # never contends for an accelerator the parent process may hold.
     proc = subprocess.run(
         [sys.executable, "-c", code], capture_output=True, text=True,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
+        env={**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": "src"},
     )
     if proc.returncode == 0:
         res = json.loads(proc.stdout.strip().splitlines()[-1])

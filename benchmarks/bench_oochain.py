@@ -266,6 +266,7 @@ def trajectory(out_path, out=print):
     roof = streamed_solve_roofline(
         bytes_read=sst.bytes_read, bytes_h2d=sst.bytes_h2d,
         flops=streamed_solve_flops(n, k, rep.iterations), seconds=solve_s,
+        device_kind=jax.devices()[0].device_kind,
     )
     result = {
         "bench": "oochain_trajectory", "schema": 1,
@@ -280,7 +281,7 @@ def trajectory(out_path, out=print):
                   "bytes_h2d_saved": sst.bytes_h2d_saved,
                   "panels": sst.panels},
         "roofline_frac": roof["roofline_frac"],
-        "roofline_bound": roof["bound"],
+        "roofline_bound": roof.get("bound"),
         "roofline": roof,
         # Registry counter deltas over the whole bench (repro.obs.metrics):
         # phase/pipeline/cache/solver telemetry.  stream.* is excluded -- the
@@ -295,7 +296,7 @@ def trajectory(out_path, out=print):
     out(f"[bench_oochain] trajectory: build {build_s:.2f}s, solve "
         f"{solve_s:.2f}s/{rep.iterations} its, {sst.bytes_h2d / 1e6:.1f} MB "
         f"H2D ({sst.bytes_h2d_saved / 1e6:.1f} MB saved), roofline "
-        f"{roof['roofline_frac']:.2e} ({roof['bound']}-bound); wrote {out_path}")
+        f"{roof['roofline_frac']} ({roof.get('bound')}-bound); wrote {out_path}")
     return result
 
 

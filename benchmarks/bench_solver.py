@@ -111,10 +111,11 @@ def run(n=96, d=4, k=8, tol=1e-5, grid=8, seed=0, out_path=None, out=print):
                         bytes_read=bread, bytes_h2d=bh2d,
                         flops=streamed_solve_flops(n, k, rep.iterations),
                         seconds=dt,
+                        device_kind=jax.devices()[0].device_kind,
                     )
                     row["roofline"] = roof
-                    frac = (f" roofline={roof['roofline_frac']:.2e} "
-                            f"({roof['bound']}-bound)")
+                    frac = (f" roofline={roof['roofline_frac']} "
+                            f"({roof.get('bound')}-bound)")
                 rows.append(row)
                 out(f"[bench_solver]  {mesh_label:>4s} {storage:8s} {method:10s} | "
                     f"{rep.iterations:5d} {rep.residual:8.1e} {dt:7.2f} | "
@@ -187,6 +188,7 @@ def trajectory(out_path, out=print):
     roof = streamed_solve_roofline(
         bytes_read=sst.bytes_read, bytes_h2d=sst.bytes_h2d,
         flops=streamed_solve_flops(n, k, rep.iterations), seconds=solve_s,
+        device_kind=jax.devices()[0].device_kind,
     )
     result = {
         "bench": "solver_trajectory", "schema": 1,
@@ -202,7 +204,7 @@ def trajectory(out_path, out=print):
                   "bytes_h2d_saved": sst.bytes_h2d_saved,
                   "panels": sst.panels},
         "roofline_frac": roof["roofline_frac"],
-        "roofline_bound": roof["bound"],
+        "roofline_bound": roof.get("bound"),
         "roofline": roof,
         # Registry counter deltas over the whole bench (repro.obs.metrics):
         # phase/pipeline/cache/solver telemetry.  stream.* is excluded -- the
@@ -218,7 +220,7 @@ def trajectory(out_path, out=print):
     out(f"[bench_solver] trajectory: {rep.iterations} its in {solve_s:.2f}s, "
         f"{sst.bytes_h2d / 1e6:.1f} MB H2D "
         f"({sst.bytes_h2d_saved / 1e6:.1f} MB saved), roofline "
-        f"{roof['roofline_frac']:.2e} ({roof['bound']}-bound); wrote {out_path}")
+        f"{roof['roofline_frac']} ({roof.get('bound')}-bound); wrote {out_path}")
     return result
 
 

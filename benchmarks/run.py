@@ -15,6 +15,7 @@ from __future__ import annotations
 import argparse
 import sys
 import time
+import traceback
 
 
 def main() -> None:
@@ -42,14 +43,18 @@ def main() -> None:
     }
     chosen = args.only.split(",") if args.only else list(benches)
     t0 = time.time()
+    failed = []
     for name in chosen:
         print(f"# === {name} ===", flush=True)
         try:
             benches[name]()
-        except Exception as e:  # keep the harness going; record the failure
-            print(f"{name},ERROR,{type(e).__name__}:{e}", file=sys.stderr)
+        except Exception as e:  # run the rest, then fail the whole harness
+            traceback.print_exc()
             print(f"{name},error,{type(e).__name__}")
+            failed.append(name)
     print(f"# total {time.time()-t0:.0f}s")
+    if failed:
+        raise SystemExit(f"failed benchmarks: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

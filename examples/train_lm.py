@@ -10,7 +10,7 @@ deterministic next-token structure (loss drops well below ln(vocab)).
 
 import argparse
 
-from repro.launch.mesh import make_cpu_mesh
+from repro.launch.mesh import make_mesh
 from repro.launch.train import train_loop
 from repro.models.common import ArchConfig
 
@@ -28,7 +28,7 @@ def main():
         name="demo-100m", family="dense", n_layers=8, d_model=512, n_heads=8,
         n_kv_heads=4, d_ff=2048, vocab=8192, tie_embeddings=True, remat=False,
     )
-    mesh = make_cpu_mesh(1, 1)
+    mesh = make_mesh(1, 1)
     _, _, losses = train_loop(
         cfg, mesh, steps=args.steps, batch=args.batch, seq=args.seq,
         ckpt_dir=args.ckpt_dir, ckpt_every=max(args.steps // 4, 1), log_every=10,

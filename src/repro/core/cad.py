@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.core.distmatrix import DistContext
+from repro.core.distmatrix import F32_PRECISION, DistContext
 from repro.core.embedding import CommuteConfig, Embedding, commute_time_embedding
 from repro.core.tiles import is_streamable, tile_map, tile_stream
 from repro.obs import phase
@@ -32,7 +32,10 @@ def _cad_scores_body(tile, b1, b2, z1, z2, v1, v2):
         zj = z[tile.cols].astype(jnp.float32)
         sq_i = jnp.sum(zi * zi, -1)
         sq_j = jnp.sum(zj * zj, -1)
-        return vol * (sq_i[:, None] + sq_j[None, :] - 2.0 * (zi @ zj.T))
+        # The expansion cancels: its k_RP-wide contraction runs at full
+        # float32 precision (a bf16 pass would swamp near distances).
+        cross = jnp.dot(zi, zj.T, precision=F32_PRECISION)
+        return vol * (sq_i[:, None] + sq_j[None, :] - 2.0 * cross)
 
     de = jnp.abs(b1.astype(jnp.float32) - b2.astype(jnp.float32)) * jnp.abs(
         dist(z1, v1) - dist(z2, v2)

@@ -29,7 +29,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.core import laplacian as lap
-from repro.core.distmatrix import DistContext, add_scaled_identity, matmul
+from repro.core.distmatrix import F32_PRECISION, DistContext, add_scaled_identity, matmul
 from repro.core.tiles import is_streamable, sharded_zeros, stream_stats, tile_map
 from repro.obs.metrics import REGISTRY as _OBS_REGISTRY
 
@@ -171,9 +171,75 @@ def _matmul_panels_from_store(
             m_cols = lax.dynamic_slice(m, (0, r0), (n, ph))
             acc = acc + jnp.dot(
                 m_cols.astype(jnp.float32), panel.astype(jnp.float32),
-                preferred_element_type=jnp.float32,
+                precision=F32_PRECISION, preferred_element_type=jnp.float32,
             )
     return ctx.constrain(acc.astype(out_dtype), ctx.matrix_spec)
+
+
+def _resident_chain(
+    ctx: DistContext,
+    a,
+    d_len: int,
+    *,
+    schedule: str,
+    dtype,
+    deflate: bool,
+    fuse_l: bool,
+    use_kernel: bool,
+    prefetch_depth: int | None,
+    level_sink: dict | None,
+):
+    """``(p1, p2, deg, vol)`` of the device-resident build: every GEMM of
+    the chain, and nothing that syncs with the host (so it also traces as
+    one program under ``jax.jit``)."""
+    mm = partial(matmul, ctx, schedule=schedule, out_dtype=dtype, use_kernel=use_kernel)
+
+    deg = lap.degrees(ctx, a, prefetch_depth=prefetch_depth)
+    vol = lap.volume(ctx, deg)
+    t = lap.normalized_adjacency(
+        ctx, a, deg, deflate=deflate, dtype=dtype, prefetch_depth=prefetch_depth
+    )  # T_0 = S
+    p = add_scaled_identity(ctx, t, 1.0)  # I + S
+    # Only a level_sink keeps the intermediate levels alive: a plain build
+    # holds O(1) n x n matrices, not 2d of them.
+    keep = level_sink is not None
+    t_levels, p_levels = ([t] if keep else []), []
+    for _ in range(1, d_len):
+        if keep:
+            p_levels.append(p)  # P_{lvl-1}, multiplied against by dP_lvl
+        t = mm(t, t)  # S^{2^k}
+        if keep:
+            t_levels.append(t)
+        p = jnp.add(mm(p, t), p)  # P (I + T) = P T + P, no identity materialized
+    if keep:
+        level_sink["t"] = t_levels
+        level_sink["p"] = p_levels[1:]  # P_0 = I + T_0 is applied implicitly
+
+    inv_sqrt = jnp.where(deg > 0, jax.lax.rsqrt(jnp.maximum(deg, 1e-30)), 0.0)
+    p1 = tile_map(
+        ctx,
+        lap._sym_scale_body,
+        p,
+        inv_sqrt,
+        in_specs=(ctx.matrix_spec, P(None)),
+        out_dtype=dtype,
+    )
+    del t, p  # an eager build frees both n x n levels before the P2 GEMM
+    if fuse_l:
+        # P2 = Z^ (D - A) = (Z^ col-scaled by d) - Z^ @ A
+        p1d = tile_map(
+            ctx, _col_scale_body, p1, deg, in_specs=(ctx.matrix_spec, P(None)), out_dtype=dtype
+        )
+        if is_streamable(a):
+            p2 = jnp.subtract(
+                p1d, _matmul_panels_from_store(ctx, p1, a, dtype, prefetch_depth)
+            )
+        else:
+            p2 = jnp.subtract(p1d, mm(p1, a.astype(dtype)))
+    else:
+        l_mat = lap.laplacian(ctx, a, deg, dtype=dtype, prefetch_depth=prefetch_depth)
+        p2 = mm(p1, l_mat)
+    return p1, p2, deg, vol
 
 
 def chain_product(
@@ -268,49 +334,11 @@ def chain_product(
             use_gemm_kernel=use_gemm_kernel,
             level_sink=level_sink,
         )
-    mm = partial(matmul, ctx, schedule=schedule, out_dtype=dtype, use_kernel=use_kernel)
-
-    deg = lap.degrees(ctx, a, prefetch_depth=prefetch_depth)
-    vol = lap.volume(ctx, deg)
-    s = lap.normalized_adjacency(
-        ctx, a, deg, deflate=deflate, dtype=dtype, prefetch_depth=prefetch_depth
+    p1, p2, deg, vol = _resident_chain(
+        ctx, a, d_len, schedule=schedule, dtype=dtype, deflate=deflate,
+        fuse_l=fuse_l, use_kernel=use_kernel, prefetch_depth=prefetch_depth,
+        level_sink=level_sink,
     )
-
-    t = s
-    p = add_scaled_identity(ctx, s, 1.0)  # I + S
-    t_levels, p_levels = [t], []
-    for _ in range(1, d_len):
-        p_levels.append(p)  # P_{lvl-1}, multiplied against by dP_lvl
-        t = mm(t, t)  # S^{2^k}
-        t_levels.append(t)
-        p = jnp.add(mm(p, t), p)  # P (I + T) = P T + P, no identity materialized
-    if level_sink is not None:
-        level_sink["t"] = t_levels
-        level_sink["p"] = p_levels[1:]  # P_0 = I + T_0 is applied implicitly
-
-    inv_sqrt = jnp.where(deg > 0, jax.lax.rsqrt(jnp.maximum(deg, 1e-30)), 0.0)
-    p1 = tile_map(
-        ctx,
-        lap._sym_scale_body,
-        p,
-        inv_sqrt,
-        in_specs=(ctx.matrix_spec, P(None)),
-        out_dtype=dtype,
-    )
-    if fuse_l:
-        # P2 = Z^ (D - A) = (Z^ col-scaled by d) - Z^ @ A
-        p1d = tile_map(
-            ctx, _col_scale_body, p1, deg, in_specs=(ctx.matrix_spec, P(None)), out_dtype=dtype
-        )
-        if is_streamable(a):
-            p2 = jnp.subtract(
-                p1d, _matmul_panels_from_store(ctx, p1, a, dtype, prefetch_depth)
-            )
-        else:
-            p2 = jnp.subtract(p1d, mm(p1, a.astype(dtype)))
-    else:
-        l_mat = lap.laplacian(ctx, a, deg, dtype=dtype, prefetch_depth=prefetch_depth)
-        p2 = mm(p1, l_mat)
     # Measure the Richardson contraction rho(S~^{2^d}) once, while P2 is hot:
     # a handful of eager skinny mat-vecs against the 2(d-1)+1 n^3 GEMMs above.
     # The solve driver reads it for Chebyshev intervals and telemetry.

@@ -24,7 +24,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import rng as crng
 from repro.core.chain import ChainOperator, chain_product
-from repro.core.distmatrix import DistContext
+from repro.core.distmatrix import F32_PRECISION, DistContext
 from repro.core.solvers import SolveReport, SolverSpec, solve
 from repro.core.tiles import is_streamable, tile_map, tile_stream
 from repro.obs import REGISTRY, phase
@@ -277,7 +277,7 @@ def commute_distance_block(
     zj = emb.z[cols].astype(jnp.float32)
     sq_i = jnp.sum(zi * zi, axis=-1)
     sq_j = jnp.sum(zj * zj, axis=-1)
-    cross = zi @ zj.T
+    cross = jnp.dot(zi, zj.T, precision=F32_PRECISION)  # cancels below
     return emb.vol * (sq_i[:, None] + sq_j[None, :] - 2.0 * cross)
 
 

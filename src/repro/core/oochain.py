@@ -49,7 +49,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core import laplacian as lap
 from repro.obs import trace as obs_trace
 from repro.core.chain import ChainOperator
-from repro.core.distmatrix import DistContext
+from repro.core.distmatrix import F32_PRECISION, DistContext
 from repro.core.tiles import (
     cached_program,
     is_streamable,
@@ -110,7 +110,7 @@ def _gemm_step(acc, block, right):
     """acc + block @ right, fp32 accumulate (one K-term of a panel GEMM)."""
     return acc + jnp.dot(
         block.astype(jnp.float32), right.astype(jnp.float32),
-        preferred_element_type=jnp.float32,
+        precision=F32_PRECISION, preferred_element_type=jnp.float32,
     )
 
 
@@ -118,7 +118,7 @@ def _gemm_step(acc, block, right):
 def _gemm_step_neg(acc, block, right):
     return acc - jnp.dot(
         block.astype(jnp.float32), right.astype(jnp.float32),
-        preferred_element_type=jnp.float32,
+        precision=F32_PRECISION, preferred_element_type=jnp.float32,
     )
 
 
@@ -249,7 +249,7 @@ def chain_product_oocore(
     panels ship in their *stored* form where the codec is device-decodable
     (bf16 bit patterns, half the H2D bytes, widened in VMEM) and the
     accumulate folds into the kernel.  Allclose vs the XLA step (same codec);
-    interpret mode off-TPU.  The flag rides on the returned operator so the
+    interpret mode on CPU.  The flag rides on the returned operator so the
     solve driver inherits the kernel path for its streamed iterations.
     """
     from repro.store import (  # deferred: core->store only on this path

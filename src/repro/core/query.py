@@ -85,7 +85,6 @@ def _streamed_topk(
     largest: bool,
     exclude: np.ndarray | None = None,
     prefetch_depth: int | None = None,
-    interpret: bool | None = None,
 ) -> tuple[np.ndarray, np.ndarray, int]:
     """One pass over the artifact's Z panels; returns (vals, ids, n_panels).
 
@@ -127,7 +126,6 @@ def _streamed_topk(
             vals, idx = panel_topk_update(
                 vals, idx, zq_dev, zp, idq, idp, vol, row0, ex,
                 topk=topk, corrected=corrected, largest=largest,
-                interpret=interpret,
             )
             n_panels += 1
     return np.asarray(vals), np.asarray(idx), n_panels
@@ -159,7 +157,6 @@ def top_anomalies_from_store(
     emb_id: str | None = None,
     corrected: bool = False,
     prefetch_depth: int | None = None,
-    interpret: bool | None = None,
 ) -> QueryResult:
     """The k most anomalous nodes of one committed embedding artifact.
 
@@ -181,7 +178,7 @@ def top_anomalies_from_store(
         return _streamed_topk(
             handle, zq, inv_q,
             topk=k, corrected=corrected, largest=True,
-            prefetch_depth=prefetch_depth, interpret=interpret,
+            prefetch_depth=prefetch_depth,
         )
 
     vals, ids, n_panels, bytes_read, dt_ms = _run_query(
@@ -201,7 +198,6 @@ def nearest_neighbors(
     emb_id: str | None = None,
     corrected: bool = False,
     prefetch_depth: int | None = None,
-    interpret: bool | None = None,
 ) -> QueryResult:
     """The k nearest (smallest commute distance) neighbors of ``node``,
     self excluded in-kernel.  Same streaming contract as
@@ -218,7 +214,6 @@ def nearest_neighbors(
             handle, zq, inv_q,
             topk=min(k, n - 1), corrected=corrected, largest=False,
             exclude=exclude, prefetch_depth=prefetch_depth,
-            interpret=interpret,
         )
 
     vals, ids, n_panels, bytes_read, dt_ms = _run_query(

@@ -73,7 +73,8 @@ def edge_rademacher(
     lo = jnp.minimum(rows, cols)
     hi = jnp.maximum(rows, cols)
     h = hash_u32(_u32(seed), lo, hi, _u32(col_id))
-    base = 1.0 - 2.0 * (h >> 31).astype(jnp.float32)  # +/-1 from top bit
+    # +/-1 from the top bit (via int32: Mosaic has no uint32 -> f32 cast)
+    base = 1.0 - 2.0 * (h >> 31).astype(jnp.int32).astype(jnp.float32)
     orient = jnp.where(rows < cols, 1.0, -1.0).astype(jnp.float32)
     return jnp.where(rows == cols, 0.0, base * orient)
 

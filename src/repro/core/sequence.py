@@ -22,9 +22,7 @@ executor -- adjacencies stay on host/disk and devices only ever hold two row
 is bounded by host/disk capacity rather than HBM.
 
 A streaming global top-k across all transitions is maintained by merging each
-transition's top-k into the running global top-k over 2k candidates.  The
-merge runs on the host: the candidates are partially-replicated k-vectors,
-and eager concatenation on those sums replicas on jax 0.4.x (see ROADMAP).
+transition's top-k into the running global top-k over 2k candidates.
 """
 
 from __future__ import annotations
@@ -37,6 +35,7 @@ from typing import Iterable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.core import chain
 from repro.core.cad import CADResult, node_anomaly_scores, top_anomalies
@@ -107,37 +106,26 @@ class SequenceDetector:
         self._metrics: list[dict] = []
         self._warmup_metrics: dict | None = None
         self._builds0 = chain.chain_build_count()
-        self._g_val: np.ndarray | None = None
-        self._g_idx: np.ndarray | None = None
-        self._g_step: np.ndarray | None = None
+        self._g_val: jax.Array | None = None
+        self._g_idx: jax.Array | None = None
+        self._g_step: jax.Array | None = None
 
     # -- streaming global top-k ---------------------------------------------
 
     def _merge_topk(self, idx, val, step: int) -> None:
-        """Merge one transition's top-k into the running global top-k, on host.
+        """Merge one transition's top-k into the running global top-k.
 
-        Host-side on purpose (the jax 0.4.x partial-replication bug, see
-        ROADMAP / tile_stream): the per-transition candidates are (k,)
-        vectors sharded ``P(row_axes)`` -- *partially replicated* over the
-        column mesh axes -- and eager ``jnp.concatenate`` on such inputs SUMS
-        the replicas on jax 0.4.37 (observed: every candidate doubled on a
-        2x2 mesh).  The candidates are k elements, so the host round-trip is
-        free; ties break toward the lower candidate index, exactly like
-        ``lax.top_k``.
+        Ties keep candidate order (``lax.top_k``): the running entries, then
+        this transition's in rank order.
         """
-        idx = np.asarray(idx)
-        val = np.asarray(val)
-        step_arr = np.full_like(idx, step)
-        if self._g_val is None:
-            cand_val, cand_idx, cand_step = val, idx, step_arr
-        else:
-            cand_val = np.concatenate([self._g_val, val])
-            cand_idx = np.concatenate([self._g_idx, idx])
-            cand_step = np.concatenate([self._g_step, step_arr])
-        pos = np.argsort(-cand_val, kind="stable")[: self.top_k]
-        self._g_val = cand_val[pos]
-        self._g_idx = cand_idx[pos]
-        self._g_step = cand_step[pos]
+        step_arr = jnp.full(idx.shape, step, jnp.int32)
+        if self._g_val is not None:
+            val = jnp.concatenate([self._g_val, val])
+            idx = jnp.concatenate([self._g_idx, idx])
+            step_arr = jnp.concatenate([self._g_step, step_arr])
+        self._g_val, pos = lax.top_k(val, min(self.top_k, val.shape[0]))
+        self._g_idx = idx[pos]
+        self._g_step = step_arr[pos]
 
     # -- snapshot lifecycle --------------------------------------------------
 
@@ -320,9 +308,9 @@ class SequenceDetector:
             )
         return SequenceResult(
             transitions=self._transitions,
-            global_top_idx=jnp.asarray(self._g_idx),
-            global_top_val=jnp.asarray(self._g_val),
-            global_top_step=jnp.asarray(self._g_step),
+            global_top_idx=self._g_idx,
+            global_top_val=self._g_val,
+            global_top_step=self._g_step,
             n_snapshots=self._t,
             chain_builds=chain.chain_build_count() - self._builds0,
             transition_seconds=self._seconds,

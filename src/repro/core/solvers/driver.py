@@ -71,12 +71,11 @@ from jax import lax
 
 from jax.sharding import PartitionSpec as P
 
-from repro.core.distmatrix import DistContext, matmul_rowblock
+from repro.core.distmatrix import F32_PRECISION, DistContext, matmul_rowblock
 from repro.core.solvers.base import SolveReport, SolverSpec
 from repro.obs import trace as obs_trace
 from repro.obs.metrics import REGISTRY as _OBS_REGISTRY
 from repro.core.tiles import (
-    _axes_index,
     cached_program,
     is_streamable,
     program_cache_stats,
@@ -202,11 +201,14 @@ def _resident_program(ctx: DistContext, method: str, deflate: bool, chi,
     def build():
         def matvec(p2, y, u2, v2):
             # identical op sequence to matmul_rowblock's resident branch
-            out = jnp.dot(p2, y.astype(jnp.float32), preferred_element_type=jnp.float32)
+            out = jnp.dot(
+                p2, y.astype(jnp.float32),
+                precision=F32_PRECISION, preferred_element_type=jnp.float32,
+            )
             if corr_rank is not None:
                 out = out + jnp.dot(
-                    u2, jnp.dot(v2.T, y.astype(jnp.float32)),
-                    preferred_element_type=jnp.float32,
+                    u2, jnp.dot(v2.T, y.astype(jnp.float32), precision=F32_PRECISION),
+                    precision=F32_PRECISION, preferred_element_type=jnp.float32,
                 )
             return ctx.constrain(out.astype(y.dtype), ctx.rowblock_spec)
 
@@ -382,32 +384,30 @@ def _kernel_panel_program(ctx, ph: int, n: int, k: int, panel_dtype: str,
 
         def local(r0, p_blk, y_rep, *rest):
             program_cache_stats().note_trace()
-            row0 = r0 + _axes_index(ctx, ctx.row_axes) * pr
+            row0 = r0 + lax.axis_index(ctx.row_axes) * pr
             if C == 1:
                 y_cols = y_rep
             else:
-                c = _axes_index(ctx, ctx.col_axes)
+                c = lax.axis_index(ctx.col_axes)
                 y_cols = lax.dynamic_slice(y_rep, (c * pc, jnp.int32(0)), (pc, k))
+            # The psums over a size-1 axis (C == 1, R == 1) move no data; they
+            # make the outputs invariant over that axis, as out_specs require.
             if not fused:
-                mv = stream_gemm(p_blk, y_cols)
-                if C > 1:
-                    mv = lax.psum(mv, ctx.col_axes)
-                return mv
+                return lax.psum(stream_gemm(p_blk, y_cols), ctx.col_axes)
             (chi_rep,) = rest
             y_rows = lax.dynamic_slice(y_rep, (row0, jnp.int32(0)), (pr, k))
             chi_rows = lax.dynamic_slice(chi_rep, (row0, jnp.int32(0)), (pr, k))
             if C == 1:
-                gy, cs, ss = fused_panel_matvec(p_blk, y_cols, chi_rows, y_rows)
+                gy, cs, ss = lax.psum(
+                    fused_panel_matvec(p_blk, y_cols, chi_rows, y_rows), ctx.col_axes
+                )
             else:
                 mv = lax.psum(stream_gemm(p_blk, y_cols), ctx.col_axes)
                 gy = chi_rows + y_rows - mv
                 delta = chi_rows - mv
                 cs = jnp.sum(delta, axis=0, keepdims=True)
                 ss = jnp.sum(delta * delta).reshape(1, 1)
-            if R > 1:
-                cs = lax.psum(cs, ctx.row_axes)
-                ss = lax.psum(ss, ctx.row_axes)
-            return gy, cs, ss
+            return gy, *lax.psum((cs, ss), ctx.row_axes)
 
         out_specs = P(ctx.row_axes, None)
         if fused:
@@ -435,9 +435,7 @@ def _kernel_stream_pass(ctx, handle, y, chi, *, depth, fused):
     moments of ``delta = chi - P2 y`` reduced over all n rows -- so the
     iteration costs exactly this one pass over the stream.  ``fused=False``
     returns the plain mat-vec (the chi build / CG direction product).
-    Per-panel outputs are host-concatenated (eager concatenate on
-    partially-replicated shards is unsafe on jax 0.4.x) and re-put with the
-    solver's rowblock sharding.
+    Per-panel outputs are concatenated in the solver's rowblock sharding.
     """
     from repro.store import PanelPipeline  # deferred: optional path
 
@@ -473,10 +471,8 @@ def _kernel_stream_pass(ctx, handle, y, chi, *, depth, fused):
             else:
                 gy_p = prog(jnp.int32(r0), panel, y_rep)
             st._note_live(pipe.device_live_bytes + gy_p.nbytes)
-            parts.append(np.asarray(gy_p))
-    out = jax.device_put(
-        np.concatenate(parts, axis=0), ctx.sharding(ctx.rowblock_spec)
-    )
+            parts.append(gy_p)
+    out = ctx.constrain(jnp.concatenate(parts, axis=0), ctx.rowblock_spec)
     if fused:
         return out, cs_total, ss_total
     return out
@@ -499,8 +495,8 @@ def _solve_streamed(
         """The delta correction u2 (v2^T x): device-resident factors, eager
         skinny products -- never touches the panel stream."""
         return jnp.dot(
-            u2, jnp.dot(v2.T, x.astype(jnp.float32)),
-            preferred_element_type=jnp.float32,
+            u2, jnp.dot(v2.T, x.astype(jnp.float32), precision=F32_PRECISION),
+            precision=F32_PRECISION, preferred_element_type=jnp.float32,
         )
 
     def stream_matvec(x):
@@ -764,8 +760,8 @@ def solve(
             chi = (
                 chi.astype(jnp.float32) * scale_col
                 + jnp.dot(
-                    u1, jnp.dot(v1.T, b.astype(jnp.float32)),
-                    preferred_element_type=jnp.float32,
+                    u1, jnp.dot(v1.T, b.astype(jnp.float32), precision=F32_PRECISION),
+                    precision=F32_PRECISION, preferred_element_type=jnp.float32,
                 )
             ).astype(b.dtype)
             chi = ctx.constrain(chi, ctx.rowblock_spec)

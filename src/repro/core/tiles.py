@@ -24,16 +24,15 @@ two panels per streamed operand, not by n^2 -- the row-parallel tile programs
 identical to their resident runs because each output row sees exactly the
 same per-device reduction extents either way.
 
-This module also owns the version-compat shims for the manual-sharding API
-(``jax.shard_map`` vs ``jax.experimental.shard_map``; ``lax.pcast`` /
-``lax.pvary`` vs nothing) so the rest of the core is version-agnostic.
+This module also owns the thin ``shard_map`` / ``pcast_varying`` helpers
+the rest of the core builds its manual-sharding programs with.
 """
 
 from __future__ import annotations
 
 from collections import OrderedDict
 from dataclasses import dataclass
-from typing import Any, Callable, Sequence
+from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -46,58 +45,25 @@ from repro.obs.metrics import REGISTRY as _OBS_REGISTRY
 from repro.obs.metrics import MetricsRegistry
 
 # ---------------------------------------------------------------------------
-# version compat: manual-sharding API surface
+# manual-sharding API
 # ---------------------------------------------------------------------------
 
-try:  # jax >= 0.5: top-level export with varying-type checking built in
-    _shard_map = jax.shard_map
-    _COMPAT_KWARGS: dict[str, Any] = {}
-except AttributeError:  # jax 0.4.x: experimental module; disable rep checking
-    from jax.experimental.shard_map import shard_map as _shard_map
 
-    _COMPAT_KWARGS = {"check_rep": False}
+def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None, check=True):
+    """``jax.shard_map`` with varying-type checking on by default.
 
-import inspect as _inspect
-
-_SHARD_MAP_PARAMS = frozenset(_inspect.signature(_shard_map).parameters)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None, check=None):
-    """``jax.shard_map`` across jax versions (old versions skip rep checks).
-
-    ``axis_names`` restricts manual sharding to those mesh axes (mapped to the
-    old API's complementary ``auto=`` set); ``check=False`` disables varying-
-    type checking where the installed jax supports toggling it.
+    ``axis_names`` restricts manual sharding to those mesh axes;
+    ``check=False`` turns varying-type checking off.
     """
-    kw = dict(_COMPAT_KWARGS)
-    if check is not None:
-        if "check_vma" in _SHARD_MAP_PARAMS:
-            kw["check_vma"] = check
-        elif "check_rep" in _SHARD_MAP_PARAMS:
-            kw["check_rep"] = check
-    if axis_names is not None:
-        if "axis_names" in _SHARD_MAP_PARAMS:
-            kw["axis_names"] = set(axis_names)
-        elif "auto" in _SHARD_MAP_PARAMS:
-            kw["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+    kw = {} if axis_names is None else {"axis_names": set(axis_names)}
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check, **kw
+    )
 
 
-if hasattr(lax, "pcast"):
-
-    def pcast_varying(x: jax.Array, axes: Sequence[str]) -> jax.Array:
-        """Mark ``x`` as device-varying over ``axes`` (loop-carry seeding)."""
-        return lax.pcast(x, tuple(axes), to="varying")
-
-elif hasattr(lax, "pvary"):
-
-    def pcast_varying(x: jax.Array, axes: Sequence[str]) -> jax.Array:
-        return lax.pvary(x, tuple(axes))
-
-else:  # old jax with check_rep=False: varying types are not tracked at all
-
-    def pcast_varying(x: jax.Array, axes: Sequence[str]) -> jax.Array:
-        return x
+def pcast_varying(x: jax.Array, axes: Sequence[str]) -> jax.Array:
+    """Mark ``x`` as device-varying over ``axes`` (loop-carry seeding)."""
+    return lax.pcast(x, tuple(axes), to="varying")
 
 
 # ---------------------------------------------------------------------------
@@ -244,24 +210,12 @@ class Tile:
     mesh_axes: tuple[str, ...]  # all manual axes, for loop-carry casts
 
     def varying(self, x: jax.Array) -> jax.Array:
-        """Seed a loop carry with the tile-varying type (no-op on old jax)."""
+        """Seed a loop carry with the tile-varying type."""
         return pcast_varying(x, self.mesh_axes)
 
     def diag_mask(self) -> jax.Array:
         """(pr, pc) bool mask of global-diagonal entries in this tile."""
         return self.rows[:, None] == self.cols[None, :]
-
-
-def _axes_index(ctx, axes: Sequence[str]) -> jax.Array:
-    """Flattened shard index over possibly-multiple mesh axes.
-
-    Folded manually (row-major over ``axes``) instead of
-    ``lax.axis_index(tuple)`` so it works on every jax version.
-    """
-    idx = jnp.int32(0)
-    for a in axes:
-        idx = idx * ctx.mesh.shape[a] + lax.axis_index(a)
-    return idx
 
 
 def _tile_local(
@@ -287,8 +241,9 @@ def _tile_local(
             origin, *blocks = args
         else:
             origin, blocks = jnp.int32(0), args
-        r = _axes_index(ctx, ctx.row_axes)
-        c = _axes_index(ctx, ctx.col_axes)
+        # flattened shard index over the row / col axes, row-major
+        r = lax.axis_index(ctx.row_axes)
+        c = lax.axis_index(ctx.col_axes)
         tile = Tile(
             rows=origin + r * pr + jnp.arange(pr),
             cols=c * pc + jnp.arange(pc),
@@ -724,11 +679,5 @@ def tile_stream(
                 consume(r0, panels)
 
     if reduce == "cols":
-        if len(reduced_outs) == 1:
-            return ctx.constrain(reduced_outs[0], out_spec)
-        # Host-side concat of the small per-panel reductions: jax 0.4.x eager
-        # concatenate on partially-replicated shardings sums the replicas
-        # (observed on 0.4.37); copying through the host is bitwise-safe.
-        out = np.concatenate([np.asarray(o) for o in reduced_outs], axis=0)
-        return jax.device_put(out, ctx.sharding(out_spec))
+        return ctx.constrain(jnp.concatenate(reduced_outs, axis=0), out_spec)
     return buf

@@ -1,4 +1,4 @@
-"""Pallas TPU kernels for the compute hot-spots (validated interpret=True on CPU).
+"""Pallas TPU kernels for the compute hot-spots (interpret mode on CPU).
 
 - ``block_matmul``    -- the paper's per-block GEMM on the MXU (fp32 accum)
 - ``edge_projection`` -- fused sqrt(A).Q row-reduce with in-kernel counter RNG

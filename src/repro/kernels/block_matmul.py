@@ -20,8 +20,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.dispatch import pallas_call
 
 
 def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int):
@@ -29,8 +32,12 @@ def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # float32 operands at full precision (the chain GEMMs); bf16 ones are
+    # exact in the MXU's one pass
+    full = jnp.float32 in (a_ref.dtype, b_ref.dtype)
     acc_ref[...] += jnp.dot(
-        a_ref[...], b_ref[...], preferred_element_type=jnp.float32
+        a_ref[...], b_ref[...], precision=lax.Precision.HIGHEST if full else None,
+        preferred_element_type=jnp.float32,
     )
 
     @pl.when(pl.program_id(2) == k_steps - 1)
@@ -40,7 +47,7 @@ def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("bm", "bk", "bn", "out_dtype", "interpret"),
+    static_argnames=("bm", "bk", "bn", "out_dtype"),
 )
 def block_matmul(
     a: jax.Array,
@@ -50,7 +57,6 @@ def block_matmul(
     bk: int = 256,
     bn: int = 256,
     out_dtype=None,
-    interpret: bool | None = None,
 ) -> jax.Array:
     """C = A @ B, (m,k)x(k,n), tiled for the MXU with fp32 accumulation."""
     m, k = a.shape
@@ -61,11 +67,10 @@ def block_matmul(
     from repro.kernels.tiling import fit
 
     bm, bk, bn = fit(m, bm), fit(k, bk), fit(n, bn)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     grid = (m // bm, n // bn, k // bk)
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_matmul_kernel, k_steps=grid[2]),
+        a, b,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
@@ -74,5 +79,4 @@ def block_matmul(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
-    )(a, b)
+    )

@@ -17,7 +17,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
+
+from repro.kernels.dispatch import pallas_call
 
 
 def _dist_tile(zi, zj, vol):
@@ -25,7 +28,10 @@ def _dist_tile(zi, zj, vol):
     zj = zj.astype(jnp.float32)
     sq_i = jnp.sum(zi * zi, axis=-1)
     sq_j = jnp.sum(zj * zj, axis=-1)
-    cross = jnp.dot(zi, zj.T, preferred_element_type=jnp.float32)
+    # full float32: the expansion below cancels for near pairs
+    cross = jnp.dot(
+        zi, zj.T, precision=lax.Precision.HIGHEST, preferred_element_type=jnp.float32
+    )
     return vol * (sq_i[:, None] + sq_j[None, :] - 2.0 * cross)
 
 
@@ -45,7 +51,7 @@ def _cad_kernel(a1_ref, a2_ref, z1i_ref, z1j_ref, z2i_ref, z2j_ref, v_ref, o_ref
     o_ref[...] += jnp.sum(de, axis=1, keepdims=True)
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bm", "bn"))
 def cad_scores_tile(
     a1: jax.Array,
     a2: jax.Array,
@@ -58,7 +64,6 @@ def cad_scores_tile(
     *,
     bm: int = 256,
     bn: int = 256,
-    interpret: bool | None = None,
 ) -> jax.Array:
     """Partial row scores (m,) for one rectangular (m, n) adjacency tile.
 
@@ -71,12 +76,11 @@ def cad_scores_tile(
     from repro.kernels.tiling import fit
 
     bm, bn = fit(m, bm), fit(n, bn)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     vols = jnp.stack([vol1, vol2]).astype(jnp.float32).reshape(1, 2)
     grid = (m // bm, n // bn)
-    out = pl.pallas_call(
+    out = pallas_call(
         _cad_kernel,
+        a1, a2, z1i, z1j, z2i, z2j, vols,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
@@ -89,8 +93,7 @@ def cad_scores_tile(
         ],
         out_specs=pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, 1), jnp.float32),
-        interpret=interpret,
-    )(a1, a2, z1i, z1j, z2i, z2j, vols)
+    )
     return out[:, 0]
 
 
@@ -104,9 +107,8 @@ def cad_scores(
     *,
     bm: int = 256,
     bn: int = 256,
-    interpret: bool | None = None,
 ) -> jax.Array:
     """Node anomaly scores F (n,) from two embeddings, fused (square case)."""
     return cad_scores_tile(
-        a1, a2, z1, z1, z2, z2, vol1, vol2, bm=bm, bn=bn, interpret=interpret
+        a1, a2, z1, z1, z2, z2, vol1, vol2, bm=bm, bn=bn
     )

@@ -19,9 +19,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 
 from repro.core import rng as crng
+from repro.kernels.dispatch import pallas_call
 
 
 def _edge_proj_kernel(a_ref, o_ref, *, seed: int, k: int, bm: int, bn: int, col_steps: int):
@@ -31,21 +33,20 @@ def _edge_proj_kernel(a_ref, o_ref, *, seed: int, k: int, bm: int, bn: int, col_
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    rows = i * bm + jnp.arange(bm, dtype=jnp.uint32)
-    cols = j * bn + jnp.arange(bn, dtype=jnp.uint32)
+    # 2-D int32 index tiles: Mosaic lowers 2-D iotas (not 1-D -> 3-D
+    # reshapes) and signed min/max (not unsigned); the hash widens to uint32.
+    rows = i * bm + lax.broadcasted_iota(jnp.int32, (bm, bn), 0)
+    cols = j * bn + lax.broadcasted_iota(jnp.int32, (bm, bn), 1)
     s = jnp.sqrt(jnp.maximum(a_ref[...].astype(jnp.float32), 0.0))
-    # (bm, bn, k) Rademacher tile, regenerated -- identical hash to core.rng.
-    q = crng.edge_rademacher(
-        seed,
-        rows[:, None, None],
-        cols[None, :, None],
-        jnp.arange(k, dtype=jnp.uint32)[None, None, :],
-    )
-    o_ref[...] += jnp.einsum("ij,ijc->ic", s, q, preferred_element_type=jnp.float32)
+    # One (bm, bn) Rademacher tile per projection column, regenerated --
+    # identical hash to core.rng -- and row-reduced on the VPU.
+    for c in range(k):
+        q = crng.edge_rademacher(seed, rows, cols, c)
+        o_ref[:, c : c + 1] += jnp.sum(s * q, axis=1, keepdims=True)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("seed", "k", "bm", "bn", "interpret")
+    jax.jit, static_argnames=("seed", "k", "bm", "bn")
 )
 def edge_projection(
     a: jax.Array,
@@ -54,24 +55,21 @@ def edge_projection(
     k: int,
     bm: int = 256,
     bn: int = 256,
-    interpret: bool | None = None,
 ) -> jax.Array:
     """Y (n, k) = B^T W^{1/2} Q with JL 1/sqrt(k) normalization."""
     m, n = a.shape
     from repro.kernels.tiling import fit
 
     bm, bn = fit(m, bm), fit(n, bn)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     grid = (m // bm, n // bn)
-    y = pl.pallas_call(
+    y = pallas_call(
         functools.partial(
             _edge_proj_kernel, seed=seed, k=k, bm=bm, bn=bn, col_steps=grid[1]
         ),
+        a,
         grid=grid,
         in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, k), jnp.float32),
-        interpret=interpret,
-    )(a)
+    )
     return y * (1.0 / jnp.sqrt(jnp.float32(k)))

@@ -40,6 +40,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.dispatch import pallas_call
 from repro.kernels.stream_gemm import _dec
 
 
@@ -86,8 +87,10 @@ def _panel_topk_kernel(
     zb = _dec(zp_ref[...], enc)
     sq_q = jnp.sum(zq * zq, axis=-1, keepdims=True)
     sq_j = jnp.sum(zb * zb, axis=-1)[None, :]
+    # Full float32 contraction: the expansion cancels for near neighbours,
+    # where one bf16 pass leaves errors of ~1e-2 of the distance.
     dist2 = sq_q + sq_j - 2.0 * jnp.dot(
-        zq, zb.T, preferred_element_type=jnp.float32
+        zq, zb.T, precision=lax.Precision.HIGHEST, preferred_element_type=jnp.float32
     )
     dist2 = jnp.maximum(dist2, 0.0)  # clamp the rank-1 cancellation noise
     if corrected:
@@ -126,7 +129,7 @@ def topk_init(nq: int, topk: int, *, largest: bool) -> tuple[jax.Array, jax.Arra
 
 
 @functools.partial(
-    jax.jit, static_argnames=("topk", "corrected", "largest", "interpret")
+    jax.jit, static_argnames=("topk", "corrected", "largest")
 )
 def panel_topk_update(
     run_vals: jax.Array,
@@ -142,7 +145,6 @@ def panel_topk_update(
     topk: int,
     corrected: bool = False,
     largest: bool = True,
-    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Merge one Z row panel into the running per-query top-k.
 
@@ -180,8 +182,6 @@ def panel_topk_update(
     from repro.kernels.tiling import fit
 
     bj = fit(ph, 256)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     grid = (ph // bj,)
     vol2 = jnp.asarray(vol, jnp.float32).reshape(1, 1)
     row02 = jnp.asarray(row0, jnp.int32).reshape(1, 1)
@@ -190,8 +190,10 @@ def panel_topk_update(
         k_steps=grid[0], bj=bj, topk=topk,
         enc=z_panel.dtype == jnp.uint16, corrected=corrected, largest=largest,
     )
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
+        zq, z_panel, inv_deg_q, inv_deg_panel, vol2, row02, exclude,
+        run_vals, run_idx,
         grid=grid,
         in_specs=[
             pl.BlockSpec((q, kdim), lambda kk: (0, 0)),
@@ -216,8 +218,4 @@ def panel_topk_update(
             pltpu.VMEM((q, topk), jnp.float32),
             pltpu.VMEM((q, topk), jnp.int32),
         ],
-        interpret=interpret,
-    )(
-        zq, z_panel, inv_deg_q, inv_deg_panel, vol2, row02, exclude,
-        run_vals, run_idx,
     )

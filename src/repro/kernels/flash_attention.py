@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.dispatch import pallas_call
+
 _NEG_INF = -1e30
 
 
@@ -65,7 +67,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *, bq, bk, 
 
 
 @functools.partial(
-    jax.jit, static_argnames=("causal", "bq", "bk", "interpret")
+    jax.jit, static_argnames=("causal", "bq", "bk")
 )
 def flash_attention(
     q: jax.Array,
@@ -75,7 +77,6 @@ def flash_attention(
     causal: bool = True,
     bq: int = 256,
     bk: int = 256,
-    interpret: bool | None = None,
 ) -> jax.Array:
     """(BH, S, D) x (BH, T, D) x (BH, T, D) -> (BH, S, D) flash attention."""
     bh, s, d = q.shape
@@ -83,14 +84,13 @@ def flash_attention(
     from repro.kernels.tiling import fit
 
     bq, bk = fit(s, bq), fit(t, bk)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     scale = 1.0 / (d**0.5)
     grid = (bh, s // bq, t // bk)
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(
             _flash_kernel, bq=bq, bk=bk, scale=scale, causal=causal, kv_steps=grid[2]
         ),
+        q, k, v,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda h, i, j: (h, i, 0)),
@@ -104,5 +104,4 @@ def flash_attention(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        interpret=interpret,
-    )(q, k, v)
+    )

@@ -1,8 +1,9 @@
 """Jit'd public wrappers over the Pallas kernels.
 
-``interpret=None`` auto-selects: compiled Pallas on TPU, interpret-mode
-(Python execution of the kernel body) on CPU -- so the same call sites run
-everywhere and tests exercise the kernel bodies on this CPU container.
+Every kernel runs compiled on TPU and in interpret mode on CPU, chosen by
+the platform its caller is lowered for (:mod:`repro.kernels.dispatch`) -- so
+the same call sites run everywhere and tests exercise the kernel bodies on
+the CPU.
 """
 
 from __future__ import annotations

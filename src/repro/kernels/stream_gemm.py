@@ -28,7 +28,7 @@ executors (``core/oochain.py`` GEMM steps, the streamed solve driver):
 Numerics: fp32 accumulation in VMEM scratch regardless of input encoding.
 With unblocked K the ``init``-form is bitwise identical to the XLA
 ``acc + dot`` step; blocked K reorders the reduction (allclose).  Interpret
-mode runs the same kernel bodies on non-TPU backends.
+mode runs the same kernel bodies on the CPU (see :mod:`repro.kernels.dispatch`).
 """
 
 from __future__ import annotations
@@ -40,6 +40,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.dispatch import pallas_call
 
 
 def _dec(x, encoded: bool):
@@ -54,14 +56,22 @@ def _dec(x, encoded: bool):
     return x.astype(jnp.float32)
 
 
+def _dot(a, b, exact_bf16: bool):
+    """fp32-accumulated ``a @ b``: full float32 precision, except where both
+    operands are decoded bf16 and the MXU's one bf16 pass is already exact."""
+    return jnp.dot(
+        a, b, precision=None if exact_bf16 else lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
+
+
 def _stream_gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps, a_enc, b_enc, neg):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(
-        _dec(a_ref[...], a_enc), _dec(b_ref[...], b_enc),
-        preferred_element_type=jnp.float32,
+    acc_ref[...] += _dot(
+        _dec(a_ref[...], a_enc), _dec(b_ref[...], b_enc), a_enc and b_enc
     )
 
     @pl.when(pl.program_id(2) == k_steps - 1)
@@ -77,9 +87,8 @@ def _stream_gemm_init_kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(
-        _dec(a_ref[...], a_enc), _dec(b_ref[...], b_enc),
-        preferred_element_type=jnp.float32,
+    acc_ref[...] += _dot(
+        _dec(a_ref[...], a_enc), _dec(b_ref[...], b_enc), a_enc and b_enc
     )
 
     @pl.when(pl.program_id(2) == k_steps - 1)
@@ -91,7 +100,7 @@ def _stream_gemm_init_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("sign", "bm", "bk", "bn", "out_dtype", "interpret"),
+    static_argnames=("sign", "bm", "bk", "bn", "out_dtype"),
 )
 def stream_gemm(
     a: jax.Array,
@@ -103,7 +112,6 @@ def stream_gemm(
     bk: int = 256,
     bn: int = 256,
     out_dtype=jnp.float32,
-    interpret: bool | None = None,
 ) -> jax.Array:
     """``init + sign * (A @ B)`` (init optional), fp32 accumulation.
 
@@ -126,8 +134,6 @@ def stream_gemm(
     from repro.kernels.tiling import fit
 
     bm, bk, bn = fit(m, bm), fit(k, bk), fit(n, bn)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     grid = (m // bm, n // bn, k // bk)
     in_specs = [
         pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
@@ -141,15 +147,15 @@ def stream_gemm(
         kernel = functools.partial(_stream_gemm_init_kernel, **kwargs)
         in_specs.append(pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)))
         operands.append(init)
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
+        *operands,
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
-    )(*operands)
+    )
 
 
 def _fused_matvec_kernel(
@@ -170,10 +176,7 @@ def _fused_matvec_kernel(
         cs_ref[...] = jnp.zeros_like(cs_ref)
         ss_ref[...] = jnp.zeros_like(ss_ref)
 
-    acc_ref[...] += jnp.dot(
-        _dec(p_ref[...], enc), y_ref[...].astype(jnp.float32),
-        preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += _dot(_dec(p_ref[...], enc), y_ref[...].astype(jnp.float32), False)
 
     @pl.when(kk == k_steps - 1)
     def _epilogue():
@@ -185,7 +188,7 @@ def _fused_matvec_kernel(
         ss_ref[...] += jnp.sum(delta * delta).reshape(1, 1)
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bm", "bk"))
 def fused_panel_matvec(
     p_panel: jax.Array,
     y: jax.Array,
@@ -194,7 +197,6 @@ def fused_panel_matvec(
     *,
     bm: int = 256,
     bk: int = 256,
-    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One fused solve-iteration pass over a P2 row panel.
 
@@ -222,12 +224,11 @@ def fused_panel_matvec(
     from repro.kernels.tiling import fit
 
     bm, bk = fit(ph, bm), fit(kdim, bk)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     grid = (ph // bm, kdim // bk)
     k_steps = grid[1]
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_fused_matvec_kernel, k_steps=k_steps, enc=enc),
+        p_panel, y, chi_panel, y_panel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, kk: (i, kk)),
@@ -246,5 +247,4 @@ def fused_panel_matvec(
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ),
         scratch_shapes=[pltpu.VMEM((bm, q), jnp.float32)],
-        interpret=interpret,
-    )(p_panel, y, chi_panel, y_panel)
+    )

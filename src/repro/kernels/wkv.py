@@ -30,6 +30,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.dispatch import pallas_call
+
 
 def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_ref, *, chunk: int):
     ci = pl.program_id(1)
@@ -67,19 +69,18 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_ref, *, chunk: int)
     o_ref[0] = y.astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def wkv(r, k, v, lw, u, *, chunk: int = 128, interpret: bool | None = None):
+@functools.partial(jax.jit, static_argnames=("chunk",))
+def wkv(r, k, v, lw, u, *, chunk: int = 128):
     """(BH, S, dk) x ... -> (BH, S, dv); u (BH, dk) bonus."""
     bh, s, dk = r.shape
     dv = v.shape[-1]
     from repro.kernels.tiling import fit
 
     c = fit(s, chunk)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     grid = (bh, s // c)
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_wkv_kernel, chunk=c),
+        r, k, v, lw, u[:, None, :],
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, c, dk), lambda h, i: (h, i, 0)),
@@ -91,5 +92,4 @@ def wkv(r, k, v, lw, u, *, chunk: int = 128, interpret: bool | None = None):
         out_specs=pl.BlockSpec((1, c, dv), lambda h, i: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s, dv), r.dtype),
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        interpret=interpret,
-    )(r, k, v, lw, u[:, None, :])
+    )

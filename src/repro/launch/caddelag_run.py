@@ -23,7 +23,8 @@ import numpy as np
 
 from repro.core import CommuteConfig, SequenceDetector, make_context, reset_stream_stats, stream_stats
 from repro.graphs import climate_snapshot_sequence, gmm_snapshot_sequence, store_snapshot_sequence
-from repro.launch.mesh import make_cpu_mesh
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 
 
 def _default_grid(n: int, n_row_shards: int) -> int:
@@ -34,7 +35,7 @@ def _default_grid(n: int, n_row_shards: int) -> int:
     return 1
 
 
-def main():
+def main(argv: list[str] | None = None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=256, help="graph nodes")
     ap.add_argument("--t-steps", type=int, default=2, help="snapshots in the sequence")
@@ -83,8 +84,8 @@ def main():
                          "chain and solver: panels ship in stored form (bf16 "
                          "bit patterns decode on-device, halving H2D) and "
                          "each streamed solve iteration is one fused pass "
-                         "over the P2 scratch; interpret-mode fallback "
-                         "off-TPU, no effect without --oocore-chain")
+                         "over the P2 scratch; interpret mode on CPU, "
+                         "no effect without --oocore-chain")
     ap.add_argument("--solver-batch", type=int, default=1,
                     help="solver iterations per scratch stream of P2: the "
                          "solver streams the store once per batch and replays "
@@ -146,11 +147,12 @@ def main():
     ap.add_argument("--strict-convergence", action="store_true",
                     help="exit nonzero (code 2) if any transition's solve "
                          "finished NOT-CONVERGED")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     from repro.obs import enable_tracing, tracer
     from repro.obs.report import build_run_report, save_run_report
 
+    enable_compile_cache()
     if args.trace is not None:
         enable_tracing(fence=True)
 
@@ -161,7 +163,7 @@ def main():
 
     effective_codec = resolve_codec(args.tile_codec).name
 
-    mesh = make_cpu_mesh(data=args.data, model=args.model)
+    mesh = make_mesh(data=args.data, model=args.model)
     ctx = make_context(mesh)
     cfg = CommuteConfig(eps_rp=args.eps, d=args.d, q=args.q, schedule=args.schedule,
                         oocore=args.oocore_chain, oocore_dir=args.oocore_dir,

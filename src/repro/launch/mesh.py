@@ -14,7 +14,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -23,13 +23,20 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     return jax.make_mesh(shape, axes)
 
 
-def make_cpu_mesh(data: int = 1, model: int = 1, pod: int = 0) -> Mesh:
-    """Small mesh over however many (host) devices exist -- tests & examples."""
-    n = (pod or 1) * data * model
-    devs = np.array(jax.devices()[:n])
-    if pod:
-        return Mesh(devs.reshape(pod, data, model), ("pod", "data", "model"))
-    return Mesh(devs.reshape(data, model), ("data", "model"))
+def make_mesh(data: int = 1, model: int = 1, pod: int = 0) -> Mesh:
+    """A ``data`` x ``model`` (x ``pod``) mesh over the first devices.
+
+    ``jax.make_mesh`` orders the devices by the physical topology (a 2x2 v5e
+    host gets ring neighbours on each axis); the axes are ``Auto``, as every
+    program here shards through ``NamedSharding`` and ``shard_map``.
+    """
+    shape = (pod, data, model) if pod else (data, model)
+    axes = ("pod", "data", "model") if pod else ("data", "model")
+    n = int(np.prod(shape))
+    return jax.make_mesh(
+        shape, axes, devices=jax.devices()[:n],
+        axis_types=(AxisType.Auto,) * len(axes),
+    )
 
 
 def mesh_chip_count(mesh: Mesh) -> int:

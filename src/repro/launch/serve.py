@@ -14,7 +14,7 @@ import jax
 import numpy as np
 
 from repro import configs
-from repro.launch.mesh import make_cpu_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import lm
 from repro.serving import ServeConfig, ServeEngine
 
@@ -32,7 +32,7 @@ def main():
     args = ap.parse_args()
 
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get_config(args.arch)
-    mesh = make_cpu_mesh(data=args.data, model=args.model)
+    mesh = make_mesh(data=args.data, model=args.model)
     spec = lm.build_spec(cfg)
     params = lm.init_params(spec, jax.random.PRNGKey(0))
 

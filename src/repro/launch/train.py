@@ -27,7 +27,7 @@ from jax.sharding import NamedSharding
 
 from repro import configs
 from repro.data import DataConfig, host_batch
-from repro.launch.mesh import make_cpu_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import common as cm
 from repro.models import lm
 from repro.training import (
@@ -125,7 +125,7 @@ def main():
     args = ap.parse_args()
 
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get_config(args.arch)
-    mesh = make_cpu_mesh(data=args.data, model=args.model)
+    mesh = make_mesh(data=args.data, model=args.model)
 
     try:
         _, _, losses = train_loop(

@@ -96,13 +96,17 @@ def build_run_report(
     n: int | None = None,
     k_rp: int | None = None,
     reg: MetricsRegistry | None = None,
+    device_kind: str | None = None,
+    peaks: Mapping[str, float] | None = None,
 ) -> dict[str, Any]:
     """Assemble the versioned run-report document for a finished sequence run.
 
     ``result`` is a :class:`~repro.core.sequence.SequenceResult`;
     ``config`` is whatever JSON-serializable run configuration the caller
     wants embedded (the CLI passes its resolved argument dict).  ``n`` and
-    ``k_rp`` enable the streamed-solve roofline attribution when given.
+    ``k_rp`` enable the streamed-solve roofline attribution when given;
+    ``device_kind`` (default: the first JAX device's) picks its published
+    peaks, and ``peaks`` overrides them (see :mod:`repro.obs.roofline`).
     Registry totals are read at call time, so build the report at end of run,
     after the last transition.
     """
@@ -181,6 +185,10 @@ def build_run_report(
         s for rec in transitions for s in rec["solves"] if s["streamed"]
     ]
     if streamed and n and k_rp:
+        if device_kind is None:
+            import jax
+
+            device_kind = jax.devices()[0].device_kind
         solve_seconds = totals["phases"]["solve"]
         roofline = streamed_solve_roofline(
             bytes_read=float(sum(s["bytes_read"] for s in streamed)),
@@ -189,6 +197,8 @@ def build_run_report(
                 sum(streamed_solve_flops(n, k_rp, s["iterations"]) for s in streamed)
             ),
             seconds=solve_seconds,
+            device_kind=device_kind,
+            peaks=peaks,
         )
 
     return {

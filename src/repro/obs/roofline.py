@@ -3,19 +3,26 @@
 The out-of-core solve is bound by whichever of scratch-disk read, host->device
 staging, or MXU FLOPs saturates first.  All three terms come from measured
 traffic (the ``stream.*`` byte counters) plus the iteration count, so run
-reports and benchmarks can state measured-vs-bound directly.  Lived in
-``benchmarks/roofline.py`` through PR 6; moved here so ``obs/report.py`` can
-attribute a roofline fraction per run without importing the benchmarks tree.
+reports and benchmarks can state measured-vs-bound directly.
+
+The compute term needs the device's peak, and only a published one counts:
+:data:`PEAKS` is keyed by ``jax.Device.device_kind``, and a device that is
+not in it gets no roofline fraction ("not measured") rather than a borrowed
+peak.  The disk and H2D bandwidths are assumed tiers, not device peaks.
 """
 
 from __future__ import annotations
 
-PEAK_FLOPS = 197e12  # bf16 / chip (TPU v5e-class)
-DISK_BW = 2.0e9  # bytes/s sustained scratch-store read (NVMe-class)
-H2D_BW = 32e9  # bytes/s host->device staging (PCIe gen4 x16-class)
+# Published peaks of one chip, by device_kind.  Source: Google Cloud
+# documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM bandwidth.
+PEAKS = {"TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9}}
+NOT_MEASURED = "not measured"
+DISK_BW = 2.0e9  # bytes/s sustained scratch-store read (NVMe-class, assumed)
+H2D_BW = 32e9  # bytes/s host->device staging (PCIe gen4 x16-class, assumed)
 
 __all__ = [
-    "PEAK_FLOPS",
+    "PEAKS",
+    "NOT_MEASURED",
     "DISK_BW",
     "H2D_BW",
     "streamed_solve_flops",
@@ -35,26 +42,36 @@ def streamed_solve_roofline(
     bytes_h2d: float,
     flops: float,
     seconds: float,
+    device_kind: str,
+    peaks: dict | None = None,
     disk_bw: float = DISK_BW,
     h2d_bw: float = H2D_BW,
-    peak_flops: float = PEAK_FLOPS,
 ) -> dict:
     """Three-term bound for a streamed solve, from measured traffic.
 
     ``bound_s = max(read/disk_bw, h2d/h2d_bw, flops/peak)`` is the fastest
     the solve could have gone on the modeled hardware; ``roofline_frac =
-    bound_s / seconds`` is the fraction of that bound actually achieved
-    (CPU-interpret runs will sit far below 1 -- the *trajectory* of the
-    fraction and of the byte terms across PRs is the signal, the absolute
-    value only means something on real accelerator + NVMe tiers).
+    bound_s / seconds`` is the fraction of that bound actually achieved.
+    ``peaks`` defaults to the published entry for ``device_kind``; a device
+    without one gets ``roofline_frac == NOT_MEASURED`` and no bound.
     """
+    peaks = PEAKS.get(device_kind) if peaks is None else peaks
     t_disk = bytes_read / disk_bw
     t_h2d = bytes_h2d / h2d_bw
-    t_flop = flops / peak_flops
+    if peaks is None:
+        return {
+            "device_kind": device_kind,
+            "t_disk_s": t_disk,
+            "t_h2d_s": t_h2d,
+            "measured_s": seconds,
+            "roofline_frac": NOT_MEASURED,
+        }
+    t_flop = flops / peaks["flops"]
     bound_s, bound = max(
         (t_disk, "disk"), (t_h2d, "h2d"), (t_flop, "compute")
     )
     return {
+        "device_kind": device_kind,
         "t_disk_s": t_disk,
         "t_h2d_s": t_h2d,
         "t_compute_s": t_flop,

@@ -108,13 +108,13 @@ class _Span:
 
 
 def _block_until_ready(value: Any) -> None:
-    try:
-        import jax
+    """Wait for the JAX arrays in ``value``; other leaves (store handles,
+    host arrays) are already ready.  Device faults raise here."""
+    import jax
 
-        jax.block_until_ready(value)
-    except Exception:
-        # Non-jax payloads (store handles, host arrays) are already "ready".
-        pass
+    jax.block_until_ready(
+        [x for x in jax.tree.leaves(value) if isinstance(x, jax.Array)]
+    )
 
 
 class Tracer:

@@ -37,11 +37,12 @@ its own ``device_put`` loop.  :class:`PanelPipeline` owns the pattern once:
   to fp32 inside the kernel -- with the transfer gap accounted in
   ``bytes_h2d_saved``.  Sources without an encoded read degrade to the
   decoded panel (nothing saved, nothing broken);
-* **pinned-host staging** where the backend supports it: staged panels hop
-  through the ``pinned_host`` memory space so the H2D copy is an async DMA
-  from pinned memory instead of a pageable-numpy transfer.  Probed once per
-  pipeline; backends without a pinned memory space (CPU) silently keep the
-  pageable path (``pipeline.pinned`` says which one is active).
+* **pinned-host staging** on TPU: staged panels hop through the
+  ``pinned_host`` memory space so the H2D copy is an async DMA from pinned
+  memory instead of a pageable-numpy transfer.  The choice follows the
+  platform of the target sharding -- pinned on TPU, pageable on CPU, where
+  the device already is host memory (``pipeline.pinned`` says which).  A
+  pinned put that fails on TPU is a staging fault and raises.
 
 Resident ``jax.Array`` operands are *not* routed through the thread: slicing
 them is a device-side operation and jax dispatch stays on the consumer
@@ -173,9 +174,9 @@ class PanelPipeline:
     ``encoded=True`` ships streamed panels in their device-decodable stored
     form (bf16 -> uint16 bit patterns; see :func:`fetch_panel_encoded_info`)
     for on-device decode by the stream-GEMM kernels; the decoded-vs-stored
-    transfer gap is accounted in ``stats.bytes_h2d_saved``.  ``pin`` controls
-    pinned-host staging of device-bound panels (None = auto: on where the
-    backend has a ``pinned_host`` memory space, silently off elsewhere).
+    transfer gap is accounted in ``stats.bytes_h2d_saved``.  Device-bound
+    panels stage through pinned host memory when the sharding's platform is
+    TPU (``pinned``).
 
     Use as a context manager (or call :meth:`close`) so an early exit --
     consumer exception, solver convergence, test breakage -- cancels the
@@ -193,7 +194,6 @@ class PanelPipeline:
         stats=None,
         device_put=None,
         encoded: bool = False,
-        pin: bool | None = None,
     ):
         self.sources = list(sources)
         self.origins = list(origins)
@@ -205,10 +205,10 @@ class PanelPipeline:
         self.stats = stats
         self._device_put = device_put
         self.encoded = bool(encoded)
-        self._pin_want = pin is None or bool(pin)  # None/True: try; False: never
-        self.pinned = False  # True once pinned staging is probed and active
-        self._pinned_sharding = None
-        self._pin_probed = False
+        self.pinned = (
+            sharding is not None
+            and next(iter(sharding.device_set)).platform == "tpu"
+        )
         self._threaded = [_is_handle(s) for s in self.sources]
         self._rings = [
             _Ring(self.depth) if threaded else None for threaded in self._threaded
@@ -302,36 +302,12 @@ class PanelPipeline:
         return bundle, decs
 
     def _pin_host(self, panel: np.ndarray):
-        """Stage one host panel into pinned memory when the backend has it.
-
-        Probed once per pipeline: backends without a ``pinned_host`` memory
-        space (the CPU backend) keep the pageable-numpy path, and a probe
-        that succeeds but whose puts later fail degrades permanently rather
-        than erroring the stream.
-        """
-        if not self._pin_probed:
-            self._pin_probed = True
-            if self._pin_want:
-                try:
-                    import jax
-
-                    jax.devices()[0].memory("pinned_host")  # capability probe
-                    self._pinned_sharding = self.sharding.with_memory_kind(
-                        "pinned_host"
-                    )
-                    self.pinned = True
-                except Exception:
-                    self._pinned_sharding = None
-        if self._pinned_sharding is None:
-            return np.ascontiguousarray(panel)
-        try:
-            return self._device_put(
-                np.ascontiguousarray(panel), self._pinned_sharding
-            )
-        except Exception:
-            self._pinned_sharding = None  # partial support: fall back for good
-            self.pinned = False
-            return np.ascontiguousarray(panel)
+        """Stage one host panel for its H2D copy: into pinned host memory on
+        TPU (errors propagate), as a contiguous pageable array on CPU."""
+        panel = np.ascontiguousarray(panel)
+        if not self.pinned:
+            return panel
+        return self._device_put(panel, self.sharding.with_memory_kind("pinned_host"))
 
     def _stage(self, row0: int) -> tuple[int, list, int]:
         """Fetch/pop one origin's bundle and (optionally) put it on device."""

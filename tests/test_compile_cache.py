@@ -240,3 +240,18 @@ def test_query_path_retrace_budget(ctx1):
         nearest_neighbors(store, 7, 5)
     assert st.traces == warm_traces, "query path retraced a tile program"
     assert st.misses == warm_misses, "query path missed the program cache"
+
+
+def test_persistent_cache_dir_follows_env_else_checkout():
+    """Entry points leave JAX_COMPILATION_CACHE_DIR to JAX when it is set;
+    otherwise the persistent cache goes to the fixed <checkout>/.jax_cache.
+    (Only the choice is tested: tests never turn the cache on.)"""
+    from pathlib import Path
+
+    from repro.launch.cache import compile_cache_dir
+
+    assert compile_cache_dir({"JAX_COMPILATION_CACHE_DIR": "/elsewhere"}) is None
+    path = Path(compile_cache_dir({}))
+    assert path.name == ".jax_cache"
+    assert (path.parent / "pyproject.toml").exists()
+    assert compile_cache_dir({}) == str(path)

@@ -1,7 +1,7 @@
 """Per-kernel allclose vs the pure-jnp oracle: shape/dtype sweeps.
 
-All kernels run in interpret mode on CPU (the kernel body executes in
-Python), so these validate the actual Pallas kernel logic.
+All kernels run in interpret mode on CPU (the kernel body executes on the
+host), so these validate the actual Pallas kernel logic.
 """
 
 import jax
@@ -19,6 +19,53 @@ def _arr(shape, dtype=np.float32, positive=False):
     if positive:
         x = np.abs(x)
     return jnp.asarray(x.astype(dtype))
+
+
+# ---------------------------------------------------------------------------
+# dispatch: where a kernel runs
+# ---------------------------------------------------------------------------
+
+
+def test_dispatch_interprets_only_on_cpu():
+    """Inside a map the device kind decides: TPU interpret mode on the CPU,
+    the compiled kernel on a TPU, and no path at all anywhere else."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.kernels.dispatch import _interpret_for
+
+    assert isinstance(_interpret_for("cpu"), pltpu.InterpretParams)
+    assert _interpret_for("TPU v5 lite") is False
+    with pytest.raises(ValueError, match="device kind"):
+        _interpret_for("NVIDIA H100 80GB HBM3")
+
+
+def test_dispatch_lowering_for_other_platform_raises():
+    """Outside a map the choice is made at lowering: a platform with no
+    branch (here CUDA) is refused, never silently interpreted."""
+    a = _arr((128, 128))
+    with pytest.raises(NotImplementedError, match="cuda"):
+        jax.jit(ops.block_matmul).trace(a, a).lower(lowering_platforms=("cuda",))
+
+
+def test_dispatch_kernel_in_varying_shard_map(ctx22):
+    """A kernel inside a checked shard_map gets outputs typed with the
+    operands' varying axes, and runs (interpreted) on the CPU mesh."""
+    from repro.core.tiles import shard_map
+
+    spec = ctx22.matrix_spec
+    a, b = _arr((128, 128)), _arr((128, 128))
+    out = jax.jit(
+        shard_map(
+            lambda x, y: ops.block_matmul(x, y, bm=64, bk=64, bn=64),
+            mesh=ctx22.mesh, in_specs=(spec, spec), out_specs=spec,
+        )
+    )(a, b)
+    # each device multiplies its own (64, 64) blocks
+    np.testing.assert_allclose(
+        np.asarray(out)[:64, :64],
+        np.asarray(ref.block_matmul(a[:64, :64], b[:64, :64])),
+        rtol=1e-5, atol=1e-4,
+    )
 
 
 # ---------------------------------------------------------------------------

@@ -29,6 +29,7 @@ from repro.obs import metrics as obs_metrics
 from repro.obs import phase
 from repro.obs import trace as obs_trace
 from repro.obs.metrics import REGISTRY, MetricsRegistry
+from repro.obs.roofline import NOT_MEASURED, PEAKS, streamed_solve_roofline
 from repro.obs.report import (
     RUN_REPORT_KIND,
     build_run_report,
@@ -303,7 +304,10 @@ def test_run_report_end_to_end_oocore(ctx1, tmp_path):
     obs_trace.enable_tracing(fence=True)
     reset_stream_stats()
     cfg, res = _tiny_sequence(ctx1, oocore=True)
-    doc = build_run_report(config={"n": 32}, result=res, n=32, k_rp=4)
+    doc = build_run_report(
+        config={"n": 32}, result=res, n=32, k_rp=4,
+        device_kind="TPU v5 lite", peaks=PEAKS["TPU v5 lite"],
+    )
     validate_run_report(doc)
 
     # acceptance: report byte totals equal the legacy stream_stats() counters
@@ -334,6 +338,7 @@ def test_run_report_end_to_end_oocore(ctx1, tmp_path):
     assert doc["pipeline"]["producer_fetch_seconds"] > 0
     assert doc["cache"]["hits"] > 0
     assert doc["roofline"] is not None and doc["roofline"]["bound_s"] > 0
+    assert doc["roofline"]["device_kind"] == "TPU v5 lite"
 
     # the saved artifact and the trace both validate from disk
     rpath = tmp_path / "report.json"
@@ -363,6 +368,20 @@ def test_run_report_resident_and_residual_series(ctx1):
             assert len(s["residuals"]) == s["iterations"]
             assert s["residuals"][-1] == pytest.approx(s["residual"])
     assert doc["roofline"] is None  # no streamed solves to attribute
+
+
+def test_roofline_peaks_by_device_kind():
+    """Only a published peak gives a roofline fraction: the v5e entry bounds
+    the compute term, an unknown device records its kind, "not measured"."""
+    traffic = dict(bytes_read=0.0, bytes_h2d=0.0, flops=197e12, seconds=2.0)
+    v5e = streamed_solve_roofline(**traffic, device_kind="TPU v5 lite")
+    assert v5e["bound"] == "compute"
+    assert v5e["t_compute_s"] == pytest.approx(1.0)
+    assert v5e["roofline_frac"] == pytest.approx(0.5)
+    cpu = streamed_solve_roofline(**traffic, device_kind="cpu")
+    assert cpu["device_kind"] == "cpu"
+    assert cpu["roofline_frac"] == NOT_MEASURED
+    assert "bound_s" not in cpu
 
 
 def test_run_report_not_converged_warning(ctx1):

@@ -61,11 +61,10 @@ def test_sequence_global_topk(ctx1):
 
 
 def test_global_topk_merge_partially_replicated(ctx22):
-    """Regression (jax 0.4.x partial-replication bug, ROADMAP): the streaming
-    top-k merge must be correct even when the per-transition candidates are
-    sharded P(row_axes) -- *partially replicated* over the column mesh axes.
-    The former eager jnp.concatenate merge SUMMED the replicas on such inputs
-    (every candidate doubled on a 2x2 mesh); the host-side merge cannot."""
+    """The streaming top-k merge is exact when the per-transition candidates
+    are sharded P(row_axes) -- *partially replicated* over the column mesh
+    axes (an eager concatenate that summed the replicas would double every
+    candidate on a 2x2 mesh)."""
     import jax
 
     det = SequenceDetector(ctx22, CFG, top_k=4)

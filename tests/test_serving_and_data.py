@@ -7,7 +7,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.data import DataConfig, Prefetcher, global_batch_for, host_batch
-from repro.launch.mesh import make_cpu_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import lm
 from repro.models.common import ArchConfig
 from repro.serving import ServeConfig, ServeEngine
@@ -19,7 +19,7 @@ TINY = ArchConfig(
 
 
 def test_serve_engine_greedy_deterministic():
-    mesh = make_cpu_mesh(1, 1)
+    mesh = make_mesh(1, 1)
     spec = lm.build_spec(TINY)
     params = lm.init_params(spec, jax.random.PRNGKey(0))
     eng = ServeEngine(spec, mesh, params, s_max=24, batch=2,
@@ -36,7 +36,7 @@ def test_serve_engine_greedy_deterministic():
 
 
 def test_serve_engine_temperature_sampling():
-    mesh = make_cpu_mesh(1, 1)
+    mesh = make_mesh(1, 1)
     spec = lm.build_spec(TINY)
     params = lm.init_params(spec, jax.random.PRNGKey(0))
     eng = ServeEngine(spec, mesh, params, s_max=24, batch=2,
@@ -51,7 +51,7 @@ def test_serve_sharded_matches_single(mesh22):
     params = lm.init_params(spec, jax.random.PRNGKey(0))
     prompts = np.random.default_rng(1).integers(0, 256, size=(4, 8)).astype(np.int32)
     outs = []
-    for mesh in (make_cpu_mesh(1, 1), mesh22):
+    for mesh in (make_mesh(1, 1), mesh22):
         eng = ServeEngine(spec, mesh, params, s_max=16, batch=4,
                           cfg=ServeConfig(max_new_tokens=4))
         outs.append(eng.generate(prompts))

@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.launch.mesh import make_cpu_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import common as cm
 from repro.models import lm
 from repro.models.common import ArchConfig
@@ -36,7 +36,7 @@ def test_loss_invariant_to_mesh(mesh22):
     batch = _batch()
     ocfg = OptConfig(lr=1e-3)
     losses = {}
-    for mesh in (make_cpu_mesh(1, 1), mesh22):
+    for mesh in (make_mesh(1, 1), mesh22):
         step, *_ = make_train_step(spec, mesh, ocfg, donate=False)
         params, opt = init_state(spec, mesh, ocfg, seed=0)
         with mesh:

@@ -307,7 +307,14 @@ def test_warm_start_from_solution_converges_immediately(ctx, method):
 @pytest.mark.parametrize("method", ["chebyshev", "cg"])
 def test_warm_start_streamed(ctx1, method):
     """Out-of-core warm start: the streamed solve accepts y0 too, and a
-    solve seeded with the resident solution converges in <= 2 passes."""
+    solve seeded with the resident solution converges in a few passes.
+
+    The seed only just meets the tolerance on the resident operator (cold
+    chebyshev stops at 9.96e-6); the streamed P2 differs from the resident
+    one by rounding (5e-7 max), so the seed starts at 1.14e-5 on the
+    streamed operator.  A restarted Chebyshev recurrence contracts by about
+    rho / (2 - rho) = 0.92 per early step, which takes 3 passes; CG takes 1.
+    """
     n = 64
     store = TileStore.create(None, n=n, grid=8)
     a = _clustered(ctx1, n)
@@ -321,7 +328,7 @@ def test_warm_start_streamed(ctx1, method):
     )
     op_str.release_scratch()
     assert rep.streamed and rep.warm_start and rep.converged
-    assert rep.iterations <= 2
+    assert rep.iterations <= {"chebyshev": 3, "cg": 2}[method]
     np.testing.assert_allclose(
         np.asarray(warm), np.asarray(cold), rtol=1e-4, atol=1e-5
     )

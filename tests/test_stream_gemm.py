@@ -14,6 +14,8 @@ Three layers of guarantees, all runnable off-TPU (interpret mode):
   and each fused iteration makes exactly one pass over the panel stream.
 """
 
+from types import SimpleNamespace
+
 import numpy as np
 import pytest
 
@@ -228,15 +230,20 @@ def test_fused_iteration_is_one_panel_pass(ctx1):
     assert panels == n_panels * (rep.iterations + 1)
 
 
+def _panel_store(n=64):
+    from repro.store import TileStore
+
+    store = TileStore.create(None, n=n, grid=4)
+    return store.put_snapshot("a", _sym(n, 0))
+
+
 def test_pinned_host_fallback_on_cpu(ctx1):
-    """The pinned-host staging probe degrades cleanly where the backend has
-    no pinned_host memory space (CPU): panels still flow, pipeline.pinned
-    stays False."""
-    from repro.store import PanelPipeline, TileStore
+    """A CPU sharding stages pageable: the device already is host memory, so
+    panels flow straight to it and pipeline.pinned is False."""
+    from repro.store import PanelPipeline
 
     n = 64
-    store = TileStore.create(None, n=n, grid=4)
-    h = store.put_snapshot("a", _sym(n, 0))
+    h = _panel_store(n)
     sharding = ctx1.sharding(ctx1.matrix_spec)
     with PanelPipeline([h], range(0, n, 16), 16, sharding=sharding) as pipe:
         seen = 0
@@ -245,6 +252,57 @@ def test_pinned_host_fallback_on_cpu(ctx1):
             seen += 1
         assert seen == 4
         assert pipe.pinned is False
+
+
+class _FakeTpuSharding:
+    """A sharding whose devices report the TPU platform (steers the staging
+    rule without a chip); ``memory_kind`` records where a put would land."""
+
+    device_set = (SimpleNamespace(platform="tpu"),)
+
+    def __init__(self, memory_kind="device"):
+        self.memory_kind = memory_kind
+
+    def with_memory_kind(self, kind):
+        return _FakeTpuSharding(kind)
+
+
+def test_pinned_host_staging_on_tpu():
+    """A TPU sharding stages every panel through pinned host memory first."""
+    from repro.store import PanelPipeline
+
+    n = 64
+    h = _panel_store(n)
+    kinds = []
+
+    def put(x, sharding):
+        kinds.append(sharding.memory_kind)
+        return np.asarray(x)
+
+    with PanelPipeline([h], range(0, n, 16), 16, sharding=_FakeTpuSharding(),
+                       device_put=put) as pipe:
+        assert pipe.pinned is True
+        assert sum(1 for _ in pipe) == 4
+    assert kinds == ["pinned_host", "device"] * 4
+
+
+def test_pinned_put_failure_raises_on_tpu():
+    """A failing pinned put on TPU is a staging fault: it surfaces, and the
+    pipeline does not quietly switch to pageable staging."""
+    from repro.store import PanelPipeline
+
+    n = 64
+    h = _panel_store(n)
+
+    def put(x, sharding):
+        if sharding.memory_kind == "pinned_host":
+            raise RuntimeError("pinned DMA fault")
+        return np.asarray(x)
+
+    with PanelPipeline([h], range(0, n, 16), 16, sharding=_FakeTpuSharding(),
+                       device_put=put) as pipe:
+        with pytest.raises(RuntimeError, match="pinned DMA fault"):
+            list(pipe)
 
 
 @pytest.mark.slow

@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from repro import configs
-from repro.launch.mesh import make_cpu_mesh
+from repro.launch.mesh import make_mesh
 from repro.launch.train import train_loop
 from repro.models import lm
 from repro.models.common import ArchConfig
@@ -41,7 +41,7 @@ TINY = ArchConfig(
 
 
 def _mesh1():
-    return make_cpu_mesh(1, 1)
+    return make_mesh(1, 1)
 
 
 def _batch(b=4, s=32, seed=0):
@@ -169,7 +169,7 @@ def test_elastic_remesh_restore(tmp_path):
     """Checkpoint on a 2x2 mesh, restore onto 1x1 -- loss trajectory equal."""
     cfg = TINY.replace(compute_dtype="float32")
     d = str(tmp_path / "remesh")
-    mesh_a = make_cpu_mesh(2, 2)
+    mesh_a = make_mesh(2, 2)
     _, _, la = train_loop(cfg, mesh_a, steps=4, batch=4, seq=32,
                           ckpt_dir=d, ckpt_every=2, log_every=100)
     # resume the remaining steps on a different mesh
