@@ -23,9 +23,12 @@ All streamed queries are panel-bounded: Z travels in row panels through
 crosses H2D at stored width and widens in VMEM), device residency is two
 panels plus the O(q topk) running state, and the per-query top-k merge runs
 inside the kernel -- no n-length score vector, let alone an n x n block, is
-ever materialized.  Every query runs under a ``phase("query")`` span and
-accounts ``query.{panels,bytes_read,latency_ms,calls}`` in the process
-metrics registry.
+ever materialized.  Every query runs under a ``phase("query")`` span that
+carries a per-process query id (``query=<n>``, which the query's
+``query.panel``, ``query.collect`` and consumer-side ``pipeline.*`` spans
+carry too), with ``query.panel`` around each panel's dispatch and
+``query.collect`` around the wait for the answer, and accounts
+``query.{calls,panels,bytes_read}`` in the process metrics registry.
 
 ``caddelag-query`` (:func:`main`) is the CLI entry over a store directory.
 """
@@ -33,13 +36,15 @@ metrics registry.
 from __future__ import annotations
 
 import argparse
+import itertools
 import time
 from dataclasses import dataclass
 
 import numpy as np
 
 from repro.core.embedding import validate_node_indices
-from repro.obs import REGISTRY, phase
+from repro.obs import REGISTRY, phase, timed
+from repro.obs import trace as obs_trace
 
 __all__ = [
     "QueryResult",
@@ -49,6 +54,8 @@ __all__ = [
     "rank_auc",
     "top_anomalies_from_store",
 ]
+
+_QUERY_IDS = itertools.count()
 
 
 @dataclass
@@ -85,8 +92,10 @@ def _streamed_topk(
     largest: bool,
     exclude: np.ndarray | None = None,
     prefetch_depth: int | None = None,
+    qid: int,
 ) -> tuple[np.ndarray, np.ndarray, int]:
     """One pass over the artifact's Z panels; returns (vals, ids, n_panels).
+    ``qid`` tags the pass's spans with the query's id.
 
     The running (q, topk) state threads through the kernel call per panel --
     identical shapes every call, so the whole stream reuses one compiled
@@ -119,24 +128,29 @@ def _streamed_topk(
     with PanelPipeline(
         [handle], origins, pr,
         depth=prefetch_depth, sharding=sharding, stats=stream_stats(),
-        encoded=True,
+        encoded=True, span_args={"query": qid},
     ) as pipe:
         for row0, (zp,) in pipe:
-            idp = jnp.asarray(inv_deg[None, row0 : row0 + pr])
-            vals, idx = panel_topk_update(
-                vals, idx, zq_dev, zp, idq, idp, vol, row0, ex,
-                topk=topk, corrected=corrected, largest=largest,
-            )
+            with timed("query.panel", query=qid, row0=row0):
+                idp = jnp.asarray(inv_deg[None, row0 : row0 + pr])
+                vals, idx = panel_topk_update(
+                    vals, idx, zq_dev, zp, idq, idp, vol, row0, ex,
+                    topk=topk, corrected=corrected, largest=largest,
+                )
             n_panels += 1
-    return np.asarray(vals), np.asarray(idx), n_panels
+    with obs_trace.span("query.collect", query=qid):
+        return np.asarray(vals), np.asarray(idx), n_panels
 
 
 def _run_query(kind: str, handle, fn, **span_args) -> QueryResult:
-    """Shared telemetry wrapper: span, counters, latency."""
+    """Shared telemetry wrapper: span, counters, latency.  ``fn(qid)`` reads
+    the query's own rows as well as streaming Z, so the ``phase.query``
+    span holds all of a query's host work."""
     t0 = time.perf_counter()
     m0 = REGISTRY.snapshot()
-    with phase("query", kind=kind, emb_id=handle.emb_id, **span_args):
-        vals, ids, n_panels = fn()
+    qid = next(_QUERY_IDS)
+    with phase("query", query=qid, kind=kind, emb_id=handle.emb_id, **span_args):
+        vals, ids, n_panels = fn(qid)
     dt_ms = (time.perf_counter() - t0) * 1e3
     bytes_read = int(REGISTRY.delta(m0).get("stream.bytes_read", 0.0))
     REGISTRY.add_named(
@@ -144,7 +158,6 @@ def _run_query(kind: str, handle, fn, **span_args) -> QueryResult:
             "query.calls": 1.0,
             "query.panels": float(n_panels),
             "query.bytes_read": float(bytes_read),
-            "query.latency_ms": dt_ms,
         }
     )
     return vals, ids, n_panels, bytes_read, dt_ms
@@ -171,14 +184,14 @@ def top_anomalies_from_store(
     ``emb_id``, default latest) or an ``EmbeddingHandle`` directly.
     """
     handle = _resolve_handle(store, emb_id)
-    zq = handle.zbar.reshape(1, -1)
-    inv_q = np.asarray([handle.inv_deg().mean()], np.float32)
 
-    def run():
+    def run(qid):
         return _streamed_topk(
-            handle, zq, inv_q,
+            handle,
+            handle.zbar.reshape(1, -1),
+            np.asarray([handle.inv_deg().mean()], np.float32),
             topk=k, corrected=corrected, largest=True,
-            prefetch_depth=prefetch_depth,
+            prefetch_depth=prefetch_depth, qid=qid,
         )
 
     vals, ids, n_panels, bytes_read, dt_ms = _run_query(
@@ -205,15 +218,15 @@ def nearest_neighbors(
     handle = _resolve_handle(store, emb_id)
     n = handle.shape[0]
     validate_node_indices("node", node, n)
-    zq = handle.read_rows([int(node)])
-    inv_q = handle.inv_deg()[[int(node)]]
-    exclude = np.asarray([int(node)], np.int32)
 
-    def run():
+    def run(qid):
         return _streamed_topk(
-            handle, zq, inv_q,
+            handle,
+            handle.read_rows([int(node)]),
+            handle.inv_deg()[[int(node)]],
             topk=min(k, n - 1), corrected=corrected, largest=False,
-            exclude=exclude, prefetch_depth=prefetch_depth,
+            exclude=np.asarray([int(node)], np.int32),
+            prefetch_depth=prefetch_depth, qid=qid,
         )
 
     vals, ids, n_panels, bytes_read, dt_ms = _run_query(

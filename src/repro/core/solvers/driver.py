@@ -739,7 +739,7 @@ def solve(
     warm = y0 is not None
 
     with obs_trace.span(
-        "solve", method=spec.method, streamed=streamed, warm=warm
+        "solver.solve", method=spec.method, streamed=streamed, warm=warm
     ) as sp:
         b = ctx.constrain(b, ctx.rowblock_spec)
         b_in = b

@@ -661,7 +661,7 @@ def tile_stream(
 
     origins = list(range(0, n0, panel_rows))
     with obs_trace.span(
-        "tile_stream",
+        "tiles.stream",
         body=getattr(fn, "__name__", repr(fn)),
         n0=n0,
         n1=n1,

@@ -1,19 +1,23 @@
 """Unified observability: span tracing, metrics registry, structured reports.
 
-Three parts (see the module docstrings for depth):
+Four parts (see the module docstrings for depth):
 
 * :mod:`repro.obs.trace` -- thread-aware span tracer, Chrome trace-event
-  export, disabled-by-default no-op fast path, cross-thread begin/end.
+  export, spans mirrored into the ``jax.profiler`` trace, disabled-by-default
+  no-op fast path.
 * :mod:`repro.obs.metrics` -- process-wide counters/gauges/series registry
   with atomic snapshot/delta/reset; backs the ``stream_stats()`` and
   ``program_cache_stats()`` facades in :mod:`repro.core.tiles`.
+* :mod:`repro.obs.compiles` -- the always-on ``jit.compiles`` counter,
+  installed on import.
 * :mod:`repro.obs.report` -- versioned RunReport JSON (+ validators) emitted
   by ``caddelag-run --run-report``.
 
-:func:`phase` is the glue the five pipeline layers use: one call opens a
-trace span (when tracing is on) AND accumulates the always-on
+:func:`phase` is the glue the pipeline layers use: one call opens a trace
+span (when tracing is on) AND accumulates the always-on
 ``phase.<name>.seconds`` / ``phase.<name>.calls`` registry counters the
-per-transition breakdowns are cut from.
+per-transition breakdowns are cut from.  :func:`timed` does the same for the
+per-panel spans of the read path, counting only while tracing is enabled.
 """
 
 from __future__ import annotations
@@ -21,7 +25,7 @@ from __future__ import annotations
 import time
 from contextlib import contextmanager
 
-from repro.obs import metrics, trace
+from repro.obs import compiles, metrics, trace
 from repro.obs.metrics import (
     REGISTRY,
     MetricsRegistry,
@@ -31,10 +35,8 @@ from repro.obs.metrics import (
 )
 from repro.obs.trace import (
     Tracer,
-    begin,
     disable_tracing,
     enable_tracing,
-    end,
     span,
     tracer,
     tracing_enabled,
@@ -54,13 +56,26 @@ __all__ = [
     "disable_tracing",
     "tracing_enabled",
     "span",
-    "begin",
-    "end",
+    "timed",
     "phase",
 ]
 
+compiles.install()
+
 
 @contextmanager
+def _counted(name: str, args: dict):
+    t0 = time.perf_counter()
+    sp = trace.span(name, **args)
+    sp.__enter__()
+    try:
+        yield sp
+    finally:
+        sp.__exit__(None, None, None)
+        dt = time.perf_counter() - t0
+        REGISTRY.add_named({f"{name}.seconds": dt, f"{name}.calls": 1.0})
+
+
 def phase(name: str, **args):
     """Time one pipeline phase: a trace span + always-on registry counters.
 
@@ -72,14 +87,17 @@ def phase(name: str, **args):
     measure dispatch + host work only; program-level walls remain honest via
     the block_until_ready at scoring boundaries.
     """
-    t0 = time.perf_counter()
-    sp = trace.span(f"phase.{name}", **args)
-    sp.__enter__()
-    try:
-        yield sp
-    finally:
-        sp.__exit__(None, None, None)
-        dt = time.perf_counter() - t0
-        REGISTRY.add_named(
-            {f"phase.{name}.seconds": dt, f"phase.{name}.calls": 1.0}
-        )
+    return _counted(f"phase.{name}", args)
+
+
+def timed(name: str, **args):
+    """A trace span + ``<name>.seconds`` / ``<name>.calls`` counters, counted
+    only while tracing is enabled.
+
+    For spans opened many times a request (one per panel): with tracing
+    disabled this returns the shared null span, so the hot path reads no
+    clock and touches no registry lock.
+    """
+    if not trace.tracing_enabled():
+        return trace._NULL_SPAN
+    return _counted(name, args)

@@ -1,32 +1,38 @@
-"""Thread-aware span tracer with Chrome trace-event export.
+"""Thread-aware span tracer: Chrome trace-event export and profiler spans.
 
 Spans measure named intervals on the monotonic clock
 (``time.perf_counter_ns``) and export as Chrome trace-event JSON ("X"
 complete events plus "M" thread-name metadata), loadable in Perfetto or
-chrome://tracing.  Two APIs:
+chrome://tracing.  One API: ``with span("phase.solve", n=1024):`` opens and
+closes a span on one thread; nesting falls out of the event intervals (the
+viewers render the stack).  Work that crosses threads is traced as a span on
+each side (the panel pipeline's ``pipeline.fetch`` on its prefetch thread,
+``pipeline.wait`` and ``pipeline.stage`` on the consumer).
 
-* ``with span("phase.solve", n=1024):`` — same-thread context manager;
-  nesting falls out of the event intervals (the viewers render the stack).
-* ``h = begin("prefetch.panel", ...)`` / ``end(h)`` — explicit pairing for
-  spans that *cross threads*: the PanelPipeline producer opens the span when
-  it starts fetching a panel, the consumer closes it when the panel is
-  staged.  The exported event carries the **producer's** tid (recorded at
-  ``begin``), so in the trace the panel's lifetime renders on the prefetch
-  thread's track.
+Every span name starts with one of :data:`PREFIXES`, the vocabulary of the
+program's layers (``tests/test_obs.py`` checks the names in the sources).
+
+While tracing is enabled each span also enters a
+``jax.profiler.TraceAnnotation`` of its name, so under a ``jax.profiler``
+trace the program's spans sit on the host thread lines beside the device
+ops, on the profiler's clock.  The annotation carries the name alone (span
+arguments stay in the Chrome export), so event names in the ``.xplane.pb``
+are stable.  Without a running profiler the annotation is a cheap no-op.
 
 Tracing is **disabled by default** and the disabled path is a no-op fast
 path: ``span()`` returns a shared null span (no allocation, no clock read,
-no lock) and ``begin()`` returns handle ``0`` which ``end()`` ignores.
-Enabling costs two clock reads plus one locked list-append per span.
+no lock, no annotation).  Enabling costs two clock reads, one annotation and
+one locked list-append per span.
 
 Fencing: device work in jax is dispatched asynchronously, so a span that
 only brackets dispatch under-reports the device wall.  When tracing is
 enabled with ``enable_tracing(fence=True)``, a span exit on which
 ``sp.fence(x)`` was called runs ``jax.block_until_ready(x)`` *inside* the
-span, making the recorded duration an honest device-phase wall.  With
-tracing disabled (or ``fence=False``) no extra synchronization is
-introduced — timings then measure dispatch plus host work, and program-level
-walls stay honest via the existing block_until_ready at scoring boundaries.
+span -- and inside its annotation -- making the recorded duration an honest
+device-phase wall.  With tracing disabled (or ``fence=False``) no extra
+synchronization is introduced -- timings then measure dispatch plus host
+work, and program-level walls stay honest via the existing
+block_until_ready at scoring boundaries.
 """
 
 from __future__ import annotations
@@ -37,16 +43,22 @@ import threading
 import time
 from typing import Any
 
+import jax
+
 __all__ = [
+    "PREFIXES",
     "Tracer",
     "tracer",
     "enable_tracing",
     "disable_tracing",
     "tracing_enabled",
     "span",
-    "begin",
-    "end",
 ]
+
+# The program's span vocabulary: one prefix per layer.
+PREFIXES = (
+    "sequence.", "phase.", "pipeline.", "query.", "solver.", "tiles.", "oochain.",
+)
 
 
 def _now_us() -> float:
@@ -77,7 +89,7 @@ _NULL_SPAN = _NullSpan()
 class _Span:
     """Live same-thread span; records one "X" event on exit."""
 
-    __slots__ = ("_tracer", "name", "args", "t0", "tid", "_fence")
+    __slots__ = ("_tracer", "name", "args", "t0", "tid", "_fence", "_ann")
 
     def __init__(self, tracer_: "Tracer", name: str, args: dict[str, Any]):
         self._tracer = tracer_
@@ -86,8 +98,11 @@ class _Span:
         self.tid = threading.get_ident()
         self.t0 = 0.0
         self._fence = None
+        self._ann = None
 
     def __enter__(self) -> "_Span":
+        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann.__enter__()
         self.t0 = _now_us()
         return self
 
@@ -99,19 +114,20 @@ class _Span:
         self._fence = value
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        if self._fence is not None and self._tracer.fence_enabled:
-            _block_until_ready(self._fence)
-        self._tracer._record(
-            self.name, self.t0, _now_us() - self.t0, self.tid, self.args
-        )
+        try:
+            if self._fence is not None and self._tracer.fence_enabled:
+                _block_until_ready(self._fence)
+            self._tracer._record(
+                self.name, self.t0, _now_us() - self.t0, self.tid, self.args
+            )
+        finally:
+            self._ann.__exit__(exc_type, exc, tb)
         return None
 
 
 def _block_until_ready(value: Any) -> None:
     """Wait for the JAX arrays in ``value``; other leaves (store handles,
     host arrays) are already ready.  Device faults raise here."""
-    import jax
-
     jax.block_until_ready(
         [x for x in jax.tree.leaves(value) if isinstance(x, jax.Array)]
     )
@@ -126,9 +142,6 @@ class Tracer:
         self._lock = threading.Lock()
         self._events: list[dict[str, Any]] = []
         self._thread_names: dict[int, str] = {}
-        # Cross-thread spans in flight: handle -> (name, t0_us, producer_tid, args)
-        self._pending: dict[int, tuple[str, float, int, dict[str, Any]]] = {}
-        self._next_handle = 1
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -145,7 +158,6 @@ class Tracer:
         with self._lock:
             self._events.clear()
             self._thread_names.clear()
-            self._pending.clear()
 
     # -- recording -----------------------------------------------------------
 
@@ -153,54 +165,6 @@ class Tracer:
         if not self.enabled:
             return _NULL_SPAN
         return _Span(self, name, args)
-
-    def begin(self, name: str, **args: Any) -> int:
-        """Open a cross-thread span; returns a handle (0 when disabled).
-
-        The calling thread is recorded as the span's owner: the exported
-        event lands on *this* thread's track even if another thread ends it.
-        """
-        if not self.enabled:
-            return 0
-        tid = threading.get_ident()
-        t0 = _now_us()
-        with self._lock:
-            handle = self._next_handle
-            self._next_handle += 1
-            self._pending[handle] = (name, t0, tid, args)
-            self._note_thread_locked(tid)
-        return handle
-
-    def end(self, handle: int, **args: Any) -> None:
-        """Close a span opened by :func:`begin`; no-op for handle 0.
-
-        Safe to call from any thread; extra ``args`` merge into the event
-        (the ending thread's id is recorded as ``end_tid`` when it differs).
-        """
-        if handle == 0:
-            return
-        t1 = _now_us()
-        end_tid = threading.get_ident()
-        with self._lock:
-            pending = self._pending.pop(handle, None)
-            if pending is None:
-                return
-            name, t0, tid, ev_args = pending
-            if args:
-                ev_args = {**ev_args, **args}
-            if end_tid != tid:
-                ev_args = {**ev_args, "end_tid": end_tid}
-            self._events.append(
-                {
-                    "name": name,
-                    "ph": "X",
-                    "ts": t0,
-                    "dur": max(t1 - t0, 0.0),
-                    "pid": os.getpid(),
-                    "tid": tid,
-                    "args": ev_args,
-                }
-            )
 
     def _record(
         self, name: str, t0: float, dur: float, tid: int, args: dict[str, Any]
@@ -282,10 +246,3 @@ def span(name: str, **args: Any):
         return _NULL_SPAN
     return _Span(_TRACER, name, args)
 
-
-def begin(name: str, **args: Any) -> int:
-    return _TRACER.begin(name, **args)
-
-
-def end(handle: int, **args: Any) -> None:
-    _TRACER.end(handle, **args)

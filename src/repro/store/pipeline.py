@@ -28,9 +28,11 @@ its own ``device_put`` loop.  :class:`PanelPipeline` owns the pattern once:
 * **observability**: the producer accumulates ``pipeline.producer_fetch_seconds``
   and the consumer ``pipeline.consumer_wait_seconds`` in the process metrics
   registry (their ratio is the prefetch-efficiency signal that says whether
-  ``depth`` is right), and with tracing enabled each fetched panel carries a
-  cross-thread span -- opened on the prefetch thread when the fetch starts,
-  closed when the consumer pops it, rendered on the producer's track;
+  ``depth`` is right).  With tracing enabled, ``pipeline.fetch`` spans each
+  panel's fetch and decode on the prefetch thread, ``pipeline.wait`` the
+  consumer's wait for it, and ``pipeline.stage`` its pinned-host copy plus
+  ``device_put`` (also counted as ``pipeline.stage.seconds`` / ``.calls``);
+  ``span_args`` (a request's id, say) tag the consumer's spans;
 * **encoded shipping** (``encoded=True``, the stream-GEMM kernel path):
   panels of device-decodable codecs travel in their *stored* form -- bf16
   tiles as raw uint16 bit patterns, half the decoded bytes over H2D, widened
@@ -64,6 +66,7 @@ from typing import Iterator, Sequence
 
 import numpy as np
 
+from repro.obs import timed
 from repro.obs import trace as obs_trace
 from repro.obs.metrics import REGISTRY as _OBS_REGISTRY
 
@@ -178,6 +181,9 @@ class PanelPipeline:
     panels stage through pinned host memory when the sharding's platform is
     TPU (``pinned``).
 
+    ``span_args`` are added to the consumer's ``pipeline.wait`` and
+    ``pipeline.stage`` spans (the read path passes its query id).
+
     Use as a context manager (or call :meth:`close`) so an early exit --
     consumer exception, solver convergence, test breakage -- cancels the
     producer instead of leaving it blocked on a full ring.
@@ -194,6 +200,7 @@ class PanelPipeline:
         stats=None,
         device_put=None,
         encoded: bool = False,
+        span_args: dict | None = None,
     ):
         self.sources = list(sources)
         self.origins = list(origins)
@@ -205,6 +212,7 @@ class PanelPipeline:
         self.stats = stats
         self._device_put = device_put
         self.encoded = bool(encoded)
+        self.span_args = dict(span_args or {})
         self.pinned = (
             sharding is not None
             and next(iter(sharding.device_set)).platform == "tpu"
@@ -233,18 +241,15 @@ class PanelPipeline:
                         continue
                     if self._cancel.is_set():
                         return
-                    # Cross-thread span: opened here (producer tid), closed by
-                    # the consumer when it pops the panel -- the trace shows
-                    # each panel's fetch-to-consumption lifetime on this track.
-                    sp = obs_trace.begin("prefetch.panel", row0=row0, operand=i)
                     t_f0 = time.perf_counter()
-                    if self.encoded:
-                        panel, stored, decoded = fetch_panel_encoded_info(
-                            src, row0, self.height
-                        )
-                    else:
-                        panel, stored = fetch_panel_info(src, row0, self.height)
-                        decoded = panel.nbytes
+                    with obs_trace.span("pipeline.fetch", row0=row0, operand=i):
+                        if self.encoded:
+                            panel, stored, decoded = fetch_panel_encoded_info(
+                                src, row0, self.height
+                            )
+                        else:
+                            panel, stored = fetch_panel_info(src, row0, self.height)
+                            decoded = panel.nbytes
                     _OBS_REGISTRY.add_named(
                         {
                             "pipeline.producer_fetch_seconds": (
@@ -260,8 +265,7 @@ class PanelPipeline:
                         # prefetch thread produced the stored form, which is
                         # exactly panel.nbytes either way.
                         self.stats.add(bytes_read=stored, bytes_decoded=panel.nbytes)
-                    if not ring.put((panel, decoded, sp)):
-                        obs_trace.end(sp, cancelled=True)
+                    if not ring.put((panel, decoded)):
                         return  # closed under us: cancelled
         except BaseException as e:  # propagate to the consumer, then stop
             self._error = e
@@ -282,12 +286,10 @@ class PanelPipeline:
                 decs.append(None)
                 continue
             t_w0 = time.perf_counter()
-            item = ring.get()
-            _OBS_REGISTRY.add_named(
-                {
-                    "pipeline.consumer_wait_seconds": time.perf_counter() - t_w0,
-                    "pipeline.consumer_waits": 1.0,
-                }
+            with obs_trace.span("pipeline.wait", row0=row0, **self.span_args):
+                item = ring.get()
+            _OBS_REGISTRY.inc(
+                "pipeline.consumer_wait_seconds", time.perf_counter() - t_w0
             )
             if item is None:
                 if self._error is not None:
@@ -295,8 +297,7 @@ class PanelPipeline:
                         f"panel prefetch failed at row {row0}"
                     ) from self._error
                 raise RuntimeError("panel pipeline closed while panels were pending")
-            panel, decoded, sp = item
-            obs_trace.end(sp)  # closes the producer-side prefetch.panel span
+            panel, decoded = item
             bundle.append(panel)
             decs.append(decoded)
         return bundle, decs
@@ -318,7 +319,8 @@ class PanelPipeline:
         put = self._device_put
         for panel, decoded, threaded in zip(bundle, decs, self._threaded):
             if threaded:
-                dev = put(self._pin_host(panel), self.sharding)
+                with timed("pipeline.stage", row0=row0, **self.span_args):
+                    dev = put(self._pin_host(panel), self.sharding)
                 nbytes += dev.nbytes
                 if self.stats is not None:
                     inc = {"panels": 1, "bytes_h2d": dev.nbytes}

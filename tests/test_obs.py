@@ -1,11 +1,13 @@
 """Observability layer: tracer, metrics registry, facades, run reports.
 
-Covers the ISSUE 7 acceptance surface: Chrome-trace schema validity and span
-nesting, cross-thread producer-tid pairing through the panel pipeline,
-disabled-tracer no-op guarantees, exact snapshot/delta semantics, the
-``StreamStats`` facade contract (in-place reset, live references, the
-reset-vs-add race), and a RunReport built from a real tiny sequence run whose
-byte totals must equal the legacy ``stream_stats()`` counters.
+Covers Chrome-trace schema validity and span nesting, the panel pipeline's
+spans on the threads that run them, disabled-tracer no-op guarantees, spans
+mirrored into the ``jax.profiler`` trace (nesting read back from the
+``.xplane.pb``), the span vocabulary, the ``jit.*`` compile counters, exact
+snapshot/delta semantics, the ``StreamStats`` facade contract (in-place
+reset, live references, the reset-vs-add race), and a RunReport built from a
+real tiny sequence run whose byte totals must equal the legacy
+``stream_stats()`` counters.
 """
 
 import json
@@ -26,7 +28,7 @@ from repro.core import (
 from repro.core.tiles import StreamStats
 from repro.graphs import gmm_snapshot_sequence
 from repro.obs import metrics as obs_metrics
-from repro.obs import phase
+from repro.obs import phase, timed
 from repro.obs import trace as obs_trace
 from repro.obs.metrics import REGISTRY, MetricsRegistry
 from repro.obs.roofline import NOT_MEASURED, PEAKS, streamed_solve_roofline
@@ -110,9 +112,6 @@ def test_disabled_tracer_is_noop():
     with sp:
         sp.annotate(y=2)
         sp.fence(object())
-    h = obs_trace.begin("cross")
-    obs_trace.end(h)
-    assert h == 0
     assert obs_trace.tracer().events() == []
     # the shared null span means zero allocation on the hot path
     assert obs_trace.span("a") is obs_trace.span("b")
@@ -120,14 +119,14 @@ def test_disabled_tracer_is_noop():
 
 def test_span_nesting_and_chrome_schema():
     obs_trace.enable_tracing()
-    with obs_trace.span("outer", level=1):
-        with obs_trace.span("inner"):
+    with obs_trace.span("phase.outer", level=1):
+        with obs_trace.span("phase.inner"):
             pass
     doc = obs_trace.tracer().to_chrome_trace()
     validate_chrome_trace(doc)
     evs = {e["name"]: e for e in doc["traceEvents"] if e["ph"] == "X"}
-    assert set(evs) == {"outer", "inner"}
-    out, inn = evs["outer"], evs["inner"]
+    assert set(evs) == {"phase.outer", "phase.inner"}
+    out, inn = evs["phase.outer"], evs["phase.inner"]
     # proper nesting: inner's interval is contained in outer's
     assert out["ts"] <= inn["ts"]
     assert inn["ts"] + inn["dur"] <= out["ts"] + out["dur"] + 1e-6
@@ -139,32 +138,44 @@ def test_span_nesting_and_chrome_schema():
     json.loads(json.dumps(doc))
 
 
-def test_cross_thread_span_keeps_producer_tid():
+class _ListHandle:
+    """A host matrix behind the snapshot-handle protocol."""
+
+    def __init__(self, a, ph):
+        self.a, self._ph = a, ph
+
+    shape = property(lambda self: self.a.shape)
+    dtype = property(lambda self: self.a.dtype)
+    panel_rows = property(lambda self: self._ph)
+
+    def read_panel(self, row0, height):
+        return self.a[row0:row0 + height]
+
+
+def test_pipeline_fetch_spans_carry_the_prefetch_tid():
+    """Each side of the panel pipeline traces on its own thread: the fetch
+    on the prefetch thread's track, the wait for it on the consumer's."""
     obs_trace.enable_tracing()
-    handles = {}
-
-    def producer():
-        handles["h"] = obs_trace.begin("xfer", item=7)
-        handles["tid"] = threading.get_ident()
-
-    t = threading.Thread(target=producer, name="producer-thread")
-    t.start()
-    t.join()
-    obs_trace.end(handles["h"], staged=True)
-    (ev,) = [e for e in obs_trace.tracer().events() if e["ph"] == "X"]
-    # the event lands on the PRODUCER's track, with the consumer's tid noted
-    assert ev["tid"] == handles["tid"]
-    assert ev["args"]["item"] == 7
-    assert ev["args"]["staged"] is True
-    assert ev["args"]["end_tid"] == threading.get_ident()
+    a = np.arange(64 * 4, dtype=np.float32).reshape(64, 4)
+    with PanelPipeline([_ListHandle(a, 16)], range(0, 64, 16), 16) as pipe:
+        for row0, (panel,) in pipe:
+            np.testing.assert_array_equal(panel, a[row0:row0 + 16])
+    evs = obs_trace.tracer().events()
+    fetch = [e for e in evs if e["name"] == "pipeline.fetch"]
+    wait = [e for e in evs if e["name"] == "pipeline.wait"]
+    assert [e["args"]["row0"] for e in fetch] == [0, 16, 32, 48]
+    assert len(wait) == 4
+    assert {e["tid"] for e in wait} == {threading.get_ident()}
+    (producer,) = {e["tid"] for e in fetch}
+    assert producer != threading.get_ident()
     names = obs_trace.tracer().to_chrome_trace()["traceEvents"]
-    assert any(e["ph"] == "M" and e["args"]["name"] == "producer-thread"
-               for e in names)
+    assert any(e["ph"] == "M" and e["tid"] == producer
+               and e["args"]["name"] == "panel-prefetch" for e in names)
 
 
 def test_trace_save_is_loadable(tmp_path):
     obs_trace.enable_tracing()
-    with obs_trace.span("s"):
+    with obs_trace.span("phase.s"):
         pass
     path = tmp_path / "trace.json"
     obs_trace.tracer().save(str(path))
@@ -183,6 +194,239 @@ def test_phase_counters_accumulate_without_tracing():
     assert d["phase.solve.seconds"] > 0.0
     # with tracing disabled, no span events were recorded
     assert obs_trace.tracer().events() == []
+
+
+# ---------------------------------------------------------------------------
+# spans in the jax.profiler trace, span vocabulary, compile counters
+# ---------------------------------------------------------------------------
+
+
+def test_disabled_span_builds_no_annotation(monkeypatch):
+    """The disabled path constructs no ``TraceAnnotation``: with one that
+    raises, disabled spans, phases and timers still run and record nothing."""
+    import jax
+
+    def refuse(name, **kw):
+        raise AssertionError(f"annotation built for {name!r}")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    with obs_trace.span("query.panel", row0=0):
+        pass
+    with phase("solve") as sp:
+        sp.fence(object())
+    with timed("pipeline.stage", row0=0) as sp:
+        sp.fence(object())
+    assert obs_trace.tracer().events() == []
+    obs_trace.enable_tracing()
+    with pytest.raises(AssertionError, match="query.panel"):
+        with obs_trace.span("query.panel"):
+            pass
+
+
+def test_annotation_leaves_after_the_fence(monkeypatch):
+    """A fenced span's annotation covers the wait for its device values."""
+    import jax
+
+    order = []
+
+    class Ann:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            order.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            order.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Ann)
+    monkeypatch.setattr(obs_trace, "_block_until_ready", lambda v: order.append(("fence", v)))
+    obs_trace.enable_tracing(fence=True)
+    with obs_trace.span("phase.chain") as sp:
+        with obs_trace.span("solver.solve"):
+            pass
+        sp.fence("x")
+    assert order == [
+        ("enter", "phase.chain"), ("enter", "solver.solve"), ("exit", "solver.solve"),
+        ("fence", "x"), ("exit", "phase.chain"),
+    ]
+
+
+def test_span_names_take_a_program_prefix():
+    """Every span, phase and timer named in the sources starts with one of
+    the prefixes of the program's span vocabulary."""
+    import pathlib
+    import re
+
+    src = pathlib.Path(obs_trace.__file__).parents[1]
+    call = re.compile(r'\b(span|phase|timed)\(\s*"([^"]+)"')
+    found = {}
+    for path in src.rglob("*.py"):
+        for kind, name in call.findall(path.read_text()):
+            found[name] = "phase." + name if kind == "phase" else name
+    assert {"sequence.push", "solver.solve", "tiles.stream", "pipeline.stage",
+            "query.panel", "phase.publish", "phase.query"} <= set(found.values())
+    bad = {k: v for k, v in found.items() if not v.startswith(obs_trace.PREFIXES)}
+    assert not bad, bad
+
+
+def _profiled_program_spans(tmp_path, body):
+    """Run ``body`` under ``jax.profiler`` with tracing on; returns the
+    program's spans per host thread line: ``[[(name, start, end), ...]]``."""
+    import glob
+
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    obs_trace.enable_tracing(fence=True)
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+        obs_trace.disable_tracing()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    lines = []
+    for pl in jax.profiler.ProfileData.from_file(path).planes:
+        if not pl.name.startswith("/host:"):
+            continue
+        for ln in pl.lines:
+            evs = [(e.name, e.start_ns, e.end_ns) for e in ln.events
+                   if e.name.startswith(obs_trace.PREFIXES)]
+            if evs:
+                lines.append(sorted(evs, key=lambda e: e[1]))
+    return lines
+
+
+def _inside(inner, outer):
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+def test_push_spans_nest_in_the_profiler_trace(ctx1, tmp_path):
+    """A traced push leaves ``sequence.push`` enclosing its phases, publish
+    included, on one host thread line of the ``.xplane.pb``."""
+    from repro.store.embstore import EmbeddingStore
+
+    cfg = CommuteConfig(k_override=4, q=3, d=3)
+    store = EmbeddingStore.create(tmp_path / "emb", n=32, k=4, seed=cfg.seed)
+    det = SequenceDetector(ctx1, cfg, top_k=5, emb_store=store)
+    seq = gmm_snapshot_sequence(ctx1, 32, 2, seed=0, inject_p=0.01)
+    snaps = list(seq.snapshots())
+    det.push(snaps[0])
+
+    lines = _profiled_program_spans(tmp_path / "prof", lambda: det.push(snaps[1]))
+    (line,) = [ln for ln in lines if any(e[0] == "sequence.push" for e in ln)]
+    (push,) = [e for e in line if e[0] == "sequence.push"]
+    for name in ("phase.chain", "phase.ingest", "phase.solve", "phase.score",
+                 "phase.publish", "solver.solve"):
+        evs = [e for e in line if e[0] == name]
+        assert evs and all(_inside(e, push) for e in evs), name
+
+
+def test_query_spans_nest_in_the_profiler_trace(ctx1, tmp_path):
+    """A traced store query leaves ``phase.query`` enclosing the consumer's
+    wait, stage, per-panel dispatch and collect on its thread line; the
+    fetches run on the prefetch thread's line."""
+    from repro.core.query import top_anomalies_from_store
+    from repro.store.embstore import EmbeddingStore
+
+    rng = np.random.default_rng(0)
+    store = EmbeddingStore.create(tmp_path / "emb", n=96, k=8, seed=1, panel_rows=32)
+    store.put_embedding("t0000", rng.normal(size=(96, 8)).astype(np.float32), 10.0,
+                        rng.uniform(1, 2, 96).astype(np.float32))
+    top_anomalies_from_store(store, 5)  # compiled outside the trace
+
+    lines = _profiled_program_spans(tmp_path / "prof", lambda: top_anomalies_from_store(store, 5))
+    (line,) = [ln for ln in lines if any(e[0] == "phase.query" for e in ln)]
+    (query,) = [e for e in line if e[0] == "phase.query"]
+    counts = {name: sum(e[0] == name for e in line) for name in
+              ("pipeline.wait", "pipeline.stage", "query.panel", "query.collect")}
+    assert counts == {"pipeline.wait": 3, "pipeline.stage": 3, "query.panel": 3,
+                      "query.collect": 1}
+    assert all(_inside(e, query) for e in line if e[0] != "phase.query")
+    fetch_lines = [ln for ln in lines if any(e[0] == "pipeline.fetch" for e in ln)]
+    assert fetch_lines and all(ln is not line for ln in fetch_lines)
+
+
+def test_query_spans_share_the_query_id(ctx1, tmp_path):
+    """In the Chrome export the spans inside one ``phase.query`` carry its
+    ``query`` id; the prefetch thread's spans do not."""
+    from repro.core.query import nearest_neighbors
+    from repro.store.embstore import EmbeddingStore
+
+    rng = np.random.default_rng(1)
+    store = EmbeddingStore.create(tmp_path, n=64, k=8, seed=1, panel_rows=32)
+    store.put_embedding("t0000", rng.normal(size=(64, 8)).astype(np.float32), 10.0,
+                        rng.uniform(1, 2, 64).astype(np.float32))
+    obs_trace.enable_tracing()
+    nearest_neighbors(store, 3, 4)
+    nearest_neighbors(store, 5, 4)
+    evs = obs_trace.tracer().events()
+    ids = [e["args"]["query"] for e in evs if e["name"] == "phase.query"]
+    assert len(ids) == 2 and ids[0] != ids[1]
+    for qid in ids:
+        kids = [e["name"] for e in evs if e["args"].get("query") == qid]
+        assert sorted(set(kids)) == ["phase.query", "pipeline.stage", "pipeline.wait",
+                                     "query.collect", "query.panel"]
+        assert kids.count("query.panel") == 2
+    assert all("query" not in e["args"] for e in evs if e["name"] == "pipeline.fetch")
+
+
+def test_timed_stage_counts_each_staged_panel():
+    """``pipeline.stage`` is counted while tracing is enabled, once per panel
+    put on a device; not at all with tracing off or for host-mode pipelines."""
+    import jax
+
+    a = np.ones((64, 4), np.float32)
+    sharding = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+
+    def staged(**kw):
+        snap = REGISTRY.snapshot()
+        with PanelPipeline([_ListHandle(a, 16)], range(0, 64, 16), 16, **kw) as pipe:
+            list(pipe)
+        return REGISTRY.delta(snap)
+
+    assert "pipeline.stage.calls" not in staged(sharding=sharding)
+    assert obs_trace.tracer().events() == []
+    obs_trace.enable_tracing()
+    assert "pipeline.stage.calls" not in staged()
+    d = staged(sharding=sharding, span_args={"query": 7})
+    assert d["pipeline.stage.calls"] == 4.0 and d["pipeline.stage.seconds"] > 0.0
+    stage = [e for e in obs_trace.tracer().events() if e["name"] == "pipeline.stage"]
+    assert [e["args"] for e in stage] == [{"row0": r, "query": 7} for r in range(0, 64, 16)]
+
+
+def test_jit_compiles_count_a_new_program_once():
+    """``jit.compiles`` rises on the first call of a new jit and not on its
+    repeat, with tracing off."""
+    import jax
+    import jax.numpy as jnp
+
+    f = jax.jit(lambda x: x * 3.0 + 1.0)
+    x = jnp.arange(7.0)
+    snap = REGISTRY.snapshot()
+    f(x).block_until_ready()
+    first = REGISTRY.delta(snap)
+    assert first.get("jit.compiles") == 1.0
+    snap = REGISTRY.snapshot()
+    f(x).block_until_ready()
+    assert not {k for k in REGISTRY.delta(snap) if k.startswith("jit.")}
+
+
+def test_compile_counter_installs_once():
+    """A second install adds no second listener: one compile, one count."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.obs import compiles
+
+    compiles.install()
+    compiles.install()
+    x = jnp.arange(5.0)
+    snap = REGISTRY.snapshot()
+    jax.jit(lambda x: x - 2.0)(x).block_until_ready()
+    assert REGISTRY.delta(snap).get("jit.compiles") == 1.0
 
 
 # ---------------------------------------------------------------------------
@@ -352,7 +596,7 @@ def test_run_report_end_to_end_oocore(ctx1, tmp_path):
     # phase spans made it into the trace with fencing enabled
     names = {e["name"] for e in obs_trace.tracer().events()}
     assert {"phase.chain", "phase.ingest", "phase.solve", "phase.score",
-            "prefetch.panel", "solve", "sequence.push"} <= names
+            "pipeline.fetch", "solver.solve", "sequence.push"} <= names
 
 
 def test_run_report_resident_and_residual_series(ctx1):

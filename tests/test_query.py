@@ -19,7 +19,7 @@ from repro.core.query import (
     top_anomalies_from_store,
 )
 from repro.graphs import gmm_graph_sequence, gmm_snapshot_sequence
-from repro.obs import REGISTRY
+from repro.obs import REGISTRY, disable_tracing, enable_tracing, tracer
 from repro.store.embstore import EmbeddingStore
 
 CFG = CommuteConfig(eps_rp=1e-3, d=8, q=12, schedule="xla", k_override=64)
@@ -205,12 +205,25 @@ def test_topk_larger_than_n_pads_with_minus_one(ctx1):
 def test_query_registry_counters(ctx1):
     store, _, _ = _publish(ctx1)
     m0 = REGISTRY.snapshot()
-    top_anomalies_from_store(store, 5)
+    res = top_anomalies_from_store(store, 5)
     d = REGISTRY.delta(m0)
     assert d.get("query.calls") == 1
     assert d.get("query.panels", 0) >= 1
     assert d.get("query.bytes_read", 0) > 0
-    assert d.get("query.latency_ms", 0) > 0
+    assert res.latency_ms > 0  # the latency is the result's, not a counter
+    assert "query.latency_ms" not in d
+    # one query.panel span per panel, counted only while tracing is enabled
+    assert "query.panel.calls" not in d
+    enable_tracing()
+    try:
+        m0 = REGISTRY.snapshot()
+        res = top_anomalies_from_store(store, 5)
+        d = REGISTRY.delta(m0)
+    finally:
+        disable_tracing()
+        tracer().clear()
+    assert d.get("query.panel.calls") == d["query.panels"] == res.panels
+    assert d.get("query.panel.seconds", 0) > 0
 
 
 # ---------------------------------------------------------------------------
