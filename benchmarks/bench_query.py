@@ -9,9 +9,14 @@ distance/top-k kernel -- O(n k_RP) work against the write path's O(n^3)
 GEMMs -- so the gap should widen with n.  Both paths run after untimed
 warm-up (shared compile cache); asserted, not just reported.
 
-Also asserted here: the query is *panel-bounded* -- the streaming
-executors' ``peak_live_bytes`` gauge stays within 2 staged panels of the
-one streamed operand (prefetch depth x one Z panel), independent of n.
+Also asserted here: a query of an artifact over the store's resident
+budget is *panel-bounded* -- the streaming executors' ``peak_live_bytes``
+gauge stays within 2 staged panels of the one streamed operand (prefetch
+depth x one Z panel), independent of n.  The streamed timings run with the
+budget at 0, through a store object that keeps nothing, so every timed
+query reads Z from the store.  Reported beside them, not in the bar: the
+latency of a hit on the artifact a store object kept on the device after
+its first query (same answers, no bytes read).
 
 ``auc`` -- scorer quality on the labeled degenerate-regime fixture
 (:func:`repro.graphs.gmm_snapshot_sequence` with ``anomaly_nodes`` +
@@ -41,6 +46,7 @@ from pathlib import Path
 import jax
 import numpy as np
 
+import repro.store.embstore as embstore
 from repro.core import CommuteConfig, SequenceDetector, trivial_context
 from repro.core.embedding import commute_time_embedding, exact_commute_distances
 from repro.core.query import rank_auc, top_anomalies_from_store
@@ -58,8 +64,18 @@ def _write_path_score(ctx, a, cfg, top_k):
     return np.argsort(-scores)[:top_k]
 
 
+def _timed_queries(store, top_k, repeats):
+    times, res = [], None
+    for _ in range(repeats):
+        t0 = time.perf_counter()
+        res = top_anomalies_from_store(store, top_k)
+        times.append(time.perf_counter() - t0)
+    return times, res
+
+
 def speedup(n=512, top_k=10, codec="raw", repeats=5, out=print):
-    """Artifact query vs full-pipeline re-score at the same n; >= 10x bar."""
+    """Streamed artifact query vs full-pipeline re-score at the same n;
+    >= 10x bar.  Resident hits are timed and reported beside it."""
     ctx = trivial_context()
     cfg = CommuteConfig(eps_rp=1e-2, d=6, q=8, schedule="xla")
     seq = gmm_snapshot_sequence(ctx, n, 2, seed=0, inject_p=0.02)
@@ -78,17 +94,32 @@ def speedup(n=512, top_k=10, codec="raw", repeats=5, out=print):
         top_anomalies_from_store(store, top_k)
         _write_path_score(ctx, a, cfg, top_k)
 
-        reset_stream_stats()
-        q_times, res = [], None
-        for _ in range(repeats):
-            t0 = time.perf_counter()
-            res = top_anomalies_from_store(store, top_k)
-            q_times.append(time.perf_counter() - t0)
-        st = stream_stats()
+        # streamed: no resident budget, a store object that keeps nothing
+        share = embstore.RESIDENT_SHARE
+        embstore.RESIDENT_SHARE = 0.0
+        try:
+            reset_stream_stats()
+            q_times, res = _timed_queries(EmbeddingStore.open(root), top_k, repeats)
+            peak = stream_stats().peak_live_bytes
+        finally:
+            embstore.RESIDENT_SHARE = share
         panel_bytes = store.manifest.panel_rows * store.manifest.k * (
             2 if codec == "bf16" else 4
         )
-        peak = st.peak_live_bytes
+
+        # resident: a device with no memory stats (the CPU) keeps nothing
+        # unless given free bytes to count; on a chip the warm-up kept it
+        unstated = embstore.NO_STATS_FREE_BYTES
+        embstore.NO_STATS_FREE_BYTES = 2**30
+        try:
+            top_anomalies_from_store(store, top_k)  # fills, or hits
+            h_times, hit = _timed_queries(store, top_k, repeats)
+        finally:
+            embstore.NO_STATS_FREE_BYTES = unstated
+        assert hit.bytes_read == 0, f"resident hit read {hit.bytes_read} bytes"
+        assert np.array_equal(hit.idx, res.idx) and np.array_equal(hit.val, res.val), (
+            "resident hit differs from the streamed answer"
+        )
 
         r_times = []
         for _ in range(max(2, repeats // 2)):
@@ -97,13 +128,14 @@ def speedup(n=512, top_k=10, codec="raw", repeats=5, out=print):
             r_times.append(time.perf_counter() - t0)
 
         q_ms, r_ms = 1e3 * min(q_times), 1e3 * min(r_times)
+        hit_ms = 1e3 * min(h_times)
         ratio = r_ms / q_ms
         overlap = len(set(res.idx.tolist()) & set(rebuilt.tolist()))
         out(
-            f"[bench_query] n={n} codec={codec}: query {q_ms:.1f} ms vs "
+            f"[bench_query] n={n} codec={codec}: streamed query {q_ms:.1f} ms vs "
             f"re-score {r_ms:.1f} ms -> {ratio:.1f}x "
             f"(panels={res.panels} bytes_read={res.bytes_read} "
-            f"top-{top_k} overlap {overlap}/{top_k})"
+            f"top-{top_k} overlap {overlap}/{top_k}); resident hit {hit_ms:.1f} ms"
         )
         out(
             f"[bench_query] residency: peak_live_bytes={peak} "
@@ -122,6 +154,7 @@ def speedup(n=512, top_k=10, codec="raw", repeats=5, out=print):
             "n": n,
             "codec": codec,
             "query_ms": q_ms,
+            "hit_ms": hit_ms,
             "rescore_ms": r_ms,
             "ratio": ratio,
             "panels": res.panels,

@@ -18,17 +18,26 @@ distance/top-k kernel (:mod:`repro.kernels.emb_query`):
 * :func:`commute_block` -- the (rows x cols) distance block for a handful of
   node pairs, indices validated (no silent clamping gathers).
 
-All streamed queries are panel-bounded: Z travels in row panels through
+Every query walks Z in row panels, one kernel call per panel, and the
+per-query top-k merge runs inside the kernel -- no n-length score vector,
+let alone an n x n block, is ever materialized.  The first query of an
+artifact streams its panels from the store through
 :class:`~repro.store.PanelPipeline` (encoded shipping: a bf16 artifact
-crosses H2D at stored width and widens in VMEM), device residency is two
-panels plus the O(q topk) running state, and the per-query top-k merge runs
-inside the kernel -- no n-length score vector, let alone an n x n block, is
-ever materialized.  Every query runs under a ``phase("query")`` span that
-carries a per-process query id (``query=<n>``, which the query's
-``query.panel``, ``query.collect`` and consumer-side ``pipeline.*`` spans
-carry too), with ``query.panel`` around each panel's dispatch and
-``query.collect`` around the wait for the answer, and accounts
-``query.{calls,panels,bytes_read}`` in the process metrics registry.
+crosses H2D at stored width and widens in VMEM).  Where the artifact fits
+:func:`~repro.store.embstore.resident_budget`, that pass keeps the staged
+panels and degree slices on the device as the store's
+:class:`~repro.store.embstore.ResidentArtifact`, and later queries of the
+same id through the same store object walk them there with no transfer
+(the stream stats' ``peak_live_bytes`` counts every kept panel).  An
+artifact over the budget streams on every query, with two panels plus the
+O(q topk) running state on the device.  Every query runs under a ``phase("query")`` span that carries
+a per-process query id (``query=<n>``, which the query's ``query.panel``,
+``query.collect``, ``query.resident.fill`` and consumer-side ``pipeline.*``
+spans carry too), with ``query.panel`` around each panel's dispatch,
+``query.resident.fill`` around a pass that keeps its panels and
+``query.collect`` around the wait for the answer.  It accounts
+``query.{calls,panels,bytes_read}`` and ``query.resident.{hits,fills,bytes}``
+in the process metrics registry.
 
 ``caddelag-query`` (:func:`main`) is the CLI entry over a store directory.
 """
@@ -36,6 +45,7 @@ carry too), with ``query.panel`` around each panel's dispatch and
 from __future__ import annotations
 
 import argparse
+import contextlib
 import itertools
 import time
 from dataclasses import dataclass
@@ -84,8 +94,7 @@ def _resolve_handle(store, emb_id: str | None):
 
 def _streamed_topk(
     handle,
-    zq: np.ndarray,
-    inv_deg_q: np.ndarray,
+    query_rows,
     *,
     topk: int,
     corrected: bool,
@@ -95,11 +104,15 @@ def _streamed_topk(
     qid: int,
 ) -> tuple[np.ndarray, np.ndarray, int]:
     """One pass over the artifact's Z panels; returns (vals, ids, n_panels).
-    ``qid`` tags the pass's spans with the query's id.
+    ``query_rows(inv_deg)`` gives the query block and its 1/deg terms from
+    the artifact's 1/deg vector; ``qid`` tags the pass's spans with the
+    query's id.
 
     The running (q, topk) state threads through the kernel call per panel --
-    identical shapes every call, so the whole stream reuses one compiled
-    program regardless of n.
+    identical shapes every call, so the whole walk reuses one compiled
+    program regardless of n, and a walk over the store's resident copy
+    makes the same calls on the same operands as the streamed pass that
+    filled it.
     """
     import jax
     import jax.numpy as jnp
@@ -111,11 +124,15 @@ def _streamed_topk(
     n, _ = handle.shape
     pr = handle.panel_rows
     topk = min(int(topk), n)
-    origins = list(range(0, n, pr))
+    device = jax.devices()[0]
+    store = handle.store
+    stats = stream_stats()
+    resident = store.resident(handle.emb_id, device)
+    inv_deg = handle.inv_deg() if resident is None else resident.inv_deg
+    zq, inv_deg_q = query_rows(inv_deg)
     zq_dev = jnp.asarray(np.asarray(zq, np.float32))
     q = zq_dev.shape[0]
     idq = jnp.asarray(np.asarray(inv_deg_q, np.float32).reshape(q, 1))
-    inv_deg = handle.inv_deg()
     vol = handle.vol
     ex = jnp.asarray(
         np.full((q, 1), -1, np.int32)
@@ -123,21 +140,49 @@ def _streamed_topk(
         else np.asarray(exclude, np.int32).reshape(q, 1)
     )
     vals, idx = topk_init(q, topk, largest=largest)
-    sharding = jax.sharding.SingleDeviceSharding(jax.devices()[0])
-    n_panels = 0
-    with PanelPipeline(
-        [handle], origins, pr,
-        depth=prefetch_depth, sharding=sharding, stats=stream_stats(),
-        encoded=True, span_args={"query": qid},
-    ) as pipe:
-        for row0, (zp,) in pipe:
-            with timed("query.panel", query=qid, row0=row0):
+
+    def update(row0, zp, idp):
+        nonlocal vals, idx
+        with timed("query.panel", query=qid, row0=row0):
+            if idp is None:
                 idp = jnp.asarray(inv_deg[None, row0 : row0 + pr])
-                vals, idx = panel_topk_update(
-                    vals, idx, zq_dev, zp, idq, idp, vol, row0, ex,
-                    topk=topk, corrected=corrected, largest=largest,
-                )
-            n_panels += 1
+            vals, idx = panel_topk_update(
+                vals, idx, zq_dev, zp, idq, idp, vol, row0, ex,
+                topk=topk, corrected=corrected, largest=largest,
+            )
+        return idp
+
+    if resident is not None:
+        for row0, zp, idp in resident.panels:
+            update(row0, zp, idp)
+        REGISTRY.inc("query.resident.hits")
+        stats._note_live(resident.nbytes)
+        n_panels = len(resident.panels)
+    else:
+        fill = store.resident_fill(handle.emb_id, device, inv_deg)
+        sharding = jax.sharding.SingleDeviceSharding(device)
+        n_panels = 0
+        with (
+            contextlib.nullcontext() if fill is None
+            else timed("query.resident.fill", query=qid)
+        ), PanelPipeline(
+            [handle], list(range(0, n, pr)), pr,
+            depth=prefetch_depth, sharding=sharding, stats=stats,
+            encoded=True, span_args={"query": qid},
+        ) as pipe:
+            for row0, (zp,) in pipe:
+                idp = update(row0, zp, None)
+                if fill is not None:
+                    fill.panels.append((row0, zp, idp))
+                n_panels += 1
+        if fill is not None:
+            # Every kept panel is live at the pass's end, not the pipeline's two.
+            stats._note_live(fill.nbytes)
+            if store.finish_fill(fill):
+                REGISTRY.add_named({
+                    "query.resident.fills": 1.0,
+                    "query.resident.bytes": float(fill.nbytes),
+                })
     with obs_trace.span("query.collect", query=qid):
         return np.asarray(vals), np.asarray(idx), n_panels
 
@@ -188,8 +233,10 @@ def top_anomalies_from_store(
     def run(qid):
         return _streamed_topk(
             handle,
-            handle.zbar.reshape(1, -1),
-            np.asarray([handle.inv_deg().mean()], np.float32),
+            lambda inv_deg: (
+                handle.zbar.reshape(1, -1),
+                np.asarray([inv_deg.mean()], np.float32),
+            ),
             topk=k, corrected=corrected, largest=True,
             prefetch_depth=prefetch_depth, qid=qid,
         )
@@ -222,8 +269,7 @@ def nearest_neighbors(
     def run(qid):
         return _streamed_topk(
             handle,
-            handle.read_rows([int(node)]),
-            handle.inv_deg()[[int(node)]],
+            lambda inv_deg: (handle.read_rows([int(node)]), inv_deg[[int(node)]]),
             topk=min(k, n - 1), corrected=corrected, largest=False,
             exclude=np.asarray([int(node)], np.int32),
             prefetch_depth=prefetch_depth, qid=qid,

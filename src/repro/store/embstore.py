@@ -32,6 +32,17 @@ idioms exactly:
 ``read_panel_info`` / ``read_panel_encoded_info``), so the generic
 :class:`~repro.store.pipeline.PanelPipeline` streams ``Z`` row panels with
 the same prefetch/accounting machinery the chain executors use.
+
+A store object also keeps one artifact on the device after its first query
+(:class:`ResidentArtifact`): the query's pass over the stored panels retains
+them there, and later queries of that id on that device, through the same
+store object, read them from device memory instead of streaming ``Z`` again.
+Only an artifact whose retained bytes fit :func:`resident_budget` is kept; a
+larger one streams on every query.  The copy is keyed by ``(emb_id,
+device)`` and is the one served last: a miss on another key replaces it,
+every ``put_embedding`` through the object drops it, and so does
+``remove_embedding`` of its id.  Invalidation is per store object: a put
+through another object of the same directory does not reach this one's copy.
 """
 
 from __future__ import annotations
@@ -39,6 +50,7 @@ from __future__ import annotations
 import json
 import os
 import shutil
+import threading
 from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Iterator
@@ -53,6 +65,28 @@ _AUX_NAME = "aux.npz"
 # Codecs with a device-decodable stored form only: the query kernel ships
 # panels encoded (uint16 bf16 bits widen in VMEM), which zstd cannot do.
 EMB_CODECS = ("raw", "bf16")
+
+# An artifact stays on the device after its first query while its retained
+# panels and degree slices take at most this share of the device's free memory.
+RESIDENT_SHARE = 1 / 8
+
+# The free memory counted on a device that reports no memory stats (the CPU
+# backend): none, since the store cannot see what fits there, so every query
+# streams, as one of an artifact over the budget does on a chip.
+NO_STATS_FREE_BYTES = 0
+
+
+def resident_budget(device) -> int:
+    """Bytes one resident artifact may take on ``device``: ``RESIDENT_SHARE``
+    of its free memory, ``bytes_limit - bytes_in_use`` of its
+    ``memory_stats()``, or of ``NO_STATS_FREE_BYTES`` where it reports
+    none."""
+    stats = device.memory_stats() or {}
+    if "bytes_limit" in stats:
+        free = stats["bytes_limit"] - stats.get("bytes_in_use", 0)
+    else:
+        free = NO_STATS_FREE_BYTES
+    return int(RESIDENT_SHARE * max(free, 0))
 
 
 @dataclass
@@ -131,6 +165,28 @@ class EmbManifest:
         )
 
 
+@dataclass
+class ResidentArtifact:
+    """One committed artifact kept on a device after its first query.
+
+    The query's streaming pass appends to ``panels``, in row order, each
+    panel's origin, its stored-form Z rows and its (1, panel_rows) 1/deg
+    slice, as the query kernel takes them; ``inv_deg`` is the host 1/deg
+    vector the queries' own terms read.  ``epoch`` is the store's count of
+    puts and removes when the fill began.
+    """
+
+    emb_id: str
+    device: object
+    inv_deg: np.ndarray
+    epoch: int
+    panels: list = field(default_factory=list)  # [(row0, z, 1/deg)], device arrays
+
+    @property
+    def nbytes(self) -> int:
+        return sum(z.nbytes + d.nbytes for _, z, d in self.panels)
+
+
 def default_panel_rows(n: int, want: int = 256) -> int:
     """The largest divisor of ``n`` <= ``want`` (MXU-alignment preferred)."""
     from repro.kernels.tiling import fit
@@ -150,6 +206,12 @@ class EmbeddingStore:
             panel = h.read_panel(row0, h.panel_rows)
 
     ``root=None`` selects the host-RAM backend (same API, dict of arrays).
+
+    The store object holds at most one :class:`ResidentArtifact`, the one
+    served last (:meth:`resident`, :meth:`resident_fill`,
+    :meth:`finish_fill`).  Every ``put_embedding`` drops it, as does
+    ``remove_embedding`` of its id, and a fill that was streaming while
+    either ran is not kept.
     """
 
     def __init__(self, manifest: EmbManifest, root: str | Path | None):
@@ -163,6 +225,9 @@ class EmbeddingStore:
         self.root = Path(root) if root is not None else None
         self._ram_panels: dict[tuple[str, int], np.ndarray] = {}
         self._ram_aux: dict[str, dict[str, np.ndarray]] = {}
+        self._resident: ResidentArtifact | None = None
+        self._resident_epoch = 0  # bumped by every put or remove
+        self._resident_lock = threading.Lock()
         self.codec = resolve_codec(manifest.codec, fallback=False)
         if self.codec.name == "bf16" and np.dtype(manifest.dtype) != np.float32:
             raise ValueError(
@@ -371,13 +436,16 @@ class EmbeddingStore:
             "zbar": zbar,
         }
         pr = self.panel_rows
-        for p in range(self.manifest.panels):
-            if self.has_panel(emb_id, p):
-                continue  # resume after a partial publish
-            stored = self.codec.encode(z[p * pr : (p + 1) * pr])
-            self._store_panel(emb_id, p, np.asarray(stored))
-        self._store_aux(emb_id, aux)
-        self._commit(emb_id)
+        try:
+            for p in range(self.manifest.panels):
+                if self.has_panel(emb_id, p):
+                    continue  # resume after a partial publish
+                stored = self.codec.encode(z[p * pr : (p + 1) * pr])
+                self._store_panel(emb_id, p, np.asarray(stored))
+            self._store_aux(emb_id, aux)
+            self._commit(emb_id)
+        finally:
+            self._drop_resident()  # latest() now serves another artifact
         return self.embedding(emb_id)
 
     def _store_panel(self, emb_id: str, p: int, stored: np.ndarray) -> None:
@@ -425,6 +493,7 @@ class EmbeddingStore:
         if emb_id in self.manifest.embeddings:
             self.manifest.embeddings.remove(emb_id)
             self._write_manifest()
+        self._drop_resident(emb_id)
         if self.root is None:
             for key in [k for k in self._ram_panels if k[0] == emb_id]:
                 del self._ram_panels[key]
@@ -433,6 +502,52 @@ class EmbeddingStore:
             emb_dir = self.root / emb_id
             if emb_dir.exists():
                 shutil.rmtree(emb_dir)
+
+    # -- device-resident copy -------------------------------------------------
+
+    def resident_nbytes(self) -> int:
+        """Bytes one artifact keeps on the device: every panel in its stored
+        form plus its float32 (1, panel_rows) 1/deg slice.  A lower bound on
+        the memory they take: the runtime's tiled layout may pad them."""
+        stored = 2 if self.codec.name == "bf16" else self.dtype.itemsize
+        return self.manifest.panels * self.panel_rows * (self.k * stored + 4)
+
+    def resident(self, emb_id: str, device) -> ResidentArtifact | None:
+        """The copy of ``emb_id`` kept on ``device``, if that is the one kept."""
+        r = self._resident
+        if r is not None and r.emb_id == emb_id and r.device == device:
+            return r
+        return None
+
+    def resident_fill(
+        self, emb_id: str, device, inv_deg: np.ndarray
+    ) -> ResidentArtifact | None:
+        """Start a miss of ``emb_id`` on ``device``: drop the kept copy (the
+        store keeps one) and return an empty :class:`ResidentArtifact` for
+        the streaming pass to append its panels to, or ``None`` when the
+        artifact does not fit :func:`resident_budget`."""
+        with self._resident_lock:
+            self._resident = None
+            epoch = self._resident_epoch
+        if self.resident_nbytes() > resident_budget(device):
+            return None
+        return ResidentArtifact(emb_id, device, inv_deg, epoch)
+
+    def finish_fill(self, fill: ResidentArtifact) -> bool:
+        """Keep ``fill`` unless a put or remove ran since it began."""
+        with self._resident_lock:
+            if fill.epoch != self._resident_epoch:
+                return False
+            self._resident = fill
+            return True
+
+    def _drop_resident(self, emb_id: str | None = None) -> None:
+        """Drop the kept copy (only if it holds ``emb_id``, when given)."""
+        with self._resident_lock:
+            self._resident_epoch += 1
+            r = self._resident
+            if r is not None and emb_id in (None, r.emb_id):
+                self._resident = None
 
     # -- read path -----------------------------------------------------------
 

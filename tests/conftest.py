@@ -14,6 +14,15 @@ from repro.core.distmatrix import DistContext, make_context
 from jax.sharding import Mesh
 
 
+@pytest.fixture
+def host_resident(monkeypatch):
+    """Count 1 GiB free on a device that reports no memory stats (the CPU),
+    so a store keeps a queried artifact on it as it does on a chip."""
+    import repro.store.embstore as embstore
+
+    monkeypatch.setattr(embstore, "NO_STATS_FREE_BYTES", 2**30)
+
+
 @pytest.fixture(scope="session")
 def ctx1() -> DistContext:
     """1x1 mesh context."""

@@ -324,10 +324,12 @@ def test_push_spans_nest_in_the_profiler_trace(ctx1, tmp_path):
         assert evs and all(_inside(e, push) for e in evs), name
 
 
-def test_query_spans_nest_in_the_profiler_trace(ctx1, tmp_path):
-    """A traced store query leaves ``phase.query`` enclosing the consumer's
-    wait, stage, per-panel dispatch and collect on its thread line; the
-    fetches run on the prefetch thread's line."""
+def test_query_spans_nest_in_the_profiler_trace(ctx1, tmp_path, host_resident):
+    """A traced store query leaves ``phase.query`` enclosing its spans on its
+    thread line: on the first query of the artifact, the fill around the
+    consumer's wait, stage and per-panel dispatch, then the collect; on the
+    next, the dispatches over the resident panels and the collect alone.
+    The fetches run on the prefetch thread's line."""
     from repro.core.query import top_anomalies_from_store
     from repro.store.embstore import EmbeddingStore
 
@@ -335,21 +337,35 @@ def test_query_spans_nest_in_the_profiler_trace(ctx1, tmp_path):
     store = EmbeddingStore.create(tmp_path / "emb", n=96, k=8, seed=1, panel_rows=32)
     store.put_embedding("t0000", rng.normal(size=(96, 8)).astype(np.float32), 10.0,
                         rng.uniform(1, 2, 96).astype(np.float32))
-    top_anomalies_from_store(store, 5)  # compiled outside the trace
+    # compiled outside the trace, through another store: this one keeps nothing yet
+    top_anomalies_from_store(EmbeddingStore.open(tmp_path / "emb"), 5)
 
-    lines = _profiled_program_spans(tmp_path / "prof", lambda: top_anomalies_from_store(store, 5))
+    def body():
+        top_anomalies_from_store(store, 5)
+        top_anomalies_from_store(store, 5)
+
+    lines = _profiled_program_spans(tmp_path / "prof", body)
     (line,) = [ln for ln in lines if any(e[0] == "phase.query" for e in ln)]
-    (query,) = [e for e in line if e[0] == "phase.query"]
-    counts = {name: sum(e[0] == name for e in line) for name in
-              ("pipeline.wait", "pipeline.stage", "query.panel", "query.collect")}
-    assert counts == {"pipeline.wait": 3, "pipeline.stage": 3, "query.panel": 3,
-                      "query.collect": 1}
-    assert all(_inside(e, query) for e in line if e[0] != "phase.query")
+    queries = [e for e in line if e[0] == "phase.query"]
+    assert len(queries) == 2
+    names = ("query.resident.fill", "pipeline.wait", "pipeline.stage", "query.panel",
+             "query.collect")
+    counts = [{name: sum(e[0] == name and _inside(e, q) for e in line) for name in names}
+              for q in queries]
+    assert counts == [
+        {"query.resident.fill": 1, "pipeline.wait": 3, "pipeline.stage": 3,
+         "query.panel": 3, "query.collect": 1},
+        {"query.resident.fill": 0, "pipeline.wait": 0, "pipeline.stage": 0,
+         "query.panel": 3, "query.collect": 1},
+    ]
+    (fill,) = [e for e in line if e[0] == "query.resident.fill"]
+    assert all(_inside(e, fill) for e in line if e[0].startswith("pipeline."))
+    assert all(any(_inside(e, q) for q in queries) for e in line if e[0] != "phase.query")
     fetch_lines = [ln for ln in lines if any(e[0] == "pipeline.fetch" for e in ln)]
     assert fetch_lines and all(ln is not line for ln in fetch_lines)
 
 
-def test_query_spans_share_the_query_id(ctx1, tmp_path):
+def test_query_spans_share_the_query_id(ctx1, tmp_path, host_resident):
     """In the Chrome export the spans inside one ``phase.query`` carry its
     ``query`` id; the prefetch thread's spans do not."""
     from repro.core.query import nearest_neighbors
@@ -360,16 +376,16 @@ def test_query_spans_share_the_query_id(ctx1, tmp_path):
     store.put_embedding("t0000", rng.normal(size=(64, 8)).astype(np.float32), 10.0,
                         rng.uniform(1, 2, 64).astype(np.float32))
     obs_trace.enable_tracing()
-    nearest_neighbors(store, 3, 4)
-    nearest_neighbors(store, 5, 4)
+    nearest_neighbors(store, 3, 4)  # streams and keeps the artifact
+    nearest_neighbors(store, 5, 4)  # walks the resident copy
     evs = obs_trace.tracer().events()
     ids = [e["args"]["query"] for e in evs if e["name"] == "phase.query"]
     assert len(ids) == 2 and ids[0] != ids[1]
-    for qid in ids:
-        kids = [e["name"] for e in evs if e["args"].get("query") == qid]
-        assert sorted(set(kids)) == ["phase.query", "pipeline.stage", "pipeline.wait",
-                                     "query.collect", "query.panel"]
-        assert kids.count("query.panel") == 2
+    kids = [[e["name"] for e in evs if e["args"].get("query") == qid] for qid in ids]
+    assert sorted(set(kids[0])) == ["phase.query", "pipeline.stage", "pipeline.wait",
+                                    "query.collect", "query.panel", "query.resident.fill"]
+    assert sorted(set(kids[1])) == ["phase.query", "query.collect", "query.panel"]
+    assert kids[0].count("query.panel") == kids[1].count("query.panel") == 2
     assert all("query" not in e["args"] for e in evs if e["name"] == "pipeline.fetch")
 
 

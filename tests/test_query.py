@@ -3,6 +3,7 @@ kernel, and the query API -- pinned against the exact eigendecomposition
 oracle and brute-force numpy on 1x1 AND 2x2 meshes.
 """
 
+import jax
 import numpy as np
 import pytest
 
@@ -19,6 +20,7 @@ from repro.core.query import (
     top_anomalies_from_store,
 )
 from repro.graphs import gmm_graph_sequence, gmm_snapshot_sequence
+from repro.core.tiles import reset_stream_stats, stream_stats
 from repro.obs import REGISTRY, disable_tracing, enable_tracing, tracer
 from repro.store.embstore import EmbeddingStore
 
@@ -224,6 +226,186 @@ def test_query_registry_counters(ctx1):
         tracer().clear()
     assert d.get("query.panel.calls") == d["query.panels"] == res.panels
     assert d.get("query.panel.seconds", 0) > 0
+
+
+# ---------------------------------------------------------------------------
+# the device-resident copy: fill on the first query, walk it on the next
+# ---------------------------------------------------------------------------
+
+
+def _random_store(root=None, *, n=96, k=8, codec="raw", emb_id="t0000", seed=0):
+    rng = np.random.default_rng(seed)
+    store = EmbeddingStore.create(root, n=n, k=k, codec=codec, seed=1, panel_rows=32)
+    store.put_embedding(emb_id, rng.normal(size=(n, k)).astype(np.float32),
+                        float(n), rng.uniform(1.0, 3.0, n).astype(np.float32))
+    return store
+
+
+def _ask(kind, store, k=6):
+    if kind == "nearest_neighbors":
+        return nearest_neighbors(store, 41, k)
+    return top_anomalies_from_store(store, k)
+
+
+def _brute(kind, h, k=6):
+    z = h.to_numpy().astype(np.float64)
+    if kind == "nearest_neighbors":
+        d = h.vol * ((z - z[41]) ** 2).sum(1)
+        d[41] = np.inf
+        order = np.argsort(d)[:k]
+    else:
+        d = h.vol * ((z - z.mean(0)) ** 2).sum(1)
+        order = np.argsort(-d)[:k]
+    return order, d[order]
+
+
+def _counted(kind, store):
+    """One traced query and the registry counters it moved."""
+    enable_tracing()
+    try:
+        m0 = REGISTRY.snapshot()
+        res = _ask(kind, store)
+        d = REGISTRY.delta(m0)
+    finally:
+        disable_tracing()
+        tracer().clear()
+    return res, d
+
+
+@pytest.mark.parametrize("codec", ["raw", "bf16"])
+@pytest.mark.parametrize("kind", ["nearest_neighbors", "top_anomalies"])
+def test_resident_hit_is_bitwise_the_streamed_answer(kind, codec, tmp_path, host_resident):
+    store = _random_store(tmp_path, codec=codec)
+    reset_stream_stats()
+    miss, d_miss = _counted(kind, store)
+    peak = stream_stats().peak_live_bytes
+    hit, d_hit = _counted(kind, store)
+
+    assert d_miss.get("query.resident.fills") == 1 and "query.resident.hits" not in d_miss
+    assert d_miss["query.bytes_read"] > 0
+    # every kept panel and 1/deg slice, live at the fill's end
+    assert d_miss["query.resident.bytes"] == peak == store.resident_nbytes()
+    assert d_hit.get("query.resident.hits") == 1 and "query.resident.fills" not in d_hit
+    assert d_hit.get("query.bytes_read", 0) == 0 and hit.bytes_read == 0
+    for d, res in ((d_miss, miss), (d_hit, hit)):
+        assert d["query.panels"] == d["query.panel.calls"] == res.panels == 3
+    np.testing.assert_array_equal(hit.idx, miss.idx)
+    np.testing.assert_array_equal(hit.val, miss.val)
+    order, want = _brute(kind, store.latest())
+    assert set(hit.idx.tolist()) == set(order.tolist())
+    np.testing.assert_allclose(hit.val, want, rtol=1e-4, atol=1e-3)
+
+
+def test_resident_copy_follows_puts_and_removes(host_resident):
+    store = _random_store()
+    first = _ask("top_anomalies", store)
+    _ask("top_anomalies", store)
+    assert store.resident("t0000", jax.devices()[0]) is not None
+
+    # a new publish is served from its own artifact on its first query
+    rng = np.random.default_rng(5)
+    store.put_embedding("t0001", rng.normal(size=(96, 8)).astype(np.float32), 96.0,
+                        rng.uniform(1.0, 3.0, 96).astype(np.float32))
+    assert store.resident("t0000", jax.devices()[0]) is None  # a commit drops the copy
+    m0 = REGISTRY.snapshot()
+    newer = _ask("top_anomalies", store)
+    assert REGISTRY.delta(m0).get("query.resident.fills") == 1
+    assert newer.emb_id == "t0001" and store.resident("t0001", jax.devices()[0]) is not None
+    order, want = _brute("top_anomalies", store.latest())
+    assert set(newer.idx.tolist()) == set(order.tolist())
+    np.testing.assert_allclose(newer.val, want, rtol=1e-4, atol=1e-3)
+
+    # a re-put of the served id, or its removal, drops the kept copy
+    z = store.latest().to_numpy()
+    store.put_embedding("t0001", z, 96.0, np.full(96, 2.0, np.float32))
+    assert store.resident("t0001", jax.devices()[0]) is None
+    _ask("top_anomalies", store)
+    assert store.resident("t0001", jax.devices()[0]) is not None
+    store.remove_embedding("t0001")
+    assert store.resident("t0001", jax.devices()[0]) is None
+    again = _ask("top_anomalies", store)
+    np.testing.assert_array_equal(again.idx, first.idx)
+    np.testing.assert_array_equal(again.val, first.val)
+
+
+def test_fill_streaming_across_a_put_is_not_kept(host_resident):
+    """A fill that was streaming while the same store published is dropped."""
+    store = _random_store()
+    dev = jax.devices()[0]
+    stale = store.resident_fill("t0000", dev, np.ones(96, np.float32))
+    store.put_embedding("t0001", np.zeros((96, 8), np.float32), 1.0, np.ones(96))
+    assert not store.finish_fill(stale)
+    assert store.resident("t0000", dev) is None
+    fresh = store.resident_fill("t0000", dev, np.ones(96, np.float32))
+    assert store.finish_fill(fresh) and store.resident("t0000", dev) is fresh
+
+
+@pytest.mark.parametrize("kind", ["nearest_neighbors", "top_anomalies"])
+def test_no_budget_streams_every_query(kind, monkeypatch, host_resident):
+    import repro.store.embstore as embstore
+
+    kept = _ask(kind, _random_store())  # a store with a budget: streamed, then kept
+    monkeypatch.setattr(embstore, "RESIDENT_SHARE", 0.0)
+    store = _random_store()
+    panel_bytes = 32 * 8 * 4
+    for _ in range(2):
+        reset_stream_stats()
+        m0 = REGISTRY.snapshot()
+        res = _ask(kind, store)
+        d = REGISTRY.delta(m0)
+        assert d["query.bytes_read"] > 0 and d["query.panels"] == 3
+        assert stream_stats().peak_live_bytes <= 2 * panel_bytes  # panel-bounded
+        assert not {"query.resident.hits", "query.resident.fills"} & set(d)
+        assert store.resident("t0000", jax.devices()[0]) is None
+        np.testing.assert_array_equal(res.idx, kept.idx)
+        np.testing.assert_array_equal(res.val, kept.val)
+
+
+def test_resident_bytes_and_budget(monkeypatch):
+    """The read cell's artifact (n=259200, k=20, 27 panels of 9600 rows)
+    keeps its Z panels at stored width and one float32 1/deg per row; the
+    budget is a share of what the device has free, and nothing on a device
+    that reports no memory stats."""
+    import repro.store.embstore as embstore
+    from repro.store.embstore import RESIDENT_SHARE, resident_budget
+
+    store = EmbeddingStore.create(None, n=259200, k=20, panel_rows=9600)
+    assert store.resident_nbytes() == 259200 * (20 * 4 + 4)
+    bf16 = EmbeddingStore.create(None, n=259200, k=20, panel_rows=9600, codec="bf16")
+    assert bf16.resident_nbytes() == 259200 * (20 * 2 + 4)
+
+    class Chip:
+        def __init__(self, stats):
+            self.stats = stats
+
+        def memory_stats(self):
+            return self.stats
+
+    limit = 16 * 2**30
+    assert resident_budget(Chip({"bytes_limit": limit, "bytes_in_use": 0})) == int(
+        RESIDENT_SHARE * limit)
+    busy = Chip({"bytes_limit": limit, "bytes_in_use": limit - 2**30})
+    assert resident_budget(busy) == int(RESIDENT_SHARE * 2**30)
+    assert resident_budget(Chip({"bytes_limit": limit, "bytes_in_use": limit})) == 0
+    assert resident_budget(Chip(None)) == resident_budget(Chip({})) == 0  # no stats
+    monkeypatch.setattr(embstore, "NO_STATS_FREE_BYTES", 2**30)
+    assert resident_budget(Chip(None)) == int(RESIDENT_SHARE * 2**30)
+
+
+def test_no_memory_stats_streams_every_query():
+    """The CPU reports no memory stats: a store keeps nothing there, and its
+    queries stream Z each time, panel-bounded."""
+    assert "bytes_limit" not in (jax.devices()[0].memory_stats() or {})
+    store = _random_store()
+    for kind in ("nearest_neighbors", "top_anomalies", "top_anomalies"):
+        reset_stream_stats()
+        m0 = REGISTRY.snapshot()
+        res = _ask(kind, store)
+        d = REGISTRY.delta(m0)
+        assert d["query.bytes_read"] > 0 and res.bytes_read > 0
+        assert not {"query.resident.hits", "query.resident.fills"} & set(d)
+        assert stream_stats().peak_live_bytes <= 2 * 32 * 8 * 4
+        assert store.resident("t0000", jax.devices()[0]) is None
 
 
 # ---------------------------------------------------------------------------
