@@ -73,16 +73,16 @@ class ChainOperator:
     p2: jax.Array  # (n, n)  Z^ @ L                    (array or store handle)
     deg: jax.Array  # (n,)
     vol: jax.Array  # scalar V_G
-    # Optional incremental low-rank correction (repro.core.delta_chain): the
-    # operator then represents P1' = diag(p1_scale) P1 diag(p1_scale) + u1 v1^T
-    # and P2' = P2 + u2 v2^T around the *base* p1/p2 buffers.  The solve
-    # driver applies them as rank-r epilogues in every mat-vec; None means an
-    # ordinary (uncorrected) operator.
+    # Optional incremental correction (repro.core.delta_chain): the operator
+    # then represents P1' = diag(p1_scale) P1 diag(p1_scale) + u1 v1^T around
+    # the *base* p1 buffer, and applies P2' = P1' (diag(deg) - adj) from the
+    # snapshot's own adjacency ``adj`` (its p2 is None), so the corrected
+    # iteration's fixed point is the exact L' z = Y'.  None means an ordinary
+    # (uncorrected) operator.
     p1_scale: jax.Array | None = None  # (n,)
     u1: jax.Array | None = None  # (n, r)
     v1: jax.Array | None = None  # (n, r)
-    u2: jax.Array | None = None  # (n, r)
-    v2: jax.Array | None = None  # (n, r)
+    adj: jax.Array | None = None  # (n, n) the snapshot's adjacency (array or store handle)
     prefetch_depth: int = 2  # panel-pipeline staging depth for streamed consumers
     rho: float | None = None  # rho(S~^{2^d}) power-iteration estimate (build-time)
     # Streamed consumers route mat-vecs through the fused Pallas stream-GEMM
@@ -98,7 +98,7 @@ class ChainOperator:
     def tree_flatten(self):
         return (
             self.p1, self.p2, self.deg, self.vol,
-            self.p1_scale, self.u1, self.v1, self.u2, self.v2,
+            self.p1_scale, self.u1, self.v1, self.adj,
         ), (self.prefetch_depth, self.rho, self.use_gemm_kernel, self.shared_base)
 
     @classmethod
@@ -200,20 +200,18 @@ def _resident_chain(
         ctx, a, deg, deflate=deflate, dtype=dtype, prefetch_depth=prefetch_depth
     )  # T_0 = S
     p = add_scaled_identity(ctx, t, 1.0)  # I + S
-    # Only a level_sink keeps the intermediate levels alive: a plain build
-    # holds O(1) n x n matrices, not 2d of them.
+    # Only a level_sink keeps the T levels alive (the delta path applies
+    # every P level as a product of (I + T) factors): a plain build holds
+    # O(1) n x n matrices, a retaining one d more.
     keep = level_sink is not None
-    t_levels, p_levels = ([t] if keep else []), []
+    t_levels = [t] if keep else []
     for _ in range(1, d_len):
-        if keep:
-            p_levels.append(p)  # P_{lvl-1}, multiplied against by dP_lvl
         t = mm(t, t)  # S^{2^k}
         if keep:
             t_levels.append(t)
         p = jnp.add(mm(p, t), p)  # P (I + T) = P T + P, no identity materialized
     if keep:
         level_sink["t"] = t_levels
-        level_sink["p"] = p_levels[1:]  # P_0 = I + T_0 is applied implicitly
 
     inv_sqrt = jnp.where(deg > 0, jax.lax.rsqrt(jnp.maximum(deg, 1e-30)), 0.0)
     p1 = tile_map(
@@ -264,12 +262,12 @@ def chain_product(
     store-backed snapshot handle.
 
     ``level_sink`` (a caller-provided dict) opts into retaining the chain's
-    intermediate levels for incremental delta updates
+    squaring levels for incremental delta updates
     (:mod:`repro.core.delta_chain`): on return ``level_sink["t"]`` holds
-    T_0 .. T_{d-1} and ``level_sink["p"]`` holds P_0 .. P_{d-2} (arrays
-    resident, store handles out-of-core -- the oocore build then skips the
-    usual intermediate-snapshot removal for retained levels; the caller owns
-    their lifetime via ``BaseChain.release()``).
+    T_0 .. T_{d-1} (arrays resident, store handles out-of-core -- the oocore
+    build then skips the usual removal of those intermediate snapshots; the
+    caller owns their lifetime via ``BaseChain.release()``).  No P level is
+    kept: P_{l} is the product of the (I + T_j), j <= l.
 
     With a handle, every consumer of A streams: the degree pass, the
     normalized-adjacency build (S, the first chain GEMM's operand, assembled

@@ -9,9 +9,10 @@ compression primitive.  This module implements that path for the squaring
 chain:
 
 1. **Sketch** ``dS = S~' - S~`` against a counter-generated Rademacher test
-   matrix (never materializing dS): a randomized range-finder compresses it
-   to a rank-r factorization ``U0 V0^T``.  The same sketch yields the *drift
-   monitor* ``||dS W||_F / ||S~ W||_F`` for free.
+   matrix W (never materializing dS): a randomized range-finder compresses
+   it to a rank-r factorization ``U0 V0^T`` from W's first r + 2 columns.
+   The same two passes, over all ``DRIFT_SKETCH_COLS`` columns of W, yield
+   the *drift monitor* ``||dS W||_F / ||S~ W||_F``.
 2. **Propagate** the correction through the squaring recurrence.  With
    ``T_l = T_{l-1}^2`` and ``P_l = P_{l-1}(I + T_l)`` (all T_l symmetric,
    powers of S commute):
@@ -19,31 +20,46 @@ chain:
        dT_l = [T U, U] [V, T V + V (U^T V)]^T               (rank 2r)
        dP_l = [E, P Ut + E (F^T Ut)] [F + T_l F, Vt]^T      (rank 2r)
 
-   where (U, V) = dT_{l-1}, (E, F) = dP_{l-1}, (Ut, Vt) = dT_l -- every
-   product against the *base* chain is a skinny n x r panel GEMM through
-   :func:`repro.core.distmatrix.matmul_rowblock` (streams store-backed base
-   levels through the panel pipeline; resident bases use one eager dot), so
-   a level costs O(n^2 r) instead of the rebuild's O(n^3).  Each level
-   recompresses 2r -> r via an exact QR + small-SVD factor truncation.
-3. **Correct the operator.**  ``P1' = diag(s) P1 diag(s) + E~ F~^T`` is
-   *exact* (s = sqrt(deg) * 1/sqrt(deg'), E~ = D'^{-1/2} E); ``dP2 =
-   P1' L' - P1 L`` is compressed by a two-pass range-finder on its implicit
-   forward/adjoint applies (the base ``L`` mat-vec is reconstructed from the
-   retained T_0 = S~, so no base adjacency is kept).  The corrected
-   :class:`~repro.core.chain.ChainOperator` carries ``(p1_scale, u1, v1,
-   u2, v2)`` -- every solver method and the fused streamed kernel pass apply
-   them as cheap rank-r epilogues around the unchanged base mat-vec.
+   where (U, V) = dT_{l-1}, (E, F) = dP_{l-1}, (Ut, Vt) = dT_l, T = T_{l-1}
+   and P = P_{l-1}.  Each level recompresses 2r -> r via an exact QR +
+   small-SVD factor truncation.  The base keeps only T_0 .. T_{d-1}: since
+   ``P_{l-1} = (I + T_0)(I + T_1) ... (I + T_{l-1})``, every ``P_{l-1} Ut``
+   comes from one pass per T level over a block that gathers the Ut's (the
+   dT chain runs first), as many passes as against stored P levels and d-2
+   fewer n x n matrices held.  Every product against the base is a skinny
+   n x w panel GEMM through :func:`repro.core.distmatrix.matmul_rowblock`
+   (streams store-backed levels through the panel pipeline; resident levels
+   use one eager dot), so a level costs O(n^2 r) instead of the rebuild's
+   O(n^3).
+3. **Correct the operator.**  ``P1' = diag(s) P1 diag(s) + E~ F~^T``
+   (s = sqrt(deg) * 1/sqrt(deg'), E~ = D'^{-1/2} E) is the preconditioner,
+   exact up to the rank-r truncation of dP.  The corrected
+   :class:`~repro.core.chain.ChainOperator` carries ``(p1_scale, u1, v1)``
+   and the snapshot's adjacency, and every solver method applies
+   ``P2' = P1' (D' - A')`` from them, two skinny products a step: the
+   iteration ``z <- z - P2' z + P1' Y'`` has the exact ``L' z = Y'`` as its
+   fixed point, whatever the truncation left in P1'.
 
-All dense-factor algebra here runs eagerly (host numpy for the O(n r^2)
-QR/SVD pieces, ``matmul_rowblock`` for the n^2 passes), so the delta path
-adds ZERO tile-program traces; the only new compiled program is the
-corrected resident solve loop, keyed once per correction rank.
+All factor algebra stays on the device in float32 (``matmul_rowblock``
+for the n^2 passes, a jitted QR + small-SVD truncation per shape): the only
+host read is the drift the gate compares, so no pass waits on a copy back
+to the host.  The factors shape only the preconditioner, never the fixed
+point.  The delta path adds ZERO tile-program traces; the only new compiled
+tile program is the corrected resident solve loop, keyed once per
+correction rank.  Over resident levels the propagation is one jitted
+program (:func:`_propagate_program`, compiled once per shape).
+
+Spans (while tracing is on): ``delta.update`` around the whole update, with
+``delta.sketch``, ``delta.propagate`` and ``delta.correct`` inside it, and
+the ``delta.update.seconds`` / ``.calls`` counters (:func:`repro.obs.timed`).
 """
 
 from __future__ import annotations
 
+import math
 import warnings
 from dataclasses import dataclass, field
+from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -52,14 +68,34 @@ import numpy as np
 from repro.core import laplacian as lap
 from repro.core import rng as crng
 from repro.core.chain import ChainOperator, chain_product
-from repro.core.distmatrix import DistContext, matmul_rowblock
+from repro.core.distmatrix import F32_PRECISION, DistContext, matmul_rowblock
 from repro.core.tiles import is_streamable
+from repro.obs import timed
+from repro.obs import trace as obs_trace
 from repro.obs.metrics import REGISTRY as _OBS_REGISTRY
 
 # Range-finder oversampling: the sketch width is delta_rank + DELTA_OVERSAMPLE
 # columns; the extra columns absorb the tail so the leading r directions are
 # captured accurately (Halko/Martinsson/Tropp's standard few-column margin).
 DELTA_OVERSAMPLE = 2
+
+# Columns of the drift monitor's sketch.  The ratio estimates
+# ||dS||_F / ||S~||_F; from r + 2 columns it is off by tens of percent, so
+# the rebuild a budget asks for landed anywhere from 36 to 66 transitions
+# into a base on the climate-drift traffic (the exact ratio: 51 to 55, on a
+# 64x64 grid).  128 columns hold it to a few percent and add no pass: the
+# same two passes read their n x n operands once, whatever the width.
+# Below n = 16 * 128 the width is n / 16 (at least r + 2), so the two passes
+# stay skinny at any n.
+DRIFT_SKETCH_COLS = 128
+
+# A delta transition's solve must end within this factor of the residual the
+# base's own (full-rebuild) solve reached, or the sequence engine rebuilds:
+# the corrected iteration has the exact fixed point, but only a converged
+# iterate reaches it.  On the drifting 128x128 climate graph (n=16384, one
+# v5e) a delta's residual reads 0.65-1.09 of the base's up to a drift of
+# 0.17; two leaves that spread room and no more.
+SOLVE_RESIDUAL_SLACK = 2.0
 
 
 # ---------------------------------------------------------------------------
@@ -97,6 +133,11 @@ class _GemmLedger:
         self.bytes += (n * n + 2.0 * n * w) * 4.0
         self.scratch += n * w * 4.0
 
+    def add(self, other: "_GemmLedger") -> None:
+        self.flops += other.flops
+        self.bytes += other.bytes
+        self.scratch += other.scratch
+
 
 def full_build_gemm_cost(n: int, d_len: int) -> tuple[float, float, float]:
     """(flops, bytes, scratch) of one full chain build.
@@ -120,48 +161,61 @@ def full_build_gemm_cost(n: int, d_len: int) -> tuple[float, float, float]:
 
 @dataclass
 class BaseChain:
-    """A full chain build plus the retained per-level factors deltas need.
+    """A full chain build plus the retained levels deltas multiply against.
 
-    ``t_levels`` holds T_0 .. T_{d-1} (T_0 = S~); ``p_levels`` holds
-    P_1 .. P_{d-2} (P_0 = I + T_0 is applied implicitly, the final P_{d-1}
-    is never needed).  Arrays or store-backed handles, matching the build.
-    ``op`` is the base operator with ``shared_base=True`` stamped on it, so
-    the sequence engine's per-snapshot ``release_scratch()`` cannot retire
-    scratch that corrected operators still stream; :meth:`release` is the
-    one place the base scratch actually dies.
+    ``t_levels`` holds T_0 .. T_{d-1} (T_0 = S~), arrays or store-backed
+    handles, matching the build.  ``op`` is the base operator with
+    ``shared_base=True`` stamped on it, so the sequence engine's
+    per-snapshot ``release_scratch()`` cannot retire scratch that the base
+    still owns; :meth:`release` is the one place the base scratch actually
+    dies.
     """
 
     op: ChainOperator
     t_levels: list = field(default_factory=list)
-    p_levels: list = field(default_factory=list)
     d_len: int = 1
     deflate: bool = True
     released: bool = False
+    residual: float = math.nan  # final residual of the base's own solve
+
+    def solved(self, residual: float) -> None:
+        """Record the final residual of the base's own solve (the bar a
+        delta's solve must meet) and free the base's P2: no delta reads it,
+        since a corrected operator applies ``P1' (D' - A')`` from its own
+        snapshot."""
+        self.residual = float(residual)
+        p2, self.op.p2 = self.op.p2, None
+        _remove_handle(p2, "BaseChain.solved: could not remove the base's P2")
 
     def release(self) -> None:
         """Retire the base: operator scratch plus every retained level.
 
         Idempotent -- a second release is a no-op, never a double-free (the
-        regression the shared-base lifecycle audit guards).
+        regression the shared-base lifecycle audit guards).  Drops the
+        base's references too, so a resident base's n x n buffers are freed
+        before whatever allocates next (the fallback's rebuild).
         """
         if self.released:
             return
         self.released = True
         self.op.shared_base = False
         self.op.release_scratch()
-        for buf in (*self.t_levels, *self.p_levels):
-            store = getattr(buf, "store", None)
-            if store is not None and hasattr(buf, "snap_id"):
-                try:
-                    store.remove_snapshot(buf.snap_id)
-                except (OSError, ValueError, KeyError) as e:
-                    warnings.warn(
-                        f"BaseChain.release: could not remove retained level "
-                        f"{buf.snap_id!r} ({e!r})",
-                        RuntimeWarning,
-                        stacklevel=2,
-                    )
-        self.t_levels, self.p_levels = [], []
+        for buf in self.t_levels:
+            _remove_handle(buf, "BaseChain.release: could not remove retained level")
+        self.t_levels = []
+        self.op = None
+
+
+def _remove_handle(buf, what: str) -> None:
+    """Remove a store-backed level from its scratch store (a resident array
+    is freed with its last reference); a failed removal is warned."""
+    store = getattr(buf, "store", None)
+    if store is None or not hasattr(buf, "snap_id"):
+        return
+    try:
+        store.remove_snapshot(buf.snap_id)
+    except (OSError, ValueError, KeyError) as e:
+        warnings.warn(f"{what} {buf.snap_id!r} ({e!r})", RuntimeWarning, stacklevel=3)
 
 
 def build_base_chain(
@@ -192,11 +246,7 @@ def build_base_chain(
     op.shared_base = True
     _OBS_REGISTRY.add_named({"chain.full_rebuilds": 1.0})
     return BaseChain(
-        op=op,
-        t_levels=list(sink.get("t", ())),
-        p_levels=list(sink.get("p", ())),
-        d_len=cfg.d,
-        deflate=cfg.deflate,
+        op=op, t_levels=list(sink.get("t", ())), d_len=cfg.d, deflate=cfg.deflate
     )
 
 
@@ -205,32 +255,34 @@ def build_base_chain(
 # ---------------------------------------------------------------------------
 
 
-def truncate_factors(
-    u: np.ndarray, v: np.ndarray, r: int
-) -> tuple[np.ndarray, np.ndarray]:
-    """Best rank-r recompression of ``u @ v.T`` (exact, O(n r^2)).
+def _mm(a, b):
+    return jnp.matmul(a, b, precision=F32_PRECISION)
+
+
+@partial(jax.jit, static_argnames="r")
+def truncate_factors(u, v, r: int):
+    """Best rank-r recompression of ``u @ v.T`` (exact, O(n r^2)), on the
+    device in float32.
 
     QR both factors, SVD the small core: ``u v^T = qu (ru rv^T) qv^T``;
     keeping the top r singular triplets of the core is the optimal rank-r
     approximation of the product itself.
     """
-    qu, ru = np.linalg.qr(u.astype(np.float64))
-    qv, rv = np.linalg.qr(v.astype(np.float64))
-    w, s, zt = np.linalg.svd(ru @ rv.T)
-    rr = min(int(r), s.size)
-    u_t = qu @ (w[:, :rr] * s[:rr])
-    v_t = qv @ zt[:rr].T
-    return u_t.astype(np.float32), v_t.astype(np.float32)
+    qu, ru = jnp.linalg.qr(u.astype(jnp.float32))
+    qv, rv = jnp.linalg.qr(v.astype(jnp.float32))
+    w, s, zt = jnp.linalg.svd(_mm(ru, rv.T))
+    rr = min(int(r), s.shape[0])
+    return _mm(qu, w[:, :rr] * s[:rr]), _mm(qv, zt[:rr].T)
 
 
-def _rademacher_omega(n: int, m: int, seed: int) -> np.ndarray:
+def _rademacher_omega(n: int, m: int, seed: int) -> jax.Array:
     """(n, m) +/-1 test matrix from the counter-based hash (zero stored
     randomness, deterministic across hosts -- same contract as the edge
     projection's Rademacher field)."""
     rows = jnp.arange(n, dtype=jnp.uint32)[:, None]
     cols = jnp.arange(m, dtype=jnp.uint32)[None, :]
     h = crng.hash_u32(np.uint32(int(seed) & 0xFFFFFFFF), rows, cols)
-    return np.asarray(1.0 - 2.0 * (h >> 31).astype(jnp.float32), np.float32)
+    return 1.0 - 2.0 * (h >> 31).astype(jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -246,13 +298,12 @@ class _Passes:
         self.depth = depth
         self.ledger = ledger
 
-    def mm(self, mat, x_np: np.ndarray) -> np.ndarray:
-        """mat @ x for an (n, w) host operand; mat is resident or a handle."""
-        n, w = int(mat.shape[0]), int(x_np.shape[1])
+    def mm(self, mat, x: jax.Array) -> jax.Array:
+        """mat @ x for an (n, w) block; mat is resident or a handle."""
+        n, w = int(mat.shape[0]), int(x.shape[1])
         self.ledger.skinny(n, w)
-        x = self.ctx.put_rowblock(jnp.asarray(x_np, jnp.float32))
-        out = matmul_rowblock(self.ctx, mat, x, prefetch_depth=self.depth)
-        return np.asarray(out, np.float32)
+        x = self.ctx.constrain(x.astype(jnp.float32), self.ctx.rowblock_spec)
+        return matmul_rowblock(self.ctx, mat, x, prefetch_depth=self.depth)
 
 
 def try_delta_update(
@@ -266,6 +317,15 @@ def try_delta_update(
     so the same budget bounds both per-transition drift and accumulated
     drift, and incremental error cannot compound across transitions.
     """
+    with timed("delta.update", n=int(a.shape[0]), rank=int(cfg.delta_rank)) as sp:
+        op = _delta_update(ctx, base, a, cfg)
+        if op is not None:
+            sp.fence((op.p1_scale, op.u1, op.v1))
+        sp.annotate(fallback=op is None)
+    return op
+
+
+def _delta_update(ctx: DistContext, base: BaseChain, a, cfg) -> ChainOperator | None:
     n = int(a.shape[0])
     r = int(cfg.delta_rank)
     m = r + DELTA_OVERSAMPLE
@@ -273,111 +333,78 @@ def try_delta_update(
     ledger = _GemmLedger()
     ps = _Passes(ctx, depth, ledger)
 
-    t_lv, p_lv = base.t_levels, base.p_levels
+    t_lv = base.t_levels
     if len(t_lv) != base.d_len:
         raise ValueError(
             f"base chain retained {len(t_lv)} T levels for d={base.d_len}; "
             f"was it built with build_base_chain()?"
         )
 
-    # -- current snapshot's degree data (needed by the corrected op anyway) --
-    deg_new = lap.degrees(ctx, a, prefetch_depth=depth)
-    vol_new = lap.volume(ctx, deg_new)
-    deg_n = np.asarray(deg_new, np.float64)
-    vol_n = float(vol_new)
-    inv_sqrt_n = np.where(deg_n > 0, 1.0 / np.sqrt(np.maximum(deg_n, 1e-30)), 0.0)
-    deg_b = np.asarray(base.op.deg, np.float64)
-    vol_b = float(base.op.vol)
-    sqrt_b = np.sqrt(np.maximum(deg_b, 0.0))
+    with obs_trace.span("delta.sketch", n=n, width=m) as sp:
+        # -- current snapshot's degree data (the corrected op needs it anyway)
+        deg_new = lap.degrees(ctx, a, prefetch_depth=depth)
+        vol_new = lap.volume(ctx, deg_new)
+        inv_sqrt_n = jnp.where(
+            deg_new > 0, jax.lax.rsqrt(jnp.maximum(deg_new, 1e-30)), 0.0
+        )[:, None]
+        u_new = jnp.sqrt(jnp.maximum(deg_new, 0.0) / jnp.maximum(vol_new, 1e-30))[:, None]
+        sqrt_b = jnp.sqrt(jnp.maximum(base.op.deg, 0.0))[:, None]
 
-    def s_new(x: np.ndarray) -> np.ndarray:
-        """S~' x from the raw snapshot: D'^{-1/2} A' D'^{-1/2} x (- u' u'^T x)."""
-        y = inv_sqrt_n[:, None] * ps.mm(a, (inv_sqrt_n[:, None] * x).astype(np.float32))
-        if base.deflate:
-            u = np.sqrt(np.maximum(deg_n, 0.0) / max(vol_n, 1e-30))
-            y = y - u[:, None] * (u @ x)
-        return y.astype(np.float32)
+        def s_new(x: jax.Array) -> jax.Array:
+            """S~' x from the raw snapshot: D'^{-1/2} A' D'^{-1/2} x (- u' u'^T x)."""
+            y = inv_sqrt_n * ps.mm(a, inv_sqrt_n * x)
+            if base.deflate:
+                y = y - u_new * _mm(u_new.T, x)
+            return y
 
-    # -- 1. sketch dS and measure drift -------------------------------------
-    omega = _rademacher_omega(n, m, cfg.seed + 0x5EED)
-    s_base_w = ps.mm(t_lv[0], omega)  # S~ W (base, retained T_0)
-    s_new_w = s_new(omega)  # S~' W (implicit, from the raw snapshot)
-    dy = s_new_w - s_base_w
-    base_norm = max(float(np.linalg.norm(s_base_w)), 1e-30)
-    drift = float(np.linalg.norm(dy)) / base_norm
-    _OBS_REGISTRY.append("chain.drift", drift)
-    _OBS_REGISTRY.set_gauge("chain.drift_last", drift)
-    if drift > float(cfg.delta_budget):
-        _OBS_REGISTRY.add_named({"chain.drift_fallbacks": 1.0})
-        return None
-
-    # Range-finder: dS ~= Q (dS Q)^T (dS symmetric).  Zero drift (identical
-    # snapshot) short-circuits to an empty-correction operator via rank-0
-    # factors -- the truncation below handles the degenerate SVD fine.
-    q, _ = np.linalg.qr(dy.astype(np.float64))
-    q = q.astype(np.float32)
-    w0 = s_new(q) - ps.mm(t_lv[0], q)  # dS Q
-    u_t, v_t = truncate_factors(q, w0, r)  # dT_0 = dS ~= u_t v_t^T
-
-    # -- 2. propagate through the squaring recurrence ------------------------
-    e_f, f_f = u_t.copy(), v_t.copy()  # dP_0 = dS (P_0 = I + T_0)
-    for lvl in range(1, base.d_len):
-        # dT_lvl from dT_{lvl-1}: one width-2r pass against base T_{lvl-1}
-        uv = ps.mm(t_lv[lvl - 1], np.concatenate([u_t, v_t], axis=1))
-        tu, tv = uv[:, : u_t.shape[1]], uv[:, u_t.shape[1] :]
-        u2r = np.concatenate([tu, u_t], axis=1)
-        v2r = np.concatenate([v_t, tv + v_t @ (u_t.T @ v_t)], axis=1)
-        ut_new, vt_new = truncate_factors(u2r, v2r, r)
-        # dP_lvl: P_{lvl-1} @ Ut (P_0 applied implicitly as I + T_0)
-        if lvl == 1:
-            pu = ut_new + ps.mm(t_lv[0], ut_new)
-        else:
-            pu = ps.mm(p_lv[lvl - 2], ut_new)
-        tf = ps.mm(t_lv[lvl], f_f)  # T_lvl @ F
-        e2r = np.concatenate([e_f, pu + e_f @ (f_f.T @ ut_new)], axis=1)
-        f2r = np.concatenate([f_f + tf, vt_new], axis=1)
-        e_f, f_f = truncate_factors(e2r, f2r, r)
-        u_t, v_t = ut_new, vt_new
-
-    # -- 3. corrected P1 (exact): diag(s) P1 diag(s) + E~ F~^T ---------------
-    p1_scale = (sqrt_b * inv_sqrt_n).astype(np.float32)
-    u1 = (inv_sqrt_n[:, None] * e_f).astype(np.float32)
-    v1 = (inv_sqrt_n[:, None] * f_f).astype(np.float32)
-
-    def p1_corr(x: np.ndarray) -> np.ndarray:
-        """P1' x through the base P1 plus the exact correction."""
-        y = p1_scale[:, None] * ps.mm(
-            base.op.p1, (p1_scale[:, None] * x).astype(np.float32)
+        omega = _rademacher_omega(n, max(m, min(DRIFT_SKETCH_COLS, n // 16)), cfg.seed + 0x5EED)
+        s_base_w = ps.mm(t_lv[0], omega)  # S~ W (base, retained T_0)
+        dy = s_new(omega) - s_base_w  # dS W, S~' W implicit from the snapshot
+        drift = float(
+            jnp.linalg.norm(dy) / jnp.maximum(jnp.linalg.norm(s_base_w), 1e-30)
         )
-        return (y + u1 @ (v1.T @ x)).astype(np.float32)
+        _OBS_REGISTRY.append("chain.drift", drift)
+        _OBS_REGISTRY.set_gauge("chain.drift_last", drift)
+        sp.annotate(drift=drift)
+        if drift > float(cfg.delta_budget):
+            _OBS_REGISTRY.add_named({"chain.drift_fallbacks": 1.0})
+            return None
 
-    def l_new(x: np.ndarray) -> np.ndarray:
-        """L' x = deg' . x - A' x from the raw snapshot."""
-        return (deg_n[:, None] * x - ps.mm(a, x)).astype(np.float32)
+        # Range-finder: dS ~= Q (dS Q)^T (dS symmetric).  Zero drift (an
+        # identical snapshot) gives rank-0 factors, which the truncation
+        # below handles fine.
+        q, _ = jnp.linalg.qr(dy[:, :m])
+        w0 = s_new(q) - ps.mm(t_lv[0], q)  # dS Q
+        dt0 = truncate_factors(q, w0, r)  # dT_0 = dS ~= u v^T
 
-    def l_base(x: np.ndarray) -> np.ndarray:
-        """Base L x reconstructed from retained T_0 (no base adjacency kept):
-        A = D^{1/2} (T_0 [+ u u^T]) D^{1/2} with u = sqrt(deg / V_G)."""
-        ax = sqrt_b[:, None] * ps.mm(t_lv[0], (sqrt_b[:, None] * x).astype(np.float32))
-        if base.deflate:
-            du = deg_b / max(np.sqrt(max(vol_b, 1e-30)), 1e-30)  # sqrt(d) . u
-            ax = ax + du[:, None] * (du @ x)
-        return (deg_b[:, None] * x - ax).astype(np.float32)
+    with obs_trace.span("delta.propagate", levels=base.d_len - 1):
+        e_f, f_f = _propagate(ps, t_lv, dt0, r)
 
-    # -- 4. dP2 = P1' L' - P1 L via a two-pass range-finder ------------------
-    omega2 = _rademacher_omega(n, m, cfg.seed + 0xD2)
-    fwd = p1_corr(l_new(omega2)) - ps.mm(base.op.p2, omega2)
-    q2, _ = np.linalg.qr(fwd.astype(np.float64))
-    q2 = q2.astype(np.float32)
-    # adjoint on Q: dP2^T q = L'(P1' q) - L(P1 q); the two base-P1 products
-    # share one width-2m pass over P1.
-    both = ps.mm(
-        base.op.p1, np.concatenate([p1_scale[:, None] * q2, q2], axis=1)
-    )
-    p1q_scaled, p1q = both[:, : q2.shape[1]], both[:, q2.shape[1] :]
-    p1c_q = p1_scale[:, None] * p1q_scaled + u1 @ (v1.T @ q2)
-    v2_full = l_new(p1c_q) - l_base(p1q)
-    u2, v2 = truncate_factors(q2, v2_full, r)
+    with obs_trace.span("delta.correct") as sp:
+        # P1' = diag(s) P1 diag(s) + E~ F~^T; the solve applies P2' = P1' L'
+        rb = ctx.sharding(ctx.rowblock_spec)
+        op = ChainOperator(
+            p1=base.op.p1,
+            p2=None,
+            deg=deg_new,
+            vol=vol_new,
+            prefetch_depth=base.op.prefetch_depth,
+            # Keep the base interval bound: corrected spectra move by
+            # O(||dS||) and both Chebyshev (Manteuffel adaptation) and CG are
+            # robust to a slightly stale rho; re-measuring would cost power
+            # iterations per transition, defeating the delta path's point.
+            rho=base.op.rho,
+            use_gemm_kernel=base.op.use_gemm_kernel,
+            p1_scale=jax.device_put(
+                (sqrt_b * inv_sqrt_n)[:, 0],
+                ctx.sharding(jax.sharding.PartitionSpec(None)),
+            ),
+            u1=jax.device_put(inv_sqrt_n * e_f, rb),
+            v1=jax.device_put(inv_sqrt_n * f_f, rb),
+            adj=a,
+            shared_base=True,
+        )
+        sp.fence((op.p1_scale, op.u1, op.v1))
 
     _OBS_REGISTRY.add_named({
         "chain.incremental_updates": 1.0,
@@ -387,26 +414,73 @@ def try_delta_update(
         "chain.delta_gemm_flops": ledger.flops,
         "chain.delta_gemm_bytes": ledger.bytes,
     })
+    return op
 
-    rb = ctx.sharding(ctx.rowblock_spec)
-    return ChainOperator(
-        p1=base.op.p1,
-        p2=base.op.p2,
-        deg=deg_new,
-        vol=vol_new,
-        prefetch_depth=base.op.prefetch_depth,
-        # Keep the base interval bound: corrected spectra move by O(||dS||)
-        # and both Chebyshev (Manteuffel adaptation, PR 8) and CG are robust
-        # to a slightly stale rho; re-measuring would cost power iterations
-        # per transition, defeating the delta path's point.
-        rho=base.op.rho,
-        use_gemm_kernel=base.op.use_gemm_kernel,
-        p1_scale=jax.device_put(
-            jnp.asarray(p1_scale), ctx.sharding(jax.sharding.PartitionSpec(None))
-        ),
-        u1=jax.device_put(jnp.asarray(u1), rb),
-        v1=jax.device_put(jnp.asarray(v1), rb),
-        u2=jax.device_put(jnp.asarray(u2), rb),
-        v2=jax.device_put(jnp.asarray(v2), rb),
-        shared_base=True,
-    )
+
+def _propagate(ps: _Passes, t_lv: list, dt0, r: int):
+    """dP_{d-1} as rank-r factors (E, F), from dT_0 and the T levels.
+
+    Resident levels run as one compiled program (:func:`_propagate_program`),
+    one dispatch in place of one per operation; store-backed levels stream
+    pass by pass (:func:`_propagate_passes`).
+    """
+    if any(is_streamable(t) for t in t_lv):
+        return _propagate_passes(ps, t_lv, dt0, r)
+    fn, ledgers = _propagate_program(ps.ctx, r)
+    e_f, f_f = fn(tuple(t_lv), *dt0)
+    ps.ledger.add(ledgers[(tuple(t.shape for t in t_lv), dt0[0].shape, dt0[1].shape)])
+    return e_f, f_f
+
+
+@lru_cache(maxsize=None)
+def _propagate_program(ctx: DistContext, r: int):
+    """The jitted :func:`_propagate_passes` over resident levels, and the
+    ledger of its passes by operand shapes (recorded when it is traced)."""
+    ledgers: dict = {}
+
+    def run(t_lv, u0, v0):
+        ledger = _GemmLedger()
+        out = _propagate_passes(_Passes(ctx, None, ledger), list(t_lv), (u0, v0), r)
+        ledgers[(tuple(t.shape for t in t_lv), u0.shape, v0.shape)] = ledger
+        return out
+
+    return jax.jit(run), ledgers
+
+
+def _propagate_passes(ps: _Passes, t_lv: list, dt0, r: int):
+    """:func:`_propagate` pass by pass: eagerly, or traced once per shape
+    by :func:`_propagate_program`.
+
+    The dT chain runs first (one pass over T_{l-1} per level); then one pass
+    per T level, from T_{d-2} down to T_0, applies ``(I + T_j)`` to a block
+    that gathers each Ut_l as its first factor T_{l-1} comes up, which
+    leaves ``P_{l-1} Ut_l`` for every level (the T_j commute); last, the dP
+    recurrence (one pass over T_l per level for ``T_l F``).
+    """
+    d_len = len(t_lv)
+    u_t, v_t = dt0
+    dts = []  # (Ut_l, Vt_l) for l = 1 .. d-1
+    for lvl in range(1, d_len):
+        uv = ps.mm(t_lv[lvl - 1], jnp.concatenate([u_t, v_t], axis=1))
+        tu, tv = uv[:, : u_t.shape[1]], uv[:, u_t.shape[1] :]
+        u2r = jnp.concatenate([tu, u_t], axis=1)
+        v2r = jnp.concatenate([v_t, tv + _mm(v_t, _mm(u_t.T, v_t))], axis=1)
+        u_t, v_t = truncate_factors(u2r, v2r, r)
+        dts.append((u_t, v_t))
+
+    block = None
+    for j in range(d_len - 2, -1, -1):
+        head = dts[j][0]
+        block = head if block is None else jnp.concatenate([head, block], axis=1)
+        block = block + ps.mm(t_lv[j], block)
+    widths = np.cumsum([0] + [ut.shape[1] for ut, _ in dts])
+
+    e_f, f_f = dt0  # dP_0 = dS (P_0 = I + T_0)
+    for lvl in range(1, d_len):
+        ut, vt = dts[lvl - 1]
+        pu = block[:, widths[lvl - 1] : widths[lvl]]  # P_{lvl-1} Ut
+        tf = ps.mm(t_lv[lvl], f_f)  # T_lvl F
+        e2r = jnp.concatenate([e_f, pu + _mm(e_f, _mm(f_f.T, ut))], axis=1)
+        f2r = jnp.concatenate([f_f + tf, vt], axis=1)
+        e_f, f_f = truncate_factors(e2r, f2r, r)
+    return e_f, f_f

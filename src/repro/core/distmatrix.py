@@ -89,7 +89,13 @@ class DistContext:
         return NamedSharding(self.mesh, spec)
 
     def constrain(self, x: jax.Array, spec: P) -> jax.Array:
-        return lax.with_sharding_constraint(x, self.sharding(spec))
+        sharding = self.sharding(spec)
+        if isinstance(x, jax.Array) and not isinstance(x, jax.core.Tracer):
+            # Eagerly, a constraint is a dispatched copy; an array already
+            # laid out so is returned as it is.
+            if x.sharding.is_equivalent_to(sharding, x.ndim):
+                return x
+        return lax.with_sharding_constraint(x, sharding)
 
     def put_matrix(self, x) -> jax.Array:
         return jax.device_put(jnp.asarray(x), self.sharding(self.matrix_spec))

@@ -81,9 +81,11 @@ class CommuteConfig:
     # retained base chain (O(n^2 r) per level), and attach the result to the
     # operator as a low-rank correction every solve applies.  `delta_budget`
     # is the drift gate: the sketched relative drift ||dS|| / ||S|| (always
-    # measured against the last *full-rebuild* base, so corrections never
-    # compound error) above which the detector falls back to a full rebuild
-    # and collapses the accumulated correction into a fresh base.
+    # measured against the last *full-rebuild* base) above which the
+    # detector falls back to a full rebuild and collapses the accumulated
+    # correction into a fresh base.  Drift only slows the corrected solve,
+    # whose fixed point is exact; a delta whose solve falls short of the
+    # base's residual rebuilds too (sequence engine).
     incremental_chain: bool = False
     delta_rank: int = 4
     delta_budget: float = 0.1

@@ -210,12 +210,11 @@ def chain_product_oocore(
 ) -> ChainOperator:
     """Build the chain operator with store-backed working matrices.
 
-    ``level_sink`` retains the intermediate chain levels as *live* scratch
-    snapshots for incremental delta updates (see
-    :func:`repro.core.chain.chain_product`): the usual eager removal of
-    T/P intermediates is skipped for retained levels, ``level_sink["t"]``
-    gets the T_0 .. T_{d-1} handles and ``level_sink["p"]`` the
-    P_1 .. P_{d-2} handles, and the caller owns their lifetime
+    ``level_sink`` retains the squaring levels as *live* scratch snapshots
+    for incremental delta updates (see
+    :func:`repro.core.chain.chain_product`): the usual eager removal of the
+    T intermediates is skipped, ``level_sink["t"]`` gets the T_0 .. T_{d-1}
+    handles, and the caller owns their lifetime
     (``delta_chain.BaseChain.release()`` removes them).
 
     ``a`` is a resident sharded adjacency or a store-backed snapshot handle
@@ -406,15 +405,14 @@ def chain_product_oocore(
     # path streams skinny GEMMs against them); without one they are removed
     # as soon as the recurrence no longer needs them, as before.
     retain = level_sink is not None
-    t_levels, p_levels = [t_h], []
+    t_levels = [t_h]
     for lvl in range(1, d_len):
-        p_levels.append(p_h)
         t_new = oo_gemm(f"{tag}Tlvl{lvl}", t_h, t_h)
         p_new = oo_gemm(f"{tag}Plvl{lvl}", p_h, t_new, init="left")
         t_levels.append(t_new)
         if not retain:
             work.remove_snapshot(t_h.snap_id)
-            work.remove_snapshot(p_h.snap_id)
+        work.remove_snapshot(p_h.snap_id)
         t_h, p_h = t_new, p_new
 
     # the P1 sandwich is the same row/col scaling as the undeflated S build
@@ -427,14 +425,9 @@ def chain_product_oocore(
         p2_h = oo_gemm(tag + "P2", p1_h, l_h)
         work.remove_snapshot(l_h.snap_id)
     if retain:
-        # T_0..T_{d-1} and P_1..P_{d-2} stay live for the delta path; the
-        # final P (never multiplied against) and the implicit P_0 = I + T_0
-        # are not needed and die now.
+        # T_0..T_{d-1} stay live for the delta path; the final P dies now.
         work.remove_snapshot(p_h.snap_id)
-        if p_levels:
-            work.remove_snapshot(p_levels[0].snap_id)
         level_sink["t"] = t_levels
-        level_sink["p"] = p_levels[1:]
     else:
         work.remove_snapshot(t_h.snap_id)
         work.remove_snapshot(p_h.snap_id)

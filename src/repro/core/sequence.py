@@ -29,7 +29,7 @@ from __future__ import annotations
 
 import time
 import warnings
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Iterable
 
 import jax
@@ -39,7 +39,12 @@ from jax import lax
 
 from repro.core import chain
 from repro.core.cad import CADResult, node_anomaly_scores, top_anomalies
-from repro.core.delta_chain import BaseChain, build_base_chain, try_delta_update
+from repro.core.delta_chain import (
+    SOLVE_RESIDUAL_SLACK,
+    BaseChain,
+    build_base_chain,
+    try_delta_update,
+)
 from repro.core.distmatrix import DistContext
 from repro.core.embedding import CommuteConfig, Embedding, commute_time_embedding
 from repro.obs import phase
@@ -129,31 +134,42 @@ class SequenceDetector:
 
     # -- snapshot lifecycle --------------------------------------------------
 
+    def _retire_op(self, emb: Embedding) -> Embedding:
+        """Drop an embedding's chain operator once its solve is done:
+        scoring reads only ``z`` and ``vol``, and publish ``z``, ``vol`` and
+        the degrees, so holding P1 / P2 until
+        the snapshot leaves the window would keep two n x n buffers alive
+        through the next chain build.
+
+        An out-of-core operator's P1 / P2 handles live in a scratch store
+        owned by the build; those snapshots are removed here (without this,
+        a disk-backed scratch would grow by 2 n^2 bytes per snapshot for the
+        whole sequence).  A resident operator is freed by refcount, or
+        deleted eagerly under ``donate=True``.  An operator that shares the
+        incremental base chain is left alone: ``BaseChain.release()`` owns
+        its buffers.
+        """
+        op = emb.op
+        if op is not None:
+            op.release_scratch()  # no-op when the op shares the base chain
+            if self.donate and not op.shared_base:
+                self._delete(op.p1, op.p2)
+        return replace(emb, op=None)
+
     def _release(self, a: jax.Array, emb: Embedding) -> None:
         """Retire an outgoing snapshot as it leaves the two-snapshot window.
 
-        An out-of-core chain operator's P1 / P2 handles live in a scratch
-        store owned by the build; those snapshots are ALWAYS removed here
-        (resident operators are freed by refcount either way -- without this,
-        a disk-backed scratch would grow by 2 n^2 bytes per snapshot for the
-        whole sequence).  The input snapshot ``a`` may also be a store-backed
-        handle -- that is the *user's* data and is never removed from its
-        store.  ``donate=True`` additionally deletes the outgoing *device*
-        buffers eagerly (double buffering); callers must not touch a donated
-        snapshot again.
+        The input snapshot ``a`` may be a store-backed handle -- that is the
+        *user's* data and is never removed from its store.  ``donate=True``
+        deletes the outgoing *device* buffers eagerly (double buffering);
+        callers must not touch a donated snapshot again.
         """
-        if emb.op is not None:
-            emb.op.release_scratch()  # no-op when the op shares the base chain
-        if not self.donate:
-            return
-        shared = emb.op is not None and getattr(emb.op, "shared_base", False)
-        for buf in (
-            a, emb.z,
-            # A shared-base op's P1/P2 *are* the retained base chain's arrays
-            # (possibly still serving later incremental transitions): never
-            # donate-delete them here -- BaseChain.release() owns that.
-            *(() if emb.op is None or shared else (emb.op.p1, emb.op.p2)),
-        ):
+        if self.donate:
+            self._delete(a, emb.z)
+
+    @staticmethod
+    def _delete(*bufs) -> None:
+        for buf in bufs:
             delete = getattr(buf, "delete", None)
             if delete is None:
                 continue  # store-backed handle: the user's data, not ours
@@ -167,41 +183,85 @@ class SequenceDetector:
                 warnings.warn(
                     f"snapshot buffer delete failed during release: {exc!r}",
                     RuntimeWarning,
-                    stacklevel=2,
+                    stacklevel=3,
                 )
 
-    def _incremental_op(self, a):
-        """The chain operator for snapshot ``a`` under incremental mode.
+    def _incremental_embedding(self, a, warm_from) -> Embedding:
+        """Snapshot ``a``'s embedding under incremental mode.
 
         Tries a low-rank delta update against the retained base chain
         (:func:`repro.core.delta_chain.try_delta_update`); when the drift
         monitor rejects the transition -- or there is no base yet -- the
         accumulated correction collapses into a fresh full build that becomes
-        the new base.  Timing lands under the same ``phase("chain")`` counter
-        the full-build path uses, so per-transition chain seconds stay
-        comparable across modes.
+        the new base.  The corrected operator is a preconditioner whose
+        iteration has the exact solution as its fixed point, so a delta
+        transition must solve as far as a rebuild would
+        (:meth:`_solved_as_far`), or it falls back to a rebuild too
+        (``chain.solve_fallbacks``).  Chain timing lands under the same
+        ``phase("chain")`` counter the full-build path uses, so
+        per-transition chain seconds stay comparable across modes.
         """
-        with phase(
+        op = self._incremental_op(a)
+        emb = commute_time_embedding(
+            self.ctx, a, self.cfg, op=op, use_kernel=self.use_kernel,
+            warm_from=warm_from,
+        )
+        if op.adj is None:  # a rebuild: its residual is the bar deltas meet
+            self._base.solved(emb.report.residual)
+            return emb
+        if self._solved_as_far(emb.report):
+            return emb
+        del op
+        emb = None  # the corrected operator holds base P1: drop it first
+        _OBS_REGISTRY.add_named({"chain.solve_fallbacks": 1.0})
+        with self._chain_phase(a) as sp:
+            self._rebuild(a, sp)
+            sp.annotate(fallback="residual")
+        emb = commute_time_embedding(
+            self.ctx, a, self.cfg, op=self._base.op, use_kernel=self.use_kernel,
+            warm_from=warm_from,
+        )
+        self._base.solved(emb.report.residual)
+        return emb
+
+    def _solved_as_far(self, report) -> bool:
+        """Whether a delta transition's solve reached what the rebuild
+        promises: the configured tolerance where there is one, else within
+        ``SOLVE_RESIDUAL_SLACK`` of the base's own fixed-step residual.  A
+        NaN on either side is not converged."""
+        if self.cfg.solver_tol is not None:
+            return report.converged
+        return bool(report.residual <= SOLVE_RESIDUAL_SLACK * self._base.residual)
+
+    def _chain_phase(self, a):
+        return phase(
             "chain", n=int(a.shape[0]), d=self.cfg.d, oocore=self.cfg.oocore,
             incremental=True,
-        ) as sp:
+        )
+
+    def _incremental_op(self, a):
+        """The delta-corrected operator for ``a``, or the new base's."""
+        with self._chain_phase(a) as sp:
             if self._base is not None:
                 op = try_delta_update(self.ctx, self._base, a, self.cfg)
                 if op is not None:
                     sp.annotate(mode="delta")
                     return op
-                # drift over budget: retire the base before rebuilding
-                self._base.release()
-                self._base = None
-            self._base = build_base_chain(
-                self.ctx, a, self.cfg, use_kernel=self.use_kernel
-            )
-            sp.annotate(mode="rebuild")
-            op = self._base.op
-            sp.fence(op.vol)
+            return self._rebuild(a, sp)
+
+    def _rebuild(self, a, sp):
+        """Free the old base, then build the new one from ``a`` (its
+        operator is returned)."""
+        if self._base is not None:
+            self._base.release()
+            self._base = None
+        self._base = build_base_chain(self.ctx, a, self.cfg, use_kernel=self.use_kernel)
+        sp.annotate(mode="rebuild")
+        op = self._base.op
+        sp.fence(op.vol)
         return op
 
-    def _publish(self, emb: Embedding) -> None:
+    def _publish(self, emb: Embedding, deg) -> None:
         """Publish snapshot t's committed embedding to the attached store.
 
         The artifact is a host-side *copy* of (z, vol, deg) -- readers never
@@ -215,7 +275,7 @@ class SequenceDetector:
                 f"t{self._t:04d}",
                 np.asarray(emb.z),
                 float(np.asarray(emb.vol)),
-                np.asarray(emb.op.deg),
+                np.asarray(deg),
             )
 
     def push(self, a) -> CADResult | None:
@@ -238,16 +298,20 @@ class SequenceDetector:
                 if (self.cfg.warm_start and self._prev is not None)
                 else None
             )
-            op_in = self._incremental_op(a) if self.cfg.incremental_chain else None
-            emb = commute_time_embedding(
-                self.ctx, a, self.cfg, op=op_in, use_kernel=self.use_kernel,
-                warm_from=warm_from,
-            )
-            if self.emb_store is not None:
-                self._publish(emb)
+            if self.cfg.incremental_chain:
+                emb = self._incremental_embedding(a, warm_from)
+            else:
+                emb = commute_time_embedding(
+                    self.ctx, a, self.cfg, use_kernel=self.use_kernel,
+                    warm_from=warm_from,
+                )
+            deg = emb.op.deg
+            emb = self._retire_op(emb)
             out = None
             if self._prev is not None:
                 a_prev, e_prev = self._prev
+                # Dispatched before publish: the device scores while the
+                # host writes the artifact.
                 scores = node_anomaly_scores(
                     self.ctx,
                     a_prev,
@@ -258,6 +322,9 @@ class SequenceDetector:
                     prefetch_depth=self.cfg.prefetch_depth,
                 )
                 idx, vals = top_anomalies(scores, self.top_k)
+            if self.emb_store is not None:
+                self._publish(emb, deg)
+            if self._prev is not None:
                 out = CADResult(
                     scores=scores, top_idx=idx, top_val=vals,
                     solve_reports=(e_prev.report, emb.report),

@@ -179,6 +179,23 @@ def _unrotate_hist(hist: np.ndarray, iters: int) -> list[float]:
     return [float(r) for r in out]
 
 
+def _corrected_matvec(ctx: DistContext, ops, y: jax.Array) -> jax.Array:
+    """``P2' y = P1' L' y`` of a delta-corrected operator, from its parts
+    ``ops = (p1, p1_scale, u1, v1, adj, deg)``: ``L' y = deg y - A' y``, then
+    ``P1' x = s (P1 (s x)) + u1 (v1^T x)``.  ``p1`` and ``adj`` are resident
+    arrays or store-backed handles (``matmul_rowblock`` streams those)."""
+    p1, scale, u1, v1, adj, deg = ops
+    y32 = y.astype(jnp.float32)
+    s_col = scale.astype(jnp.float32).reshape(-1, 1)
+    ly = deg.astype(jnp.float32).reshape(-1, 1) * y32 - matmul_rowblock(ctx, adj, y32)
+    low = jnp.dot(
+        u1, jnp.dot(v1.T, ly, precision=F32_PRECISION),
+        precision=F32_PRECISION, preferred_element_type=jnp.float32,
+    )
+    out = s_col * matmul_rowblock(ctx, p1, s_col * ly) + low
+    return ctx.constrain(out.astype(y.dtype), ctx.rowblock_spec)
+
+
 # ---------------------------------------------------------------------------
 # resident path: one cached while_loop program per (method, ctx, geometry)
 # ---------------------------------------------------------------------------
@@ -190,26 +207,24 @@ def _resident_program(ctx: DistContext, method: str, deflate: bool, chi,
     the warm-start iterate y0 are traced, so one compiled program serves
     every tolerance/cap/rho and both cold (y0 = chi) and warm starts.
 
-    ``corr_rank`` selects the delta-corrected variant: the incremental
-    low-rank factors (u2, v2) become *operands* of the same while_loop
-    program (P2' y = P2 y + u2 (v2^T y)), so a steady-state incremental
-    sequence compiles the corrected program once per correction rank and
-    every later corrected push is a cache hit.  Uncorrected solves keep the
-    historical program (and its bitwise behaviour) untouched.
+    ``corr_rank`` selects the delta-corrected variant: ``ops`` is then
+    ``(p1, p1_scale, u1, v1, adj, deg)`` and every mat-vec is
+    ``P2' y = P1' (deg y - A' y)`` (:func:`_corrected_matvec`), operands of
+    the same while_loop program, so a steady-state incremental sequence
+    compiles the corrected program once per correction rank and every later
+    corrected push is a cache hit.  Uncorrected solves pass ``ops = (p2,)``
+    and keep the historical program (and its bitwise behaviour) untouched.
     """
 
     def build():
-        def matvec(p2, y, u2, v2):
+        def matvec(ops, y):
+            if corr_rank is not None:
+                return _corrected_matvec(ctx, ops, y)
             # identical op sequence to matmul_rowblock's resident branch
             out = jnp.dot(
-                p2, y.astype(jnp.float32),
+                ops[0], y.astype(jnp.float32),
                 precision=F32_PRECISION, preferred_element_type=jnp.float32,
             )
-            if corr_rank is not None:
-                out = out + jnp.dot(
-                    u2, jnp.dot(v2.T, y.astype(jnp.float32), precision=F32_PRECISION),
-                    precision=F32_PRECISION, preferred_element_type=jnp.float32,
-                )
             return ctx.constrain(out.astype(y.dtype), ctx.rowblock_spec)
 
         def metric_deflate(delta):
@@ -223,7 +238,7 @@ def _resident_program(ctx: DistContext, method: str, deflate: bool, chi,
                 )
             return delta
 
-        def run(p2, u2, v2, chi, y0, tol, max_steps, rho):
+        def run(ops, chi, y0, tol, max_steps, rho):
             den = jnp.maximum(_frob(chi), 1e-30)
 
             def cond(carry):
@@ -234,7 +249,7 @@ def _resident_program(ctx: DistContext, method: str, deflate: bool, chi,
                 y, y_prev, k, kr, res_anchor, p_prev, rho_c, hist, _ = carry
                 gamma = 2.0 / (2.0 - rho_c)
                 sigma2 = (rho_c / (2.0 - rho_c)) ** 2
-                gy = y - matvec(p2, y, u2, v2) + chi  # G y + chi; gy - y is the residual
+                gy = y - matvec(ops, y) + chi  # G y + chi; gy - y is the residual
                 if method == "richardson":
                     y_new, p_new = gy, p_prev
                 else:
@@ -289,7 +304,7 @@ def _resident_program(ctx: DistContext, method: str, deflate: bool, chi,
             y, _, k, _, _, _, rho_c, hist, res = lax.while_loop(cond, body, init)
             return y, k, res, hist, rho_c
 
-        def run_cg(p2, u2, v2, chi, y0, w, tol, max_steps):
+        def run_cg(ops, chi, y0, w, tol, max_steps):
             den = jnp.maximum(_frob(chi), 1e-30)
             wcol = jnp.maximum(w.astype(jnp.float32), 0.0).reshape(-1, 1)
             wsum = jnp.maximum(jnp.sum(wcol), 1e-30)
@@ -303,7 +318,7 @@ def _resident_program(ctx: DistContext, method: str, deflate: bool, chi,
                 return x - jnp.sum(wcol * x, axis=0, keepdims=True) / wsum
 
             r0 = chi.astype(jnp.float32) - matvec(
-                p2, y0.astype(jnp.float32), u2, v2
+                ops, y0.astype(jnp.float32)
             ).astype(jnp.float32)
             if deflate:
                 r0 = dproj(r0)
@@ -315,7 +330,7 @@ def _resident_program(ctx: DistContext, method: str, deflate: bool, chi,
 
             def body(carry):
                 y, r, p, rz, k, _, hist = carry
-                q = matvec(p2, p, u2, v2)
+                q = matvec(ops, p)
                 if deflate:
                     q = ctx.constrain(dproj(q), ctx.rowblock_spec)
                 pq = wdot(p, q)
@@ -480,7 +495,7 @@ def _kernel_stream_pass(ctx, handle, y, chi, *, depth, fused):
 
 def _solve_streamed(
     ctx, p2_handle, chi, y0, method, deflate, tol, max_steps, rho,
-    solver_batch, prefetch_depth, use_kernel=False, w=None, u2=None, v2=None,
+    solver_batch, prefetch_depth, use_kernel=False, w=None, corr=None,
 ):
     p2, cached = p2_handle, None
     if solver_batch > 1 and is_streamable(p2_handle):
@@ -491,18 +506,12 @@ def _solve_streamed(
     n_rows = int(chi.shape[0])
     passes = 0
 
-    def low_rank(x):
-        """The delta correction u2 (v2^T x): device-resident factors, eager
-        skinny products -- never touches the panel stream."""
-        return jnp.dot(
-            u2, jnp.dot(v2.T, x.astype(jnp.float32), precision=F32_PRECISION),
-            precision=F32_PRECISION, preferred_element_type=jnp.float32,
-        )
-
     def stream_matvec(x):
-        """One P2' @ x pass over the stream (kernel path when enabled): the
-        base stream plus the rank-r correction epilogue when present."""
+        """One P2 @ x pass over the stream (kernel path when enabled); a
+        corrected operator's P1' L' x streams its P1 and adjacency."""
         nonlocal passes
+        if corr is not None:
+            return _corrected_matvec(ctx, corr, x).astype(jnp.float32)
         if cached is not None and passes and passes % solver_batch == 0:
             cached.refresh()  # batch boundary: next pass re-streams the store
         passes += 1
@@ -514,8 +523,6 @@ def _solve_streamed(
             mv = matmul_rowblock(
                 ctx, p2, x, prefetch_depth=prefetch_depth
             ).astype(jnp.float32)
-        if u2 is not None:
-            mv = mv + low_rank(x)
         return ctx.constrain(mv, ctx.rowblock_spec)
 
     def metric(delta):
@@ -586,15 +593,6 @@ def _solve_streamed(
             gy, cs, ss = _kernel_stream_pass(
                 ctx, p2, y, chi, depth=prefetch_depth, fused=True
             )
-            if u2 is not None:
-                # The fused kernel computed gy and the residual moments for
-                # the *base* P2; fold in the rank-r term and recompute the
-                # moments from delta = gy' - y (= chi - P2' y) -- a cheap
-                # eager epilogue, still one pass over the stream.
-                gy = gy.astype(jnp.float32) - low_rank(y)
-                delta = gy - y.astype(jnp.float32)
-                cs = np.asarray(jnp.sum(delta, axis=0), np.float64)
-                ss = float(jnp.sum(delta * delta))
             gy = ctx.constrain(gy.astype(chi.dtype), ctx.rowblock_spec)
             num2 = ss - float(np.sum(cs * cs)) / n_rows if deflate else ss
             res = math.sqrt(max(num2, 0.0)) / den
@@ -717,18 +715,18 @@ def solve(
             # inner product (exact only for uniform degrees).
             w = jnp.ones((int(b.shape[0]),), jnp.float32)
 
-    # Incremental-chain correction factors (None on a plain base operator).
-    # p1_scale/u1/v1 turn the chi build into the exact corrected
-    # P1' b = s * (P1 (s * b)) + u1 (v1^T b); u2/v2 add the rank-r ΔP2
-    # term to every mat-vec of the iteration.
+    # Incremental-chain correction (None on a plain base operator):
+    # p1_scale/u1/v1 turn the chi build into the corrected
+    # P1' b = s * (P1 (s * b)) + u1 (v1^T b), and every mat-vec of the
+    # iteration applies P2' = P1' (D' - A') from the snapshot's adjacency.
     p1_scale = getattr(op, "p1_scale", None)
     u1 = getattr(op, "u1", None)
     v1 = getattr(op, "v1", None)
-    u2 = getattr(op, "u2", None)
-    v2 = getattr(op, "v2", None)
-    corr_rank = None if u2 is None else int(u2.shape[1])
+    adj = getattr(op, "adj", None)
+    corr = None if adj is None else (op.p1, p1_scale, u1, v1, adj, op.deg)
+    corr_rank = None if corr is None else int(u1.shape[1])
 
-    streamed = is_streamable(op.p1) or is_streamable(op.p2)
+    streamed = any(is_streamable(m) for m in (op.p1, op.p2, adj))
     use_k = bool(
         use_gemm_kernel
         if use_gemm_kernel is not None
@@ -785,20 +783,21 @@ def solve(
             y, iters, res, res_hist, rho_final = _solve_streamed(
                 ctx, op.p2, chi, y_start, spec.method, deflate, tol, max_steps,
                 rho or 0.0, solver_batch, depth,
-                use_kernel=use_k and is_streamable(op.p2), w=w, u2=u2, v2=v2,
+                use_kernel=use_k and is_streamable(op.p2), w=w, corr=corr,
             )
             if spec.method != "chebyshev":
                 rho_final = rho
         else:
             prog = _resident_program(ctx, spec.method, deflate, chi, corr_rank)
+            ops = (op.p2,) if corr is None else corr
             if spec.method == "cg":
                 y, k_arr, res_arr, hist_arr = prog(
-                    op.p2, u2, v2, chi, y_start, jnp.asarray(w),
+                    ops, chi, y_start, jnp.asarray(w),
                     jnp.float32(tol), jnp.int32(max_steps),
                 )
             else:
                 y, k_arr, res_arr, hist_arr, rho_arr = prog(
-                    op.p2, u2, v2, chi, y_start, jnp.float32(tol),
+                    ops, chi, y_start, jnp.float32(tol),
                     jnp.int32(max_steps), jnp.float32(rho or 0.0),
                 )
                 if spec.method == "chebyshev":

@@ -122,8 +122,8 @@ def main(argv: list[str] | None = None) -> None:
     ap.add_argument("--delta-budget", type=float, default=0.1,
                     help="drift gate for --incremental-chain: sketched "
                          "||dS||_F / ||S||_F (measured against the last full "
-                         "rebuild, so corrections never compound) above which "
-                         "the transition triggers a full rebuild")
+                         "rebuild) above which the transition triggers a full "
+                         "rebuild")
     ap.add_argument("--solver-tol", type=float, default=None,
                     help="stop the solve when the relative preconditioned "
                          "residual drops below this (default: fixed q "
@@ -276,6 +276,7 @@ def main(argv: list[str] | None = None) -> None:
             f"{int(REGISTRY.value('chain.full_rebuilds'))} full rebuilds, "
             f"{int(REGISTRY.value('chain.incremental_updates'))} incremental "
             f"updates, {int(REGISTRY.value('chain.drift_fallbacks'))} drift "
+            f"and {int(REGISTRY.value('chain.solve_fallbacks'))} solve "
             f"fallbacks (rank={args.delta_rank}, budget={args.delta_budget}, "
             f"last drift={REGISTRY.gauge('chain.drift_last'):.2e}); "
             f"delta GEMM {REGISTRY.value('chain.delta_gemm_flops') / 1e9:.3f} "

@@ -58,6 +58,7 @@ __all__ = [
 # The program's span vocabulary: one prefix per layer.
 PREFIXES = (
     "sequence.", "phase.", "pipeline.", "query.", "solver.", "tiles.", "oochain.",
+    "delta.",
 )
 
 

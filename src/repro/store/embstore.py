@@ -12,9 +12,12 @@ mid-publish leaves the previous embedding current, never a torn one.
 The store reuses the :class:`~repro.store.tilestore.TileStore` durability
 idioms exactly:
 
-* every panel is written to a temp file and ``os.replace``d into place
-  (atomic on POSIX); ``aux`` (vol / deg / zbar) likewise;
-* an embedding id joins the manifest only once all its panels and the aux
+* an artifact's ``Z`` is one file of row panels, written to a temp file
+  and ``os.replace``d into place (atomic on POSIX); ``aux`` (vol / deg /
+  zbar) likewise.  One file, not one per panel: a publish's file-system
+  calls do not grow with the panel count, and readers still map one panel
+  at a time;
+* an embedding id joins the manifest only once its ``Z`` file and the aux
   sidecar exist (commit-on-complete; re-opening after a crash sees only
   complete embeddings);
 * the manifest is fingerprinted on (seed, k, codec, geometry) plus a
@@ -59,7 +62,8 @@ import numpy as np
 
 from repro.store.tilestore import MANIFEST_NAME, resolve_codec
 
-_FORMAT_VERSION = 1
+# v2: one Z file per artifact (v1 kept one file per panel).
+_FORMAT_VERSION = 2
 _AUX_NAME = "aux.npz"
 
 # Codecs with a device-decodable stored form only: the query kernel ships
@@ -150,8 +154,15 @@ class EmbManifest:
                 f"manifest kind {d.get('kind')!r} is not an embedding store "
                 "(a TileStore directory cannot be opened as an EmbeddingStore)"
             )
-        if d.get("version", 0) > _FORMAT_VERSION:
-            raise ValueError(f"embstore format v{d['version']} is newer than this reader")
+        version = d.get("version", 0)
+        if version > _FORMAT_VERSION:
+            raise ValueError(f"embstore format v{version} is newer than this reader")
+        if version < _FORMAT_VERSION:
+            raise ValueError(
+                f"embstore format v{version} keeps a file per Z panel; this reader "
+                f"reads v{_FORMAT_VERSION} (one Z file per artifact): publish into "
+                "a fresh directory"
+            )
         return cls(
             n=int(d["n"]),
             k=int(d["k"]),
@@ -223,7 +234,7 @@ class EmbeddingStore:
             )
         self.manifest = manifest
         self.root = Path(root) if root is not None else None
-        self._ram_panels: dict[tuple[str, int], np.ndarray] = {}
+        self._ram_z: dict[str, np.ndarray] = {}
         self._ram_aux: dict[str, dict[str, np.ndarray]] = {}
         self._resident: ResidentArtifact | None = None
         self._resident_epoch = 0  # bumped by every put or remove
@@ -341,28 +352,31 @@ class EmbeddingStore:
 
     # -- panel I/O -----------------------------------------------------------
 
-    def _panel_path(self, emb_id: str, p: int) -> Path:
+    def _z_path(self, emb_id: str) -> Path:
         assert self.root is not None
-        return self.root / emb_id / f"z_{p:04d}{self.codec.suffix}"
+        return self.root / emb_id / f"z{self.codec.suffix}"
 
     def _aux_path(self, emb_id: str) -> Path:
         assert self.root is not None
         return self.root / emb_id / _AUX_NAME
 
-    def has_panel(self, emb_id: str, p: int) -> bool:
+    def has_z(self, emb_id: str) -> bool:
         if self.root is None:
-            return (emb_id, p) in self._ram_panels
-        return self._panel_path(emb_id, p).exists()
+            return emb_id in self._ram_z
+        return self._z_path(emb_id).exists()
 
     def has_aux(self, emb_id: str) -> bool:
         if self.root is None:
             return emb_id in self._ram_aux
         return self._aux_path(emb_id).exists()
 
-    def _load_stored(self, emb_id: str, p: int, *, mmap: bool = True) -> np.ndarray:
+    def _load_stored(self, emb_id: str, p: int) -> np.ndarray:
         if self.root is None:
-            return self._ram_panels[(emb_id, p)]
-        return np.load(self._panel_path(emb_id, p), mmap_mode="r" if mmap else None)
+            z = self._ram_z[emb_id]
+        else:
+            z = np.load(self._z_path(emb_id), mmap_mode="r")
+        pr = self.panel_rows
+        return z[p * pr : (p + 1) * pr]
 
     def read_panel_stored(self, emb_id: str, p: int) -> np.ndarray:
         """One (panel_rows, k) panel in its *stored* form (raw fp32 or uint16
@@ -384,9 +398,9 @@ class EmbeddingStore:
         return np.asarray(arr).reshape(self.panel_rows, self.k)
 
     def panel_nbytes_stored(self, emb_id: str, p: int) -> int:
-        if self.root is None:
-            return self.codec.stored_nbytes(self._ram_panels[(emb_id, p)])
-        return self._panel_path(emb_id, p).stat().st_size
+        """Bytes one stored panel occupies (its slice of the Z file)."""
+        itemsize = 2 if self.codec.name == "bf16" else self.dtype.itemsize
+        return self.panel_rows * self.k * itemsize
 
     def read_aux(self, emb_id: str) -> dict[str, np.ndarray]:
         """``{vol: (), deg: (n,), zbar: (k,)}`` -- the small fp32/fp64 sidecar."""
@@ -411,9 +425,9 @@ class EmbeddingStore:
         here, so the reader never aliases live solver buffers), ``vol`` the
         scalar graph volume, ``deg`` the (n,) degree vector.  ``zbar`` (the
         column mean of Z, which the centroid-anomaly query needs) defaults to
-        being computed here.  Panels already on disk are skipped (resume);
-        the id joins the manifest only once every panel and the aux sidecar
-        exist.
+        being computed here.  A Z file already on disk is kept (resume after
+        a publish torn before its aux); the id joins the manifest only once
+        the Z file and the aux sidecar exist.
         """
         if "/" in emb_id or emb_id in ("", ".", ".."):
             raise ValueError(f"bad embedding id {emb_id!r}")
@@ -435,24 +449,20 @@ class EmbeddingStore:
             "deg": deg,
             "zbar": zbar,
         }
-        pr = self.panel_rows
         try:
-            for p in range(self.manifest.panels):
-                if self.has_panel(emb_id, p):
-                    continue  # resume after a partial publish
-                stored = self.codec.encode(z[p * pr : (p + 1) * pr])
-                self._store_panel(emb_id, p, np.asarray(stored))
+            if not self.has_z(emb_id):  # else resume after a partial publish
+                self._store_z(emb_id, np.asarray(self.codec.encode(z)))
             self._store_aux(emb_id, aux)
             self._commit(emb_id)
         finally:
             self._drop_resident()  # latest() now serves another artifact
         return self.embedding(emb_id)
 
-    def _store_panel(self, emb_id: str, p: int, stored: np.ndarray) -> None:
+    def _store_z(self, emb_id: str, stored: np.ndarray) -> None:
         if self.root is None:
-            self._ram_panels[(emb_id, p)] = np.array(stored, copy=True)
+            self._ram_z[emb_id] = np.array(stored, copy=True)
             return
-        path = self._panel_path(emb_id, p)
+        path = self._z_path(emb_id)
         path.parent.mkdir(parents=True, exist_ok=True)
         tmp = path.with_name(path.name + ".tmp")
         with open(tmp, "wb") as f:
@@ -471,13 +481,11 @@ class EmbeddingStore:
         os.replace(tmp, path)
 
     def _commit(self, emb_id: str) -> None:
-        missing = [
-            p for p in range(self.manifest.panels) if not self.has_panel(emb_id, p)
-        ]
-        if missing or not self.has_aux(emb_id):
+        has_z, has_aux = self.has_z(emb_id), self.has_aux(emb_id)
+        if not (has_z and has_aux):
             raise ValueError(
                 f"embedding {emb_id!r} incomplete: "
-                f"{len(missing)} panels missing, aux={'ok' if self.has_aux(emb_id) else 'missing'}"
+                f"z={'ok' if has_z else 'missing'}, aux={'ok' if has_aux else 'missing'}"
             )
         self._refresh_manifest()
         if emb_id not in self.manifest.embeddings:
@@ -495,8 +503,7 @@ class EmbeddingStore:
             self._write_manifest()
         self._drop_resident(emb_id)
         if self.root is None:
-            for key in [k for k in self._ram_panels if k[0] == emb_id]:
-                del self._ram_panels[key]
+            self._ram_z.pop(emb_id, None)
             self._ram_aux.pop(emb_id, None)
         else:
             emb_dir = self.root / emb_id

@@ -167,3 +167,16 @@ def test_cad_symmetric_inputs_score_zero(ctx1):
     cfg = CommuteConfig(eps_rp=1e-2, d=5, q=6, schedule="xla")
     res = detect_anomalies(ctx1, seq.a1, seq.a1, cfg, top_k=5)
     assert float(jnp.max(jnp.abs(res.scores))) < 1e-3
+
+
+def test_constrain_returns_a_placed_array_as_it_is(ctx1, ctx22):
+    """Eagerly, an array already laid out as asked comes back as it is;
+    another is laid out anew; under jit the constraint is traced."""
+    x = ctx1.put_rowblock(np.ones((8, 2), np.float32))
+    assert ctx1.constrain(x, ctx1.rowblock_spec) is x
+    want = ctx22.sharding(ctx22.rowblock_spec)
+    y = ctx22.constrain(jnp.ones((8, 2), jnp.float32), ctx22.rowblock_spec)
+    assert y.sharding.is_equivalent_to(want, 2)
+    z = jax.jit(lambda v: ctx22.constrain(2.0 * v, ctx22.rowblock_spec))(y)
+    assert z.sharding.is_equivalent_to(want, 2)
+    np.testing.assert_array_equal(np.asarray(z), 2.0)

@@ -204,8 +204,14 @@ def test_shared_base_scratch_lifecycle_oocore(ctx1):
     base = build_base_chain(ctx1, snaps[0], cfg)
     store = base.op.p1.store
     live = set(store.snapshot_ids)
-    # p1 + p2 + d retained T levels + (d-2) retained P levels
-    assert len(live) == 2 + cfg.d + (cfg.d - 2)
+    # p1 + p2 + d retained T levels (no P level: P_l is a product of T's)
+    assert len(live) == 2 + cfg.d
+
+    p2_id = base.op.p2.snap_id
+    base.solved(1e-6)  # the base's own solve is done: its P2 goes
+    assert base.op.p2 is None
+    live.discard(p2_id)
+    assert set(store.snapshot_ids) == live and len(live) == 1 + cfg.d
 
     corrected = try_delta_update(ctx1, base, snaps[1], cfg)
     assert corrected is not None and corrected.shared_base
@@ -235,9 +241,70 @@ def test_truncate_factors_is_optimal_rank_r():
     for r in (2, 4, 6):
         ut, vt = truncate_factors(u, v, r)
         assert ut.shape == (40, r) and vt.shape == (40, r)
-        err = np.linalg.norm(prod - ut.astype(np.float64) @ vt.astype(np.float64).T)
+        err = np.linalg.norm(prod - np.asarray(ut, np.float64) @ np.asarray(vt, np.float64).T)
         opt = np.linalg.norm(s[r:])
         np.testing.assert_allclose(err, opt, rtol=1e-4, atol=1e-4)
+
+
+def test_jitted_propagate_matches_the_passes(ctx1):
+    """Resident levels propagate as one compiled program: the same dP
+    factors' product as the pass-by-pass path, and the same ledger."""
+    from repro.core.delta_chain import _GemmLedger, _Passes, _propagate, _propagate_passes
+
+    rng = np.random.default_rng(3)
+    n, r, d = 64, 4, 4
+    s = rng.normal(size=(n, n)).astype(np.float32)
+    t0 = ctx1.put_matrix(0.05 * (s + s.T))
+    t_lv = [t0]
+    for _ in range(d - 1):
+        t_lv.append(t_lv[-1] @ t_lv[-1])
+    u, v = rng.normal(size=(n, r)).astype(np.float32), rng.normal(size=(n, r)).astype(np.float32)
+    dt0 = truncate_factors(ctx1.put_rowblock(1e-2 * u), ctx1.put_rowblock(1e-2 * v), r)
+    got, want = _GemmLedger(), _GemmLedger()
+    for _ in range(2):  # the second call reuses the compiled program and its ledger
+        e_j, f_j = _propagate(_Passes(ctx1, None, got), t_lv, dt0, r)
+    e_p, f_p = _propagate_passes(_Passes(ctx1, None, want), t_lv, dt0, r)
+    prod_j = np.asarray(e_j, np.float64) @ np.asarray(f_j, np.float64).T
+    prod_p = np.asarray(e_p, np.float64) @ np.asarray(f_p, np.float64).T
+    np.testing.assert_allclose(prod_j, prod_p, rtol=1e-4, atol=1e-4 * np.abs(prod_p).max())
+    assert (got.flops, got.bytes, got.scratch) == (2 * want.flops, 2 * want.bytes, 2 * want.scratch)
+
+
+def test_drift_monitor_reads_the_frobenius_ratio(ctx1):
+    """At a grid of 48x48 the monitor sketches 128 columns, and its drift
+    reads the exact ``||S~' - S~||_F / ||S~||_F`` within 5% (six columns
+    were 6-14% off on the same snapshots)."""
+    from bench import traffic as tf
+    from repro.core.delta_chain import DRIFT_SKETCH_COLS
+    from repro.obs import REGISTRY
+
+    grid = dict(n_lat=48, n_lon=48, n=2304)
+    assert min(DRIFT_SKETCH_COLS, grid["n"] // 16) == 128
+    cfg = CommuteConfig(eps_rp=1e-3, d=2, q=4, schedule="xla", seed=11, incremental_chain=True)
+
+    def s_tilde(a, deflate):
+        a = np.asarray(a, np.float64)
+        deg = a.sum(axis=1)
+        inv = 1.0 / np.sqrt(deg)
+        s = inv[:, None] * a * inv[None, :]
+        if deflate:
+            u = np.sqrt(deg / deg.sum())
+            s -= np.outer(u, u)
+        return s
+
+    for seed in (7, 8):
+        snaps = tf.snapshots(_CLIMATE_TRAFFIC, grid, seed)
+        a0 = snaps.adjacency(ctx1, 0)
+        base = build_base_chain(ctx1, a0, cfg)
+        s0 = s_tilde(a0, base.deflate)
+        for t in (6, 12):
+            a1 = snaps.adjacency(ctx1, t)
+            assert try_delta_update(ctx1, base, a1, cfg) is not None
+            drift = REGISTRY.gauge("chain.drift_last")
+            exact = np.linalg.norm(s_tilde(a1, base.deflate) - s0) / np.linalg.norm(s0)
+            assert 0.01 < exact < cfg.delta_budget
+            assert abs(drift / exact - 1.0) < 0.05, (seed, t, drift, exact)
+        base.release()
 
 
 # ---------------------------------------------------------------------------
@@ -271,3 +338,160 @@ def test_incremental_sweep_rank_storage_mesh(ctx, rank, storage):
         )
     assert sum(_counter(m, "incremental_updates") for m in inc.transition_metrics) == t_steps - 1
     assert sum(_counter(m, "drift_fallbacks") for m in inc.transition_metrics) == 0
+
+
+# ---------------------------------------------------------------------------
+# the slowly drifting climate deployment against the plain references
+# ---------------------------------------------------------------------------
+
+# The benchmark's climate-drift traffic (drift 0.01, no event) on a grid the
+# CPU holds, with the chain cut to d=3.
+_CLIMATE = dict(n_lat=8, n_lon=8, n=64, eps_rp=1e-3, d=3, q=10)
+_CLIMATE_TRAFFIC = {
+    "kind": "climate_fields", "loop": "write", "channels": 12, "smooth_passes": 8,
+    "drift": 0.01, "sigma": 1.0,
+    "event": {"frac": 0.02, "strength": 0.0, "smooth_passes": 2, "period": 4, "phase": 1},
+}
+_CLIMATE_SEED = 7
+
+
+def _climate_scores(ctx, incremental: bool, k: int | None):
+    """Scores of five transitions through ``SequenceDetector``; with
+    ``incremental`` both flags are on and the drift budget is 0 for the push
+    of snapshot 3, which the drift monitor must then hand to a rebuild."""
+    from bench import reference
+    from bench import traffic as tf
+    from repro.core import SequenceDetector
+
+    snaps = tf.snapshots(_CLIMATE_TRAFFIC, _CLIMATE, _CLIMATE_SEED)
+    cfg = CommuteConfig(
+        eps_rp=_CLIMATE["eps_rp"], d=_CLIMATE["d"], q=_CLIMATE["q"], schedule="xla",
+        seed=reference.projection_seed(_CLIMATE_SEED), k_override=k,
+        warm_start=incremental, incremental_chain=incremental,
+    )
+    det = SequenceDetector(ctx, cfg, top_k=5)
+    scores = {}
+    for t in range(6):
+        det.cfg = replace(cfg, delta_budget=0.0) if incremental and t == 3 else cfg
+        res = det.push(snaps.adjacency(ctx, t))
+        if res is not None:
+            scores[t] = np.asarray(res.scores, np.float64)
+    return snaps, scores, det.finalize().transition_metrics
+
+
+def test_incremental_climate_matches_the_references(ctx1):
+    """The drifting climate deployment with ``warm_start`` and
+    ``incremental_chain`` on scores every transition -- deltas and the drift
+    fallback's rebuild alike -- as close to ``bench.reference`` (the exact
+    full chain in plain jax.numpy) and to the eigendecomposition oracle
+    ``exact_commute_distances`` as the full-rebuild path does on the same
+    data."""
+    from bench import check, reference
+    from repro.core.embedding import exact_commute_distances
+
+    def exact(snaps, t):
+        a1, a2 = (np.asarray(snaps.adjacency(ctx1, s), np.float64) for s in (t - 1, t))
+        c1, c2 = (np.asarray(exact_commute_distances(x)) for x in (a1, a2))
+        return (np.abs(a1 - a2) * np.abs(c1 - c2)).sum(1)
+
+    sharding = ctx1.sharding(ctx1.matrix_spec)
+    # 1e-3 of the top score against the reference (the full rebuild reads
+    # up to 4.9e-4 here: float32 rounding over score differences that shrink
+    # with the drift); 0.1 against the oracle, which has no random
+    # projection (k_RP 4096 leaves about 5e-2 of Johnson-Lindenstrauss error).
+    for k, tol, target in (
+        (None, 1e-3, lambda s, t: reference.transition_scores(s, t, _CLIMATE, sharding)),
+        (4096, 0.1, exact),
+    ):
+        snaps, full, _ = _climate_scores(ctx1, False, k)
+        _, inc, metrics = _climate_scores(ctx1, True, k)
+        for t in full:
+            want = target(snaps, t)
+            assert check.score_gap(full[t], want) <= tol, (k, t)
+            assert check.score_gap(inc[t], want) <= tol, (k, t)
+        modes = [(_counter(m, "incremental_updates"), _counter(m, "drift_fallbacks"))
+                 for m in metrics]
+        assert modes == [(1, 0), (1, 0), (0, 1), (1, 0), (1, 0)], modes
+
+
+def test_residual_short_of_the_base_falls_back_to_a_rebuild(ctx1, monkeypatch):
+    """A delta transition whose solve ends above the bar the base's own solve
+    set is not scored with that iterate: the engine rebuilds and re-solves
+    (``chain.solve_fallbacks``), so its scores are the full rebuild's."""
+    import repro.core.sequence as seq_mod
+
+    monkeypatch.setattr(seq_mod, "SOLVE_RESIDUAL_SLACK", 0.0)
+    _, full, _ = _climate_scores(ctx1, False, None)
+    _, inc, metrics = _climate_scores(ctx1, True, None)
+    for m in metrics:
+        assert _counter(m, "full_rebuilds") == 1
+        assert _counter(m, "incremental_updates") + _counter(m, "drift_fallbacks") == 1
+        assert _counter(m, "solve_fallbacks") == _counter(m, "incremental_updates")
+    for t in full:
+        np.testing.assert_allclose(inc[t], full[t], rtol=2e-3, atol=1e-3 * full[t].max())
+
+
+def _live_nn(n: int) -> int:
+    import jax
+
+    return sum(1 for x in jax.live_arrays() if tuple(x.shape) == (n, n))
+
+
+def test_live_square_arrays_through_delta_and_fallback(ctx1, monkeypatch):
+    """Peak of live (n, n) arrays, sampled after every call that makes one
+    (the chain's GEMMs, its adds and tile programs, S~ and L): a delta
+    transition holds the two snapshots, the base's d T levels and P1
+    (d + 3; the base's P2 goes once the base is solved); a drift fallback
+    frees the old base before it builds, and its build peaks at the two
+    snapshots, d T levels and three build arrays (d + 5).  n is unique to
+    this test, so nothing else counts."""
+    import repro.core.chain as chain_mod
+    from bench import traffic as tf
+    from repro.core import SequenceDetector
+
+    geom = dict(_CLIMATE, n_lat=8, n_lon=9, n=72)
+    n, d = geom["n"], geom["d"]
+    peak = {"now": 0}
+
+    def sampled(fn):
+        def call(*args, **kwargs):
+            out = fn(*args, **kwargs)
+            peak["now"] = max(peak["now"], _live_nn(n))
+            return out
+        return call
+
+    class _Sampled:
+        def __init__(self, mod, names):
+            self._mod, self._names = mod, names
+
+        def __getattr__(self, name):
+            fn = getattr(self._mod, name)
+            return sampled(fn) if name in self._names else fn
+
+    for name in ("matmul", "tile_map", "add_scaled_identity"):
+        monkeypatch.setattr(chain_mod, name, sampled(getattr(chain_mod, name)))
+    monkeypatch.setattr(chain_mod, "jnp", _Sampled(chain_mod.jnp, {"add"}))
+    monkeypatch.setattr(
+        chain_mod, "lap", _Sampled(chain_mod.lap, {"normalized_adjacency", "laplacian"})
+    )
+
+    snaps = tf.snapshots(_CLIMATE_TRAFFIC, geom, _CLIMATE_SEED)
+    cfg = CommuteConfig(
+        eps_rp=geom["eps_rp"], d=d, q=geom["q"], schedule="xla",
+        warm_start=True, incremental_chain=True,
+    )
+    det = SequenceDetector(ctx1, cfg, top_k=5)
+    peaks = []
+    for t in range(4):
+        det.cfg = replace(cfg, delta_budget=0.0) if t == 3 else cfg
+        a = snaps.adjacency(ctx1, t)
+        peak["now"] = _live_nn(n)
+        det.push(a)
+        del a
+        peaks.append(max(peak["now"], _live_nn(n)))
+    modes = [
+        "delta" if _counter(m, "incremental_updates") else "fallback"
+        for m in det.finalize().transition_metrics
+    ]
+    assert modes == ["delta", "delta", "fallback"], modes
+    assert peaks[1:] == [d + 3, d + 3, d + 5], peaks

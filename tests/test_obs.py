@@ -692,3 +692,47 @@ def test_validators_reject_malformed():
         validate_chrome_trace({"traceEvents": [
             {"name": "x", "ph": "X", "ts": 1.0, "pid": 1, "tid": 1}
         ]})
+
+
+def test_delta_spans_nest_under_the_chain_phase(ctx1, tmp_path):
+    """A traced incremental push leaves ``delta.update`` inside
+    ``phase.chain`` with ``delta.sketch``, ``delta.propagate`` and
+    ``delta.correct`` inside it, on one host thread line of the
+    ``.xplane.pb``; ``delta.update.seconds/.calls`` count only while tracing
+    is on; the source scan finds the names under the ``delta.`` prefix."""
+    import pathlib
+    import re
+
+    from repro.core import delta_chain
+
+    cfg = CommuteConfig(
+        k_override=4, q=3, d=3, schedule="xla", warm_start=True,
+        incremental_chain=True, delta_budget=10.0,
+    )
+    det = SequenceDetector(ctx1, cfg, top_k=5)
+    seq = gmm_snapshot_sequence(
+        ctx1, 32, 3, seed=0, noise=0.02, inject_steps=set(), drift_nodes=3
+    )
+    snaps = list(seq.snapshots())
+    det.push(snaps[0])  # the base build
+    m0 = REGISTRY.snapshot()
+    det.push(snaps[1])  # an untraced delta counts no span time
+    assert "delta.update.calls" not in REGISTRY.delta(m0)
+
+    m1 = REGISTRY.snapshot()
+    lines = _profiled_program_spans(tmp_path / "prof", lambda: det.push(snaps[2]))
+    counted = REGISTRY.delta(m1)
+    assert counted["delta.update.calls"] == 1.0 and counted["delta.update.seconds"] > 0
+    assert counted["chain.incremental_updates"] == 1.0
+    (line,) = [ln for ln in lines if any(e[0] == "delta.update" for e in ln)]
+    (chain,) = [e for e in line if e[0] == "phase.chain"]
+    (update,) = [e for e in line if e[0] == "delta.update"]
+    assert _inside(update, chain)
+    for name in ("delta.sketch", "delta.propagate", "delta.correct"):
+        (ev,) = [e for e in line if e[0] == name]
+        assert _inside(ev, update), name
+
+    call = re.compile(r'\b(?:span|timed)\(\s*"([^"]+)"')
+    names = set(call.findall(pathlib.Path(delta_chain.__file__).read_text()))
+    assert names == {"delta.update", "delta.sketch", "delta.propagate", "delta.correct"}
+    assert all(name.startswith(obs_trace.PREFIXES) for name in names)

@@ -88,12 +88,11 @@ def test_embstore_fingerprint_mismatch_rejected(tmp_path):
 
 
 def test_embstore_commit_on_complete(tmp_path):
-    """An artifact is served only once every panel AND the aux sidecar exist;
+    """An artifact is served only once its Z file AND the aux sidecar exist;
     a torn publish (missing aux) never reaches the manifest."""
     store = EmbeddingStore.create(tmp_path, n=64, k=8)
     z = np.zeros((64, 8), np.float32)
-    stored = store.codec.encode(z[: store.panel_rows])
-    store._store_panel("torn", 0, np.asarray(stored))  # crash before aux
+    store._store_z("torn", np.asarray(store.codec.encode(z)))  # crash before aux
     with pytest.raises(ValueError, match="incomplete"):
         store._commit("torn")
     assert "torn" not in store.embedding_ids
@@ -102,6 +101,32 @@ def test_embstore_commit_on_complete(tmp_path):
     # resume: put_embedding completes the torn publish in place
     h = store.put_embedding("torn", z, 1.0, np.ones(64))
     assert h.emb_id in store.embedding_ids
+
+
+@pytest.mark.parametrize("codec", ["raw", "bf16"])
+def test_embstore_artifact_is_one_z_file(tmp_path, codec):
+    """A publish writes one Z file and one aux sidecar, whatever the panel
+    count, and every panel reads back from its slice of that file."""
+    rng = np.random.default_rng(0)
+    store = EmbeddingStore.create(tmp_path, n=128, k=8, codec=codec, panel_rows=16)
+    z = rng.normal(size=(128, 8)).astype(np.float32)
+    store.put_embedding("t0000", z, 2.0, np.ones(128))
+    assert sorted(f.name for f in (tmp_path / "t0000").iterdir()) == ["aux.npz", "z.npy"]
+    want = store.codec.decode(store.codec.encode(z), 128, np.dtype(np.float32))
+    itemsize = 2 if codec == "bf16" else 4
+    for p in range(store.manifest.panels):
+        np.testing.assert_array_equal(store.read_panel("t0000", p), want[16 * p : 16 * (p + 1)])
+        assert store.panel_nbytes_stored("t0000", p) == 16 * 8 * itemsize
+
+
+def test_embstore_rejects_panel_file_layout(tmp_path):
+    """A directory of the older layout (a file per Z panel, format v1) is
+    refused, never read as empty or torn."""
+    EmbeddingStore.create(tmp_path, n=64, k=8)
+    path = tmp_path / "manifest.json"
+    path.write_text(path.read_text().replace('"version": 2', '"version": 1'))
+    with pytest.raises(ValueError, match="fresh directory"):
+        EmbeddingStore.open(tmp_path)
 
 
 def test_embstore_rejects_tilestore_dir(tmp_path):

@@ -274,10 +274,9 @@ def test_oocore_chain_residency_bounded_by_panels(ctx1):
 
 
 def test_oocore_chain_sequence_retires_scratch(ctx1, tmp_path):
-    """Outgoing operators' scratch snapshots are retired as the two-snapshot
-    window advances -- with or without donate -- so a disk scratch stays
-    bounded by the window, not the sequence length.  The user's input store
-    is never touched."""
+    """Each operator's scratch snapshots are retired once its solve is done
+    -- with or without donate -- so a disk scratch holds at most the push in
+    flight, not the sequence.  The user's input store is never touched."""
     n = 32
     scratch = tmp_path / "scratch"
     cfg_oo = CommuteConfig(
@@ -291,8 +290,9 @@ def test_oocore_chain_sequence_retires_scratch(ctx1, tmp_path):
     res = det.run(store.iter_snapshots())
     assert len(res.transitions) == 3
     assert store.snapshot_ids == ["t0", "t1", "t2", "t3"]  # user data untouched
-    # only the still-live window's operator (last snapshot: P1 + P2) remains
-    assert len(TileStore.open(scratch).snapshot_ids) == 2
+    # each operator's P1 + P2 are retired once its solve is done (scoring
+    # reads only z and vol), so nothing of the window remains
+    assert len(TileStore.open(scratch).snapshot_ids) == 0
 
 
 # ---------------------------------------------------------------------------
