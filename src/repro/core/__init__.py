@@ -8,6 +8,7 @@ from repro.core.chain import (
     ChainOperator,
     chain_build_count,
     chain_product,
+    factored_chain,
     reset_chain_build_count,
 )
 from repro.core.delta_chain import (
@@ -84,6 +85,7 @@ __all__ = [
     "build_from_nodes",
     "chain_build_count",
     "chain_product",
+    "factored_chain",
     "commute_distance_block",
     "commute_time_embedding",
     "detect_anomalies",

@@ -13,7 +13,7 @@ gather/scatter on the MXU for dense graphs.
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 
 import jax
 import jax.numpy as jnp
@@ -119,6 +119,15 @@ class CADResult:
     solve_reports: tuple = ()
 
 
+def _solved(ctx, a, cfg, use_kernel) -> Embedding:
+    """``a``'s embedding, its operator retired once solved: scoring reads only
+    ``z`` and ``vol``, so the first snapshot's operator (P1 / P2, out-of-core
+    scratch or T levels) is gone before the second's chain is built."""
+    emb = commute_time_embedding(ctx, a, cfg, use_kernel=use_kernel)
+    emb.op.release_scratch()
+    return replace(emb, op=None)
+
+
 def detect_anomalies(
     ctx: DistContext,
     a1: jax.Array,
@@ -130,17 +139,11 @@ def detect_anomalies(
 ) -> CADResult:
     """End-to-end CADDeLaG (Algorithm 4) for one graph transition."""
     cfg = cfg or CommuteConfig()
-    e1 = commute_time_embedding(ctx, a1, cfg, use_kernel=use_kernel)
-    e2 = commute_time_embedding(ctx, a2, cfg, use_kernel=use_kernel)
+    e1, e2 = (_solved(ctx, a, cfg, use_kernel) for a in (a1, a2))
     scores = node_anomaly_scores(
         ctx, a1, a2, e1, e2, use_kernel=use_kernel, prefetch_depth=cfg.prefetch_depth
     )
     idx, vals = top_anomalies(scores, top_k)
-    # The operators die with this call: retire any out-of-core scratch they
-    # hold, so a pairwise loop over a disk scratch dir stays bounded.
-    for e in (e1, e2):
-        if e.op is not None:
-            e.op.release_scratch()
     return CADResult(
         scores=scores, top_idx=idx, top_val=vals,
         solve_reports=(e1.report, e2.report),

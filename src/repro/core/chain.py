@@ -8,11 +8,29 @@ Erratum vs the paper: Alg. 2 line 8 writes P1 = D^{-1/2} P; the right
 inverse needs the symmetric sandwich D^{-1/2} P D^{-1/2} (their EstimateSolution
 only converges with the latter).  We implement the correct sandwich.
 
-Cost: exactly 2(d-1) + 1 dense n x n GEMMs (T <- T@T and P <- P@T + P per
-level, one more for P2 = Z^ @ L) -- the paper's hot spot, distributed with the
-schedule chosen in :mod:`repro.core.distmatrix`.  ``fuse_l=True`` instead forms
-P2 = Z^ D - Z^ A via a column scale plus one GEMM on the *original* adjacency,
-saving the materialization of L (a beyond-paper memory optimization).
+Two forms of the same operator:
+
+* **materialized** (:func:`chain_product`): 2(d-1) + 1 dense n x n GEMMs
+  (T <- T@T and P <- P@T + P per level, one more for P2 = Z^ @ L) -- the
+  paper's hot spot, distributed with the schedule chosen in
+  :mod:`repro.core.distmatrix`.  ``fuse_l=True`` instead forms
+  P2 = Z^ D - Z^ A via a column scale plus one GEMM on the *original*
+  adjacency, saving the materialization of L (a beyond-paper memory
+  optimization).  Every solve step is then one skinny pass over P2.
+* **factored** (:func:`factored_chain`): only the d - 1 squarings.  The
+  operator keeps T_0 .. T_{d-1} and the snapshot's adjacency, and a solve
+  applies ``P1 x = D^{-1/2} (I + T_{d-1}) ... (I + T_0) D^{-1/2} x``
+  (d skinny passes; the T_j commute, all being powers of S~) and
+  ``P2 y = P1 (deg y - A y)`` (one pass more).  The same operator, reordered
+  from ``(P L) y`` to ``P (L y)``: d GEMMs fewer, d skinny passes more a
+  solve step.
+
+:func:`use_factored` picks the form from what it can observe: the factored
+one where the operator serves one solve whose passes cost less than the
+GEMMs they replace (``max_steps + 1 < n / PASS_COST_DIVISOR``), the build and
+its adjacency are resident, and d + 4 n x n shards fit the device; the
+materialized one otherwise (a tolerance-driven solve's 300-step cap, an
+out-of-core or streamed graph, an operator built to serve many solves).
 """
 
 from __future__ import annotations
@@ -29,7 +47,13 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.core import laplacian as lap
-from repro.core.distmatrix import F32_PRECISION, DistContext, add_scaled_identity, matmul
+from repro.core.distmatrix import (
+    F32_PRECISION,
+    DistContext,
+    add_scaled_identity,
+    matmul,
+    matmul_rowblock,
+)
 from repro.core.tiles import is_streamable, sharded_zeros, stream_stats, tile_map
 from repro.obs.metrics import REGISTRY as _OBS_REGISTRY
 
@@ -94,26 +118,35 @@ class ChainOperator:
     # the single owner of that scratch (prevents a corrected operator's
     # retirement from freeing panels the base or its siblings still stream).
     shared_base: bool = False
+    # The factored form (factored_chain): T_0 .. T_{d-1}, resident, with p1 /
+    # p2 None; the solve applies P1 and P2 = P1 (diag(deg) - adj) from them.
+    t_levels: tuple | None = None
+
+    @property
+    def factored(self) -> bool:
+        return self.t_levels is not None
 
     def tree_flatten(self):
         return (
             self.p1, self.p2, self.deg, self.vol,
-            self.p1_scale, self.u1, self.v1, self.adj,
+            self.p1_scale, self.u1, self.v1, self.adj, self.t_levels,
         ), (self.prefetch_depth, self.rho, self.use_gemm_kernel, self.shared_base)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
         return cls(
-            *children,
+            *children[:-1], t_levels=children[-1],
             prefetch_depth=aux[0], rho=aux[1], use_gemm_kernel=aux[2],
             shared_base=aux[3],
         )
 
     def release_scratch(self) -> None:
-        """Retire store-backed P1 / P2 from their scratch store (no-op for
-        resident operators).  Call once the operator will not be used again;
-        every consumer that builds oocore operators internally
-        (``detect_anomalies``, ``SequenceDetector``) does this itself.
+        """Retire store-backed P1 / P2 from their scratch store, and free a
+        factored operator's T levels (no-op for a resident materialized
+        operator, which its last reference frees).  Call once the operator
+        will not be used again; every consumer that builds operators
+        internally (``detect_anomalies``, ``SequenceDetector``) does this
+        itself.
 
         A failed removal (a wedged scratch dir, a concurrently-removed
         snapshot) is *warned*, never raised: scoring already succeeded and
@@ -126,6 +159,9 @@ class ChainOperator:
         """
         if self.shared_base:
             return
+        levels, self.t_levels = self.t_levels or (), None
+        for t in levels:
+            t.delete()  # built for this operator alone
         for buf in (self.p1, self.p2):
             store = getattr(buf, "store", None)
             if store is not None and hasattr(buf, "snap_id"):
@@ -176,6 +212,21 @@ def _matmul_panels_from_store(
     return ctx.constrain(acc.astype(out_dtype), ctx.matrix_spec)
 
 
+def _inv_sqrt(deg: jax.Array) -> jax.Array:
+    """D^{-1/2}, zero where a node has no edges."""
+    return jnp.where(deg > 0, jax.lax.rsqrt(jnp.maximum(deg, 1e-30)), 0.0)
+
+
+def _chain_start(ctx: DistContext, a, deflate: bool, dtype, prefetch_depth):
+    """``(deg, vol, T_0)``: the degrees, the volume and S~, both forms' start."""
+    deg = lap.degrees(ctx, a, prefetch_depth=prefetch_depth)
+    vol = lap.volume(ctx, deg)
+    t0 = lap.normalized_adjacency(
+        ctx, a, deg, deflate=deflate, dtype=dtype, prefetch_depth=prefetch_depth
+    )
+    return deg, vol, t0
+
+
 def _resident_chain(
     ctx: DistContext,
     a,
@@ -193,12 +244,7 @@ def _resident_chain(
     the chain, and nothing that syncs with the host (so it also traces as
     one program under ``jax.jit``)."""
     mm = partial(matmul, ctx, schedule=schedule, out_dtype=dtype, use_kernel=use_kernel)
-
-    deg = lap.degrees(ctx, a, prefetch_depth=prefetch_depth)
-    vol = lap.volume(ctx, deg)
-    t = lap.normalized_adjacency(
-        ctx, a, deg, deflate=deflate, dtype=dtype, prefetch_depth=prefetch_depth
-    )  # T_0 = S
+    deg, vol, t = _chain_start(ctx, a, deflate, dtype, prefetch_depth)
     p = add_scaled_identity(ctx, t, 1.0)  # I + S
     # Only a level_sink keeps the T levels alive (the delta path applies
     # every P level as a product of (I + T) factors): a plain build holds
@@ -213,7 +259,7 @@ def _resident_chain(
     if keep:
         level_sink["t"] = t_levels
 
-    inv_sqrt = jnp.where(deg > 0, jax.lax.rsqrt(jnp.maximum(deg, 1e-30)), 0.0)
+    inv_sqrt = _inv_sqrt(deg)
     p1 = tile_map(
         ctx,
         lap._sym_scale_body,
@@ -258,8 +304,9 @@ def chain_product(
     use_gemm_kernel: bool = False,
     level_sink: dict | None = None,
 ) -> ChainOperator:
-    """Build the chain operator from ``a``: a resident sharded adjacency or a
-    store-backed snapshot handle.
+    """Build the materialized chain operator from ``a``: a resident sharded
+    adjacency or a store-backed snapshot handle.  (:func:`factored_chain`
+    builds the factored form, for a resident ``a`` and one solve.)
 
     ``level_sink`` (a caller-provided dict) opts into retaining the chain's
     squaring levels for incremental delta updates
@@ -344,3 +391,107 @@ def chain_product(
 
     rho = estimate_rho(ctx, p2, prefetch_depth=prefetch_depth)
     return ChainOperator(p1=p1, p2=p2, deg=deg, vol=vol, rho=rho)
+
+
+# ---------------------------------------------------------------------------
+# the factored form: the squarings only, applied as factors by the solve
+# ---------------------------------------------------------------------------
+
+# One n x n float32 GEMM at HIGHEST costs about n / PASS_COST_DIVISOR skinny
+# passes (one read of an n x n matrix against the solve's (n, k_RP) block).
+# On one v5e at n=16384 a chain GEMM took 0.305 s and a solve pass 1.9 ms, a
+# ratio of 160, n / 102 (ledger, the climate write cell: chain_s 3.359 s over
+# 11 GEMMs, solve_s 0.0188 s over 10 passes); 128 leaves that margin.
+PASS_COST_DIVISOR = 128
+
+# n x n shards a factored build holds at its peak: T_0 .. T_{d-1} plus this
+# many more (the snapshot's adjacency, the previous snapshot's that the
+# sequence keeps for scoring, and a squaring's operand copies).
+FACTORED_EXTRA_MATRICES = 4
+
+
+def _device_bytes_limit(ctx: DistContext) -> int | None:
+    """One device's memory in bytes, or None where the backend reports none."""
+    stats = ctx.mesh.devices.flat[0].memory_stats() or {}
+    limit = stats.get("bytes_limit")
+    return None if limit is None else int(limit)
+
+
+def use_factored(ctx: DistContext, a, d_len: int, solve_passes: int) -> bool:
+    """Whether a build that serves one solve of ``solve_passes`` operator
+    applications (``max_steps + 1``, the power iterations added for
+    Chebyshev) should take the factored form.
+
+    It replaces d GEMMs by d skinny passes a application, so it pays where
+    ``solve_passes < n / PASS_COST_DIVISOR``; it reads A on every step and
+    holds every T level, so A must be resident and d + 4 n x n float32
+    shards must fit one device (a backend that reports no memory limit, the
+    CPU's, is taken to fit).
+    """
+    if is_streamable(a):
+        return False
+    n = int(a.shape[0])
+    if solve_passes >= n / PASS_COST_DIVISOR:
+        return False
+    limit = _device_bytes_limit(ctx)
+    shard = 4.0 * n * n / (ctx.n_row_shards * ctx.n_col_shards)
+    return limit is None or (d_len + FACTORED_EXTRA_MATRICES) * shard <= limit
+
+
+def factored_chain(
+    ctx: DistContext,
+    a: jax.Array,
+    d_len: int,
+    *,
+    schedule: str = "cannon",
+    dtype=jnp.float32,
+    deflate: bool = True,
+    use_kernel: bool = False,
+) -> ChainOperator:
+    """The factored chain operator of a resident ``a``: d - 1 squarings, no
+    P, P1 or P2 (see the module docstring).  No rho is estimated: the solve
+    measures one through the operator's own mat-vec where Chebyshev asks."""
+    if d_len < 1:
+        raise ValueError("chain length d must be >= 1")
+    if is_streamable(a):
+        raise ValueError("the factored chain reads A every solve step: A must be resident")
+    n = float(a.shape[0])
+    _OBS_REGISTRY.add_named({
+        "chain.builds": 1.0,
+        "chain.gemm_flops": (d_len - 1) * 2.0 * n**3,
+        "chain.gemm_bytes": (d_len - 1) * 3.0 * n**2 * 4.0,
+        "chain.scratch_bytes": d_len * n**2 * 4.0,  # S~ and the d - 1 squares
+    })
+    mm = partial(matmul, ctx, schedule=schedule, out_dtype=dtype, use_kernel=use_kernel)
+    deg, vol, t = _chain_start(ctx, a, deflate, dtype, None)
+    levels = [t]
+    for _ in range(1, d_len):
+        levels.append(mm(levels[-1], levels[-1]))  # S~^{2^k}
+    return ChainOperator(p1=None, p2=None, deg=deg, vol=vol, adj=a, t_levels=tuple(levels))
+
+
+def apply_levels(mm, t_levels, x=None, heads=None):
+    """``(I + T_0) ... (I + T_{m-1}) x``: one skinny pass ``mm(T_j, block)``
+    per level, the last level first.  The T_j commute, so that is the
+    product in any order.
+
+    ``heads[j]``, where given, joins the block as its first columns just
+    before level j's pass, so each head comes out multiplied by the factors
+    of levels 0 .. j alone -- all of them from one pass a level
+    (the delta chain's ``P_{l-1} Ut_l``).
+    """
+    for j in reversed(range(len(t_levels))):
+        if heads is not None:
+            x = heads[j] if x is None else jnp.concatenate([heads[j], x], axis=1)
+        x = x + mm(t_levels[j], x)
+    return x
+
+
+def factored_p1(ctx: DistContext, t_levels, deg: jax.Array, x: jax.Array) -> jax.Array:
+    """``P1 x = D^{-1/2} (I + T_{d-1}) ... (I + T_0) D^{-1/2} x`` for an
+    (n, k) block, in float32: d skinny passes."""
+    s = _inv_sqrt(deg.astype(jnp.float32)).reshape(-1, 1)
+    px = apply_levels(
+        lambda t, y: matmul_rowblock(ctx, t, y), t_levels, s * x.astype(jnp.float32)
+    )
+    return s * px

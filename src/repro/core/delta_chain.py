@@ -67,7 +67,7 @@ import numpy as np
 
 from repro.core import laplacian as lap
 from repro.core import rng as crng
-from repro.core.chain import ChainOperator, chain_product
+from repro.core.chain import ChainOperator, apply_levels, chain_product
 from repro.core.distmatrix import F32_PRECISION, DistContext, matmul_rowblock
 from repro.core.tiles import is_streamable
 from repro.obs import timed
@@ -468,11 +468,7 @@ def _propagate_passes(ps: _Passes, t_lv: list, dt0, r: int):
         u_t, v_t = truncate_factors(u2r, v2r, r)
         dts.append((u_t, v_t))
 
-    block = None
-    for j in range(d_len - 2, -1, -1):
-        head = dts[j][0]
-        block = head if block is None else jnp.concatenate([head, block], axis=1)
-        block = block + ps.mm(t_lv[j], block)
+    block = apply_levels(ps.mm, t_lv[: d_len - 1], heads=[ut for ut, _ in dts])
     widths = np.cumsum([0] + [ut.shape[1] for ut, _ in dts])
 
     e_f, f_f = dt0  # dP_0 = dS (P_0 = I + T_0)

@@ -23,9 +23,10 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core import rng as crng
-from repro.core.chain import ChainOperator, chain_product
+from repro.core.chain import ChainOperator, chain_product, factored_chain, use_factored
 from repro.core.distmatrix import F32_PRECISION, DistContext
 from repro.core.solvers import SolveReport, SolverSpec, solve
+from repro.core.solvers.power import DEFAULT_POWER_ITERS
 from repro.core.tiles import is_streamable, tile_map, tile_stream
 from repro.obs import REGISTRY, phase
 
@@ -185,28 +186,53 @@ def commute_time_embedding(
     k): the solver starts from it instead of the cold ``y0 = chi`` start.
     Ignored (with a cold solve) when its shape does not match -- a sequence
     whose k_RP changed mid-stream should not crash the detector.
+
+    Without ``op`` the operator is built here and serves this one solve, so
+    its form follows :func:`repro.core.chain.use_factored`: the factored
+    chain (d - 1 GEMMs; the solve applies the T levels and A as factors)
+    where the solve's ``max_steps + 1`` applications (plus the power
+    iterations Chebyshev needs) number fewer than ``n / PASS_COST_DIVISOR``
+    (n / 128), the build is resident (``not cfg.oocore``, A on the device)
+    and d + 4 n x n shards fit; else the materialized chain (2d - 1 GEMMs),
+    as for a tolerance-driven solve's 300-step cap or an out-of-core graph.
+    Counted in ``chain.factored_builds`` (0 for a materialized build) and
+    named by the ``phase.chain`` span's ``form``.
     """
     n = a.shape[0]
     k = cfg.k_rp(n)
     if op is None:
-        with phase("chain", n=n, d=cfg.d, oocore=cfg.oocore) as sp:
-            op = chain_product(
-                ctx,
-                a,
-                cfg.d,
-                schedule=cfg.schedule,
-                dtype=cfg.dtype,
-                deflate=cfg.deflate,
-                fuse_l=cfg.fuse_l,
-                use_kernel=use_kernel,
-                oocore=cfg.oocore,
-                oocore_work=cfg.oocore_dir,
-                oocore_panel_rows=cfg.oocore_panel_rows,
-                tile_codec=cfg.tile_codec,
-                prefetch_depth=cfg.prefetch_depth,
-                use_gemm_kernel=cfg.use_gemm_kernel,
-            )
-            sp.fence(op.p2 if not is_streamable(op.p2) else op.vol)
+        spec = cfg.solver_spec()
+        passes = spec.max_steps(cfg.q) + 1
+        if spec.method == "chebyshev":
+            passes += DEFAULT_POWER_ITERS
+        factored = not cfg.oocore and use_factored(ctx, a, cfg.d, passes)
+        form = "factored" if factored else "materialized"
+        with phase("chain", n=n, d=cfg.d, oocore=cfg.oocore, form=form) as sp:
+            if factored:
+                op = factored_chain(
+                    ctx, a, cfg.d, schedule=cfg.schedule, dtype=cfg.dtype,
+                    deflate=cfg.deflate, use_kernel=use_kernel,
+                )
+                sp.fence(op.t_levels[-1])
+            else:
+                op = chain_product(
+                    ctx,
+                    a,
+                    cfg.d,
+                    schedule=cfg.schedule,
+                    dtype=cfg.dtype,
+                    deflate=cfg.deflate,
+                    fuse_l=cfg.fuse_l,
+                    use_kernel=use_kernel,
+                    oocore=cfg.oocore,
+                    oocore_work=cfg.oocore_dir,
+                    oocore_panel_rows=cfg.oocore_panel_rows,
+                    tile_codec=cfg.tile_codec,
+                    prefetch_depth=cfg.prefetch_depth,
+                    use_gemm_kernel=cfg.use_gemm_kernel,
+                )
+                sp.fence(op.p2 if not is_streamable(op.p2) else op.vol)
+        REGISTRY.add_named({"chain.factored_builds": 1.0 if factored else 0.0})
     with phase("ingest", n=n, k=k) as sp:
         y = edge_projection(
             ctx, a, cfg.seed, k, prefetch_depth=cfg.prefetch_depth

@@ -137,9 +137,11 @@ class SequenceDetector:
     def _retire_op(self, emb: Embedding) -> Embedding:
         """Drop an embedding's chain operator once its solve is done:
         scoring reads only ``z`` and ``vol``, and publish ``z``, ``vol`` and
-        the degrees, so holding P1 / P2 until
-        the snapshot leaves the window would keep two n x n buffers alive
-        through the next chain build.
+        the degrees, so holding P1 / P2 (or a factored operator's d T levels)
+        until the snapshot leaves the window would keep those n x n buffers
+        alive through the next chain build.  A factored operator's T levels
+        are deleted here (``release_scratch``); its adjacency is the
+        snapshot's own, which the window keeps for scoring.
 
         An out-of-core operator's P1 / P2 handles live in a scratch store
         owned by the build; those snapshots are removed here (without this,

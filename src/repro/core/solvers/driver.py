@@ -71,6 +71,7 @@ from jax import lax
 
 from jax.sharding import PartitionSpec as P
 
+from repro.core.chain import factored_p1
 from repro.core.distmatrix import F32_PRECISION, DistContext, matmul_rowblock
 from repro.core.solvers.base import SolveReport, SolverSpec
 from repro.obs import trace as obs_trace
@@ -196,13 +197,24 @@ def _corrected_matvec(ctx: DistContext, ops, y: jax.Array) -> jax.Array:
     return ctx.constrain(out.astype(y.dtype), ctx.rowblock_spec)
 
 
+def _factored_matvec(ctx: DistContext, ops, y: jax.Array) -> jax.Array:
+    """``P2 y = P1 (deg y - A y)`` of a factored operator, from its parts
+    ``ops = (t_levels, deg, adj)`` (:func:`repro.core.chain.factored_p1`):
+    d + 1 skinny passes, the adjacency's and one per T level."""
+    t_levels, deg, adj = ops
+    y32 = y.astype(jnp.float32)
+    ly = deg.astype(jnp.float32).reshape(-1, 1) * y32 - matmul_rowblock(ctx, adj, y32)
+    out = factored_p1(ctx, t_levels, deg, ly)
+    return ctx.constrain(out.astype(y.dtype), ctx.rowblock_spec)
+
+
 # ---------------------------------------------------------------------------
 # resident path: one cached while_loop program per (method, ctx, geometry)
 # ---------------------------------------------------------------------------
 
 
 def _resident_program(ctx: DistContext, method: str, deflate: bool, chi,
-                      corr_rank: int | None = None):
+                      corr_rank: int | None = None, n_levels: int | None = None):
     """The jitted adaptive loop.  Stopping operands (tol, max_steps, rho) and
     the warm-start iterate y0 are traced, so one compiled program serves
     every tolerance/cap/rho and both cold (y0 = chi) and warm starts.
@@ -212,14 +224,19 @@ def _resident_program(ctx: DistContext, method: str, deflate: bool, chi,
     ``P2' y = P1' (deg y - A' y)`` (:func:`_corrected_matvec`), operands of
     the same while_loop program, so a steady-state incremental sequence
     compiles the corrected program once per correction rank and every later
-    corrected push is a cache hit.  Uncorrected solves pass ``ops = (p2,)``
-    and keep the historical program (and its bitwise behaviour) untouched.
+    corrected push is a cache hit.  ``n_levels`` selects the factored
+    variant the same way: ``ops`` is ``(t_levels, deg, adj)`` and every
+    mat-vec is ``P1 (deg y - A y)`` (:func:`_factored_matvec`), compiled once
+    per chain length.  Materialized solves pass ``ops = (p2,)`` and keep the
+    historical program (and its bitwise behaviour) untouched.
     """
 
     def build():
         def matvec(ops, y):
             if corr_rank is not None:
                 return _corrected_matvec(ctx, ops, y)
+            if n_levels is not None:
+                return _factored_matvec(ctx, ops, y)
             # identical op sequence to matmul_rowblock's resident branch
             out = jnp.dot(
                 ops[0], y.astype(jnp.float32),
@@ -363,7 +380,7 @@ def _resident_program(ctx: DistContext, method: str, deflate: bool, chi,
 
     key = (
         "solve_driver", method, ctx, deflate, tuple(chi.shape),
-        np.dtype(chi.dtype).name, RES_HIST_CAP, corr_rank,
+        np.dtype(chi.dtype).name, RES_HIST_CAP, corr_rank, n_levels,
     )
     return cached_program(key, build)
 
@@ -658,8 +675,9 @@ def solve(
 
     ``op`` is any chain operator (duck-typed: ``p1``/``p2`` arrays or
     store-backed handles, optional ``prefetch_depth``/``rho``/``deg``
-    metadata).  ``fixed_q`` feeds the legacy fixed-iteration default: with no
-    tolerance, cap or delta on the spec, the driver runs exactly
+    metadata; a factored operator's ``t_levels``, ``deg`` and ``adj`` in
+    place of ``p1``/``p2``).  ``fixed_q`` feeds the legacy fixed-iteration
+    default: with no tolerance, cap or delta on the spec, the driver runs exactly
     ``fixed_q - 1`` refinement steps -- bit-compatible with the historical
     Richardson loop.  ``solver_batch``/``prefetch_depth`` are the streamed
     path's I/O knobs (ignored resident -- nothing streams); see
@@ -693,13 +711,34 @@ def solve(
     max_steps = spec.max_steps(fixed_q)
     tol = 0.0 if spec.tolerance is None else float(spec.tolerance)
 
+    # Incremental-chain correction (None on a plain base operator):
+    # p1_scale/u1/v1 turn the chi build into the corrected
+    # P1' b = s * (P1 (s * b)) + u1 (v1^T b), and every mat-vec of the
+    # iteration applies P2' = P1' (D' - A') from the snapshot's adjacency.
+    # A factored operator's T levels apply P1 and P2 = P1 (D - A) instead.
+    p1_scale = getattr(op, "p1_scale", None)
+    u1 = getattr(op, "u1", None)
+    v1 = getattr(op, "v1", None)
+    adj = getattr(op, "adj", None)
+    t_levels = getattr(op, "t_levels", None)
+    factored = None if t_levels is None else (tuple(t_levels), op.deg, adj)
+    corr = None if adj is None or factored is not None else (
+        op.p1, p1_scale, u1, v1, adj, op.deg
+    )
+    corr_rank = None if corr is None else int(u1.shape[1])
+
     rho = None
     if spec.method == "chebyshev":
         rho_raw = getattr(op, "rho", None)
         if rho_raw is None:
             from repro.core.solvers.power import estimate_rho
 
-            rho_raw = estimate_rho(ctx, op.p2, prefetch_depth=depth)
+            if factored is not None:
+                rho_raw = estimate_rho(
+                    ctx, lambda y: _factored_matvec(ctx, factored, y), n=int(b.shape[0])
+                )
+            else:
+                rho_raw = estimate_rho(ctx, op.p2, prefetch_depth=depth)
             if hasattr(op, "rho"):
                 op.rho = rho_raw  # cache: later solves on this operator reuse it
         # Start from the raw power-iteration estimate (it converges to rho
@@ -714,17 +753,6 @@ def solve(
             # No degree metadata on the operator: fall back to the Euclidean
             # inner product (exact only for uniform degrees).
             w = jnp.ones((int(b.shape[0]),), jnp.float32)
-
-    # Incremental-chain correction (None on a plain base operator):
-    # p1_scale/u1/v1 turn the chi build into the corrected
-    # P1' b = s * (P1 (s * b)) + u1 (v1^T b), and every mat-vec of the
-    # iteration applies P2' = P1' (D' - A') from the snapshot's adjacency.
-    p1_scale = getattr(op, "p1_scale", None)
-    u1 = getattr(op, "u1", None)
-    v1 = getattr(op, "v1", None)
-    adj = getattr(op, "adj", None)
-    corr = None if adj is None else (op.p1, p1_scale, u1, v1, adj, op.deg)
-    corr_rank = None if corr is None else int(u1.shape[1])
 
     streamed = any(is_streamable(m) for m in (op.p1, op.p2, adj))
     use_k = bool(
@@ -747,7 +775,12 @@ def solve(
                 (b.astype(jnp.float32) * scale_col).astype(b.dtype),
                 ctx.rowblock_spec,
             )
-        if streamed and use_k and is_streamable(op.p1):
+        if factored is not None:
+            chi = ctx.constrain(
+                factored_p1(ctx, factored[0], op.deg, b_in).astype(b.dtype),
+                ctx.rowblock_spec,
+            )
+        elif streamed and use_k and is_streamable(op.p1):
             chi = _kernel_stream_pass(
                 ctx, op.p1, b_in, None, depth=depth, fused=False
             )
@@ -788,8 +821,11 @@ def solve(
             if spec.method != "chebyshev":
                 rho_final = rho
         else:
-            prog = _resident_program(ctx, spec.method, deflate, chi, corr_rank)
-            ops = (op.p2,) if corr is None else corr
+            prog = _resident_program(
+                ctx, spec.method, deflate, chi, corr_rank,
+                None if factored is None else len(factored[0]),
+            )
+            ops = factored or corr or (op.p2,)
             if spec.method == "cg":
                 y, k_arr, res_arr, hist_arr = prog(
                     ops, chi, y_start, jnp.asarray(w),

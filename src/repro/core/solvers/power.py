@@ -13,8 +13,10 @@ the paper's worst-case ``q = ceil(log 1/delta)`` into a measured bound --
 von Luxburg et al. (arXiv:1003.1266) show the spectral regime, not the
 iteration count, is what governs commute-time estimate quality.  Estimating
 it costs a handful of ``G v`` mat-vecs against the already-built P2, so the
-chain build computes it once and caches it on the operator
-(:class:`repro.core.chain.ChainOperator.rho`).
+materialized chain build computes it once and caches it on the operator
+(:class:`repro.core.chain.ChainOperator.rho`).  A factored operator forms no
+P2: the solve driver measures its rho through the operator's own mat-vec,
+and only for Chebyshev, the one method that reads it.
 
 All ops here are eager (no tile-program bodies, no jitted closures), so the
 estimate adds zero entries to the program cache and zero body retraces.
@@ -38,6 +40,7 @@ def estimate_rho(
     iters: int = DEFAULT_POWER_ITERS,
     seed: int = 0,
     prefetch_depth: int | None = None,
+    n: int | None = None,
 ) -> float:
     """Spectral-radius estimate of ``G = I - P2`` on the 1-orthogonal subspace.
 
@@ -49,29 +52,36 @@ def estimate_rho(
     ``p2`` may be a store-backed handle (an out-of-core chain's P2): the
     mat-vecs then stream, and a :class:`repro.store.CachingHandle` wrap makes
     the whole estimate cost ONE real scratch pass -- the remaining iterations
-    replay decoded panels from host RAM.
+    replay decoded panels from host RAM.  It may also be a function
+    ``v -> P2 v`` (a factored operator's, which forms no P2); ``n`` then
+    gives the order.
     """
     if iters < 1:
         raise ValueError(f"power iters must be >= 1, got {iters}")
-    n = int(p2.shape[0])
+    if callable(p2):
+        matvec = p2
+    else:
+        n = int(p2.shape[0])
+        handle = p2
+        if is_streamable(p2):
+            from repro.store import CachingHandle  # deferred: optional oocore path
+
+            handle = CachingHandle(p2)
+
+        def matvec(x):
+            return matmul_rowblock(ctx, handle, x, prefetch_depth=prefetch_depth)
     rng = np.random.default_rng(seed)
     v0 = rng.normal(size=(n, 1)).astype(np.float32)
     v0 -= v0.mean(axis=0, keepdims=True)
     v0 /= max(float(np.linalg.norm(v0)), 1e-30)
     v = ctx.put_rowblock(v0)
 
-    handle = p2
-    if is_streamable(p2):
-        from repro.store import CachingHandle  # deferred: optional oocore path
-
-        handle = CachingHandle(p2)
-
     # All iterations stay on device (the norm is a device scalar); the single
     # host sync is the final float() below, so the estimate costs mat-vec
     # dispatches, not per-step round-trips.
     nrm = None
     for _ in range(iters):
-        gv = v - matmul_rowblock(ctx, handle, v, prefetch_depth=prefetch_depth)
+        gv = v - matvec(v)
         gv = gv - jnp.mean(gv.astype(jnp.float32), axis=0, keepdims=True)
         nrm = jnp.sqrt(jnp.sum(gv.astype(jnp.float32) ** 2))
         v = ctx.constrain(

@@ -209,3 +209,31 @@ def test_resident_chain_fits_each_chip(topo, grid, schedule):
         + m.temp_size_in_bytes - m.alias_size_in_bytes
     )
     assert used < V5E_HBM_BYTES, f"{used / 1e9:.2f} GB"
+
+
+@pytest.mark.parametrize("grid", [(1, 1), (2, 2)], ids=["1x1", "2x2"])
+def test_factored_chain_and_solve_fit_each_chip(topo, grid):
+    """The n=16384, d=6 factored build (five Cannon squarings, six T levels
+    kept) and its solve program (the levels, the degrees and the adjacency
+    as operands of one while_loop) compile and fit each chip."""
+    from repro.core.chain import factored_chain
+    from repro.core.solvers.driver import _resident_program
+
+    ctx = _ctx(topo, *grid)
+    mat = NamedSharding(ctx.mesh, ctx.matrix_spec)
+    a = _s(mat, (N_RESIDENT, N_RESIDENT))
+    build = _compile(lambda a: factored_chain(ctx, a, 6, schedule="cannon").t_levels, a)
+    chi = _s(NamedSharding(ctx.mesh, ctx.rowblock_spec), (N_RESIDENT, K_RP))
+    ops = (tuple([a] * 6), _s(NamedSharding(ctx.mesh, P(None)), (N_RESIDENT,)), a)
+    scalar = NamedSharding(ctx.mesh, P())
+    prog = _resident_program(ctx, "richardson", True, chi, None, 6)
+    solve = prog.lower(
+        ops, chi, chi, _s(scalar, ()), _s(scalar, (), jnp.int32), _s(scalar, ())
+    ).compile()
+    for compiled in (build, solve):
+        m = compiled.memory_analysis()
+        used = (
+            m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes
+        )
+        assert used < V5E_HBM_BYTES, f"{used / 1e9:.2f} GB"
